@@ -57,6 +57,7 @@ from repro.core.programs import registered_methods
 from repro.serve.cluster_batcher import ClusterBatcher, ClusterRequest
 from repro.serve.engine import serve_all
 from repro.serve.scheduler import POLICY_NAMES
+from repro.util import enable_compile_cache
 
 
 def make_workload(num_graphs: int, seed: int = 0):
@@ -171,6 +172,7 @@ def bench_serve_policy(graphs, lams, policy: str, executor: str,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graphs", type=int, default=96)
     ap.add_argument("--repeat", type=int, default=3,

@@ -13,8 +13,11 @@ import argparse
 import sys
 import traceback
 
+from repro.util import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run benchmarks whose name contains this substring")

@@ -92,6 +92,7 @@ from repro.serve.cluster_batcher import (
 from repro.serve.engine import serve_all
 from repro.serve.scheduler import POLICY_NAMES
 from repro.util import VirtualClock
+from repro.util import enable_compile_cache
 
 
 def make_requests(num_graphs: int, seed: int = 0, n_lo: int = 8,
@@ -834,6 +835,7 @@ def pct(x, q):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graphs", type=int, default=200)
     ap.add_argument("--max-batch", type=int, default=16)

@@ -49,6 +49,7 @@ from repro.serve.cluster_batcher import (
     ClusterBatcher,
     ClusterRequest,
 )
+from repro.util import enable_compile_cache
 
 
 def make_stream(n_requests: int, seed: int = 42):
@@ -115,6 +116,7 @@ def drive(batcher: ClusterBatcher, n_requests: int, label: str):
 
 
 def main():
+    enable_compile_cache()
     n_requests = 100
     print(f"streaming {n_requests} clustering queries (max_batch=16)...")
     drive(ClusterBatcher(max_batch=16, num_samples=2),

@@ -5,9 +5,11 @@
 
 from repro.data.dedup import dedup_corpus, dedup_quality
 from repro.data.synthetic import synthetic_corpus, token_stream
+from repro.util import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     corpus = synthetic_corpus(n_docs=200, dup_fraction=0.4, mutate_p=0.05,
                               seed=0)
     res = dedup_corpus(corpus, threshold=0.45)

@@ -9,9 +9,11 @@ import numpy as np
 from repro.core import (build_graph, correlation_cluster, matching_size,
                         max_matching_forest)
 from repro.core.graph import random_forest
+from repro.util import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(1)
     g = build_graph(5_000, random_forest(5_000, rng))
     exact = correlation_cluster(g, method="forest_exact")

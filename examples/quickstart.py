@@ -8,9 +8,11 @@ import numpy as np
 
 from repro.core import build_graph, correlation_cluster
 from repro.core.graph import random_arboric
+from repro.util import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     n, lam = 2_000, 3
     edges, _ = random_arboric(n, lam, rng)

@@ -4,9 +4,11 @@
 """
 
 from repro.launch.serve import run
+from repro.util import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     reqs, stats = run("smollm-135m", smoke=True, n_requests=8, max_new=16,
                       max_slots=4, cache_len=96)
     print(f"prefills={stats.prefills} decode_steps={stats.decode_steps} "

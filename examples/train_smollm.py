@@ -7,9 +7,11 @@ with checkpointing (deliverable (b): train-kind end-to-end example).
 import argparse
 
 from repro.launch.train import run
+from repro.util import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_smollm")

@@ -33,8 +33,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
-
 from .graph import Graph
 from .mis import IN_MIS, INF_RANK, UNDECIDED, assign_to_min_rank_mis_neighbor
 
@@ -142,15 +140,15 @@ def _dist_mis_program(src, dst, ranks, n: int, mesh: Mesh,
         wmin = jax.lax.pmin(local, "shard")[:n]
         return status, rounds, wmin
 
-    # check_rep=False: the pinned jax has no replication rule for `while`
-    # inside shard_map; every out spec is replicated by construction (pmin /
-    # pmax collectives close each round).
-    return _shard_map(
+    # Every out spec is replicated by construction (pmin / pmax collectives
+    # close each round), which the replication checker cannot see through
+    # the while loop.
+    return jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(P("shard"), P("shard"), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(src, dst, ranks)
 
 

@@ -57,9 +57,12 @@ computation (asserted for every executor in ``tests/test_executor.py``).
 
 from __future__ import annotations
 
+import os
 import time
 import warnings
 from collections import OrderedDict, deque
+from concurrent.futures import ThreadPoolExecutor
+from contextlib import contextmanager
 from functools import partial
 from typing import Any, Callable, Deque, List, Optional, Protocol, Tuple, \
     runtime_checkable
@@ -70,7 +73,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.util import next_pow2
 
 from .programs import IN_MIS, REMOVED, UNDECIDED, _gather_rows, \
@@ -174,13 +176,12 @@ def _build_program(k: int, use_kernel: bool, donate: bool,
     if mesh is not None:
         axis = mesh.axis_names[0]
         spec = P(axis)
-        # check_rep=False: the pinned jax has no replication rule for
-        # `while` inside shard_map (same situation as core.dist); every
-        # entry is independent, so out specs sharded like the inputs.
-        impl = _shard_map(impl, mesh=mesh,
-                          in_specs=(spec, spec, spec, spec),
-                          out_specs=(spec, spec, spec, spec),
-                          check_rep=False)
+        # Every entry is independent, so the outputs shard like the
+        # inputs; nothing is replicated for the checker to verify.
+        impl = jax.shard_map(impl, mesh=mesh,
+                             in_specs=(spec, spec, spec, spec),
+                             out_specs=(spec, spec, spec, spec),
+                             check_vma=False)
     return jax.jit(impl, donate_argnums=(0, 1, 2, 3) if donate else ())
 
 
@@ -365,6 +366,31 @@ def run_bucket_program(ell, ranks_p, elig_p, m_edges, k: int,
     """
     global _last_compile_wall
     _last_compile_wall = None
+    ell = jnp.asarray(ell)
+    key, fn, fresh = _cached_program(ell.shape, k, use_kernel, donate, mesh,
+                                     block_rows, method, objective)
+    args = (ell, jnp.asarray(ranks_p), jnp.asarray(elig_p),
+            jnp.asarray(m_edges))
+
+    def _invoke():
+        with _donation_quiet(donate):
+            return fn(*args)
+
+    if not fresh:
+        return _invoke()
+    t0 = time.perf_counter()
+    out = _invoke()
+    _last_compile_wall = time.perf_counter() - t0
+    _record_compile_wall(key, _last_compile_wall)
+    return out
+
+
+def _cached_program(shape, k: int, use_kernel: bool, donate: bool,
+                    mesh: Optional[Mesh], block_rows, method: str,
+                    objective: str) -> Tuple[tuple, Callable, bool]:
+    """The program cache's entry for one bucket program, built on a miss:
+    ``(key, jitted program, built_now)``."""
+    global _program_cache_compiles
     program = method_spec(method).program
     objective_spec(objective)            # fail fast on unknown objectives
     if use_kernel:
@@ -374,43 +400,78 @@ def run_bucket_program(ell, ranks_p, elig_p, m_edges, k: int,
         # that leak into every later (untraced) kernel call.
         from repro.kernels import ops  # noqa: F401
 
-    ell = jnp.asarray(ell)
-    resolved = _resolve_block_rows(ell.shape, use_kernel, block_rows)
-    key = _program_key(ell.shape, k, use_kernel, donate, mesh, resolved,
+    resolved = _resolve_block_rows(shape, use_kernel, block_rows)
+    key = _program_key(shape, k, use_kernel, donate, mesh, resolved,
                        program=program, objective=objective)
     fn = _program_cache.get(key)
-    fresh = fn is None
-    if fresh:
-        global _program_cache_compiles
-        _program_cache_compiles += 1
-        fn = _build_program(k, use_kernel, donate, mesh, resolved,
-                            program=program, objective=objective)
-        _program_cache[key] = fn
-        _evict_to_capacity()
-    else:
+    if fn is not None:
         _program_cache.move_to_end(key)
-    args = (ell, jnp.asarray(ranks_p), jnp.asarray(elig_p),
-            jnp.asarray(m_edges))
+        return key, fn, False
+    _program_cache_compiles += 1
+    fn = _build_program(k, use_kernel, donate, mesh, resolved,
+                        program=program, objective=objective)
+    _program_cache[key] = fn
+    _evict_to_capacity()
+    return key, fn, True
 
-    def _invoke():
-        if donate:
-            with warnings.catch_warnings():
-                warnings.filterwarnings(
-                    "ignore", message="Some donated buffers were not usable")
-                return fn(*args)
-        return fn(*args)
 
-    if not fresh:
-        return _invoke()
-    t0 = time.perf_counter()
-    out = _invoke()
-    wall = time.perf_counter() - t0
+def _record_compile_wall(key: tuple, wall: float) -> None:
     bucket = _key_bucket(key)
     prev = _compile_walls.get(bucket)
     _compile_walls[bucket] = wall if prev is None else (
         _COMPILE_EWMA_ALPHA * wall + (1.0 - _COMPILE_EWMA_ALPHA) * prev)
-    _last_compile_wall = wall
-    return out
+
+
+def compile_bucket_programs(shapes, k: int, use_kernel: bool = False,
+                            donate: bool = False,
+                            mesh: Optional[Mesh] = None,
+                            method: str = "pivot",
+                            objective: str = "disagree") -> int:
+    """Compile the bucket programs of several packed ``(B, R, W)`` shapes
+    before their first run, side by side; returns how many were compiled.
+
+    XLA compiles release the GIL, so a serving warmup — hundreds of
+    programs on a cold TPU host — compiles on a pool of threads instead of
+    one program at a time. The programs enter the cache here, and jit
+    reuses the ahead-of-time executable on the first
+    :func:`run_bucket_program` of each shape. Each compile wall feeds the
+    same per-shape EWMA a first run would have.
+    """
+    fresh = []
+    for shape in shapes:
+        key, fn, built = _cached_program(shape, k, use_kernel, donate, mesh,
+                                         None, method, objective)
+        if built:
+            fresh.append((key, fn))
+
+    def compile_one(entry):
+        key, fn = entry
+        b, r, w = key[0]
+        t0 = time.perf_counter()
+        fn.lower(jax.ShapeDtypeStruct((b, r, w), jnp.int32),
+                 jax.ShapeDtypeStruct((b, r + 1), jnp.int32),
+                 jax.ShapeDtypeStruct((b, r + 1), jnp.bool_),
+                 jax.ShapeDtypeStruct((b,), jnp.int32)).compile()
+        return key, time.perf_counter() - t0
+
+    if fresh:
+        # One thread per CPU this process may run on (not per CPU of the
+        # host): each compile holds its own host memory.
+        workers = min(len(fresh), len(os.sched_getaffinity(0)))
+        with _donation_quiet(donate), ThreadPoolExecutor(workers) as pool:
+            for key, wall in pool.map(compile_one, fresh):
+                _record_compile_wall(key, wall)
+    return len(fresh)
+
+
+@contextmanager
+def _donation_quiet(donate: bool):
+    """Silence the expected "donated buffers were not usable" warning."""
+    with warnings.catch_warnings():
+        if donate:
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable")
+        yield
 
 
 # ---------------------------------------------------------------------------
@@ -486,9 +547,6 @@ class InFlightBucket:
         """
         if self._fetched is not None or self._outputs is None:
             return True
-        probe = getattr(self._outputs[0], "is_ready", None)
-        if probe is None:        # very old jax: no non-blocking probe
-            return False
         return all(o.is_ready() for o in self._outputs)
 
     def result(self) -> Tuple[np.ndarray, ...]:

@@ -1,25 +1,34 @@
-"""Pallas TPU kernel: masked neighbour-min propagation (the MIS round loop).
+"""Pallas TPU kernels: masked neighbour-min and same-label counts over ELL.
 
 Contract: given an ELL adjacency (each vertex's neighbour list padded to a
-fixed width ``W``), per-vertex ``ranks`` and an ``active`` mask, compute for
-every vertex the minimum rank over its *active* neighbours (INF if none).
-This is the per-round hot loop of the paper's greedy-MIS engine — executed
-O(log n) times per PIVOT call on the full edge set.
+fixed width ``W``, pad id ``R``), per-vertex ``ranks`` and an ``active``
+mask, compute for every vertex the minimum rank over its *active*
+neighbours (INF if none). This is the per-round hot loop of the paper's
+greedy-MIS engine — executed O(log n) times per PIVOT call on the full
+edge set. ``label_agree_ell_batch`` is the cost pass over the same layout:
+per vertex, how many neighbours carry its own label.
 
-TPU adaptation (see DESIGN.md §2): the paper's own Theorem 26 bounds the
-degree of the clustered subgraph by ``O(λ/ε)`` (12λ at ε=2), which makes the
-ELL layout efficient — padding waste is bounded by the degree cap, and the
-row-blocked kernel is a dense (R × W) tile pipeline through VMEM instead of
-a data-dependent CSR walk. The full rank/active vectors are staged in VMEM
-once per row-block (vertex state is O(n) and edge-sharded shards keep
-n ≤ ~1M per device ⇒ ≤ 4 MB, well inside the 16 MB VMEM budget claimed by
-the BlockSpec below).
+TPU layout. The paper's Theorem 26 bounds the degree of the clustered
+subgraph by ``O(λ/ε)`` (12λ at ε=2), which keeps ``W`` small, so the hot
+loop is a dense tile pipeline through VMEM instead of a data-dependent CSR
+walk. Mosaic lowers an in-kernel gather only inside one vreg row (a
+``take_along_axis`` over 128 lanes), so:
 
-Grid: 1-D over row blocks of ``R`` vertices.
-  ell_ref:    (R, W) int32  — neighbour ids (pad = n)
-  ranks_ref:  (n_pad,)      — full vector, replicated per block
-  active_ref: (n_pad,)      — full vector (int32 0/1), replicated per block
-  out_ref:    (R,) int32    — per-vertex min
+* the ELL is fed **lane-major**: ``(B, W, R)``, vertices on lanes, so a
+  row block's result is a lane row written straight to a ``(B, 1, R)``
+  output;
+* the per-graph state table is staged whole in VMEM as ``(C, 128)`` lane
+  chunks (``R·4`` bytes: 128 KiB at ``R = 2^15``), and a neighbour id
+  ``v`` is read as lane ``v % 128`` of chunk ``v // 128`` — one 128-lane
+  gather per chunk, selected by the chunk id. That costs ``O(R/128)``
+  vector ops per ELL entry, where a one-hot compare over the whole row
+  would cost ``O(R)``.
+
+Neighbour ids ``>= R`` (the pad id) read a fixed fill value (INF for the
+min, the ``-1`` sentinel for labels), so the state's pad slot ``R`` is
+never staged.
+
+Grid: ``(B, R_lanes / rb)`` with ``rb`` a multiple of 128 lanes.
 """
 
 from __future__ import annotations
@@ -33,64 +42,84 @@ from jax.experimental import pallas as pl
 
 INF_VAL = 2**31 - 1  # int32 max; Python int so pallas kernels don't capture arrays
 INF = jnp.int32(INF_VAL)
+LANES = 128
 
 
-def _kernel(ell_ref, ranks_ref, active_ref, out_ref):
-    cols = ell_ref[...]                       # (R, W) int32
-    ranks = ranks_ref[...]                    # (n_pad,)
-    active = active_ref[...]                  # (n_pad,) int32 0/1
-    vals = jnp.take(ranks, cols, axis=0, fill_value=2**31 - 1)  # gather
-    act = jnp.take(active, cols, axis=0, fill_value=0)
-    vals = jnp.where(act > 0, vals, INF_VAL)
-    out_ref[...] = jnp.min(vals, axis=1)
+def _lanes(n: int) -> int:
+    """Vertex axis padded to whole 128-lane rows."""
+    return max(LANES, pl.cdiv(n, LANES) * LANES)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def neighbor_min_ell(ell: jnp.ndarray, ranks: jnp.ndarray, active: jnp.ndarray,
-                     block_rows: int = 256, interpret: bool = True
-                     ) -> jnp.ndarray:
-    """Blocked Pallas neighbour-min over an ELL adjacency.
+def _row_block(block_rows: int, r_lanes: int) -> int:
+    """Lane tile of one grid step: ``block_rows`` rounded up to 128 lanes
+    (the only tile Mosaic accepts on the lane axis), at most the row."""
+    return min(r_lanes, pl.cdiv(max(1, block_rows), LANES) * LANES)
 
-    Args:
-      ell: (n_rows, W) int32 neighbour ids; entries == len(ranks)-1 slot map
-        to a padded rank slot holding INF (see :func:`pad_state`).
-      ranks: (n_pad,) int32 — last slot is the INF pad slot.
-      active: (n_pad,) bool/int32 — last slot False.
-    Returns (n_rows,) int32 mins.
+
+def _lane_major(ell: jnp.ndarray, r_lanes: int) -> jnp.ndarray:
+    """(B, R, W) → (B, W, R_lanes); pad lanes hold the pad id R."""
+    r = ell.shape[1]
+    t = jnp.swapaxes(ell, 1, 2)
+    if r_lanes > r:
+        t = jnp.pad(t, ((0, 0), (0, 0), (0, r_lanes - r)), constant_values=r)
+    return t
+
+
+def _chunk_table(state: jnp.ndarray, r: int, r_lanes: int,
+                 fill: int) -> jnp.ndarray:
+    """(B, >= R) state → (B, R_lanes/128, 128): ids < R read ``state``,
+    ids in [R, R_lanes) read ``fill``."""
+    t = state[:, :r].astype(jnp.int32)
+    if r_lanes > r:
+        t = jnp.pad(t, ((0, 0), (0, r_lanes - r)), constant_values=fill)
+    return t.reshape(t.shape[0], r_lanes // LANES, LANES)
+
+
+def _gather_tile(table_ref, cols: jnp.ndarray, fill: int) -> jnp.ndarray:
+    """``table[cols]`` for one (W, 128) tile of neighbour ids.
+
+    One 128-lane gather per table chunk, kept where the id's chunk matches;
+    ids past the table (the pad id when R is a multiple of 128) keep
+    ``fill``.
     """
-    n_rows, w = ell.shape
-    rb = min(block_rows, n_rows)
-    n_blocks = pl.cdiv(n_rows, rb)
-    active_i = active.astype(jnp.int32)
+    hi = cols >> 7
+    lo = cols & (LANES - 1)
 
-    out = pl.pallas_call(
-        _kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((rb, w), lambda i: (i, 0)),
-            pl.BlockSpec(ranks.shape, lambda i: (0,)),
-            pl.BlockSpec(ranks.shape, lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((rb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_rows,), jnp.int32),
-        interpret=interpret,
-    )(ell, ranks, active_i)
-    return out
+    def body(c, acc):
+        row = table_ref[0, pl.ds(c, 1), :]                  # (1, 128)
+        got = jnp.take_along_axis(jnp.broadcast_to(row, cols.shape), lo,
+                                  axis=1, mode="promise_in_bounds")
+        return jnp.where(hi == c, got, acc)
+
+    return jax.lax.fori_loop(0, table_ref.shape[1], body,
+                             jnp.full(cols.shape, fill, jnp.int32))
 
 
-def _kernel_batch(ell_ref, ranks_ref, active_ref, out_ref):
-    """Per-(graph, row-block) program of the batched grid.
+def _neighbor_min_kernel(ell_ref, table_ref, out_ref):
+    """One (graph, lane block): ell (1, W, rb), table (1, C, 128),
+    out (1, 1, rb)."""
+    for j in range(ell_ref.shape[2] // LANES):
+        lanes = slice(j * LANES, (j + 1) * LANES)
+        vals = _gather_tile(table_ref, ell_ref[0, :, lanes], INF_VAL)
+        out_ref[0, :, lanes] = jnp.min(vals, axis=0, keepdims=True)
 
-    Identical math to :func:`_kernel`; the leading length-1 axis is the
-    batch block (one graph's row-block plus that graph's replicated state).
-    """
-    cols = ell_ref[0]                         # (RB, W) int32
-    ranks = ranks_ref[0]                      # (R+1,)
-    active = active_ref[0]                    # (R+1,) int32 0/1
-    vals = jnp.take(ranks, cols, axis=0, fill_value=INF_VAL)
-    act = jnp.take(active, cols, axis=0, fill_value=0)
-    vals = jnp.where(act > 0, vals, INF_VAL)
-    out_ref[0] = jnp.min(vals, axis=1)
+
+def _label_agree_kernel(ell_ref, table_ref, own_ref, out_ref):
+    """One (graph, lane block): count neighbours whose label equals the
+    vertex's own. Pad ids read the -1 sentinel, never a real label."""
+    for j in range(ell_ref.shape[2] // LANES):
+        lanes = slice(j * LANES, (j + 1) * LANES)
+        nbr = _gather_tile(table_ref, ell_ref[0, :, lanes], -1)
+        same = (nbr == own_ref[0, :, lanes]).astype(jnp.int32)
+        out_ref[0, :, lanes] = jnp.sum(same, axis=0, keepdims=True)
+
+
+def _specs(w: int, rb: int, c: int):
+    """BlockSpecs of the (B, W, R) ELL, the (B, C, 128) table and a
+    (B, 1, R) lane row, for grid point (graph, lane block)."""
+    return (pl.BlockSpec((1, w, rb), lambda bi, i: (bi, 0, i)),
+            pl.BlockSpec((1, c, LANES), lambda bi, i: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, rb), lambda bi, i: (bi, 0, i)))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -101,7 +130,7 @@ def neighbor_min_ell_batch(ell: jnp.ndarray, ranks: jnp.ndarray,
 
     The multi-graph PIVOT engine (``core.batch``) packs ``B`` graphs of one
     shape bucket into a single ``(B, R, W)`` ELL tensor; this kernel runs the
-    per-round hot loop for the whole bucket with a 2-D ``(batch, row_block)``
+    per-round hot loop for the whole bucket with a 2-D ``(batch, lane_block)``
     grid, so one Mosaic program serves every graph in the bucket and the
     round loop stays on device end to end.
 
@@ -110,46 +139,43 @@ def neighbor_min_ell_batch(ell: jnp.ndarray, ranks: jnp.ndarray,
         slot, see ``core.batch``).
       ranks: (B, R+1) int32 — slot R is the INF pad slot.
       active: (B, R+1) bool/int32 — slot R inactive.
+      block_rows: vertices per grid step, rounded up to 128 lanes.
     Returns (B, R) int32 per-vertex mins.
     """
-    b, n_rows, w = ell.shape
-    rb = min(block_rows, n_rows)
-    n_blocks = pl.cdiv(n_rows, rb)
-    state_w = ranks.shape[1]
-    active_i = active.astype(jnp.int32)
-
+    b, r, w = ell.shape
+    r_lanes = _lanes(r)
+    rb = _row_block(block_rows, r_lanes)
+    masked = jnp.where(active[:, :r].astype(bool), ranks[:, :r], INF_VAL)
+    table = _chunk_table(masked, r, r_lanes, INF_VAL)
+    ell_spec, table_spec, row_spec = _specs(w, rb, table.shape[1])
     out = pl.pallas_call(
-        _kernel_batch,
-        grid=(b, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, rb, w), lambda bi, i: (bi, i, 0)),
-            pl.BlockSpec((1, state_w), lambda bi, i: (bi, 0)),
-            pl.BlockSpec((1, state_w), lambda bi, i: (bi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rb), lambda bi, i: (bi, i)),
-        out_shape=jax.ShapeDtypeStruct((b, n_rows), jnp.int32),
+        _neighbor_min_kernel,
+        grid=(b, pl.cdiv(r_lanes, rb)),
+        in_specs=[ell_spec, table_spec],
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, r_lanes), jnp.int32),
         interpret=interpret,
-    )(ell, ranks, active_i)
-    return out
+        name="neighbor_min",
+    )(_lane_major(ell, r_lanes), table)
+    return out[:, 0, :r]
 
 
-def _kernel_agree_batch(ell_ref, labels_full_ref, labels_rows_ref, out_ref):
-    """Per-(graph, row-block) program of the batched cost reduction.
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def neighbor_min_ell(ell: jnp.ndarray, ranks: jnp.ndarray, active: jnp.ndarray,
+                     block_rows: int = 256, interpret: bool = True
+                     ) -> jnp.ndarray:
+    """Single-graph neighbour-min: the batched kernel at ``B = 1``.
 
-    Counts, for every vertex of the row block, how many of its ELL
-    neighbours carry the same cluster label. The eligible-induced ELL holds
-    both directions of every kept undirected edge, so summing this output
-    over rows yields ``2 · intra_pos`` — the quantity the fused batch
-    program combines with cluster sizes into the disagreement cost. Pad
-    entries point at slot R whose label is the -1 sentinel (never a real
-    label), so they contribute nothing.
+    Args:
+      ell: (n_rows, W) int32 neighbour ids; pad entries == n_rows (the
+        padded rank slot, see :func:`pad_state`).
+      ranks: (n_rows+1,) int32 — last slot is the INF pad slot.
+      active: (n_rows+1,) bool/int32 — last slot False.
+    Returns (n_rows,) int32 mins.
     """
-    cols = ell_ref[0]                         # (RB, W) int32
-    labels = labels_full_ref[0]               # (R+1,) int32, slot R = -1
-    own = labels_rows_ref[0]                  # (RB,) int32
-    nbr = jnp.take(labels, cols, axis=0, fill_value=-1)
-    same = (nbr == own[:, None]).astype(jnp.int32)
-    out_ref[0] = jnp.sum(same, axis=1)
+    return neighbor_min_ell_batch(ell[None], ranks[None], active[None],
+                                  block_rows=block_rows,
+                                  interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -158,35 +184,31 @@ def label_agree_ell_batch(ell: jnp.ndarray, labels_p: jnp.ndarray,
                           ) -> jnp.ndarray:
     """Batched same-label neighbour count over shape-bucketed ELL tensors.
 
-    The device cost pass of ``core.batch``: one ``(batch, row_block)`` grid
+    The device cost pass of ``core.batch``: one ``(batch, lane_block)`` grid
     program computes per-vertex agreement counts for every graph of a
-    bucket, mirroring :func:`neighbor_min_ell_batch`'s layout so the cost
-    reduction rides the same VMEM staging as the round loop.
+    bucket, in the same lane-major layout as :func:`neighbor_min_ell_batch`.
 
     Args:
       ell: (B, R, W) int32 neighbour ids; pad entries == R.
       labels_p: (B, R+1) int32 cluster labels; slot R holds the -1 sentinel.
     Returns (B, R) int32 per-vertex same-label neighbour counts.
     """
-    b, n_rows, w = ell.shape
-    rb = min(block_rows, n_rows)
-    n_blocks = pl.cdiv(n_rows, rb)
-    state_w = labels_p.shape[1]
-    labels_rows = labels_p[:, :n_rows]
-
+    b, r, w = ell.shape
+    r_lanes = _lanes(r)
+    rb = _row_block(block_rows, r_lanes)
+    table = _chunk_table(labels_p, r, r_lanes, -1)
+    own = table.reshape(b, 1, r_lanes)
+    ell_spec, table_spec, row_spec = _specs(w, rb, table.shape[1])
     out = pl.pallas_call(
-        _kernel_agree_batch,
-        grid=(b, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, rb, w), lambda bi, i: (bi, i, 0)),
-            pl.BlockSpec((1, state_w), lambda bi, i: (bi, 0)),
-            pl.BlockSpec((1, rb), lambda bi, i: (bi, i)),
-        ],
-        out_specs=pl.BlockSpec((1, rb), lambda bi, i: (bi, i)),
-        out_shape=jax.ShapeDtypeStruct((b, n_rows), jnp.int32),
+        _label_agree_kernel,
+        grid=(b, pl.cdiv(r_lanes, rb)),
+        in_specs=[ell_spec, table_spec, row_spec],
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, r_lanes), jnp.int32),
         interpret=interpret,
-    )(ell, labels_p, labels_rows)
-    return out
+        name="label_agree",
+    )(_lane_major(ell, r_lanes), table, own)
+    return out[:, 0, :r]
 
 
 def pad_state(ranks: jnp.ndarray, active: jnp.ndarray):

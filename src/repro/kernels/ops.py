@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels are written for TPU as the target and validated in interpret mode).
-On a real TPU backend the same call sites lower the Mosaic kernels.
+On a TPU backend the wrappers lower the Mosaic kernels; on any other
+backend (the CPU test runs) they run the same kernels in the Pallas
+interpreter.
 """
 
 from __future__ import annotations

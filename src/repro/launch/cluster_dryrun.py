@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core import (algorithm1, build_graph, degree_threshold,
                         greedy_mis_parallel, random_permutation_ranks)
 from repro.core.dist import _dist_mis_program, _pad_edges_for_mesh
@@ -72,10 +71,11 @@ def _round_program_bytes(n: int, edges_per_shard: int, mesh: Mesh,
             status_r = jnp.where(hit, 2, status_r)
             return status_r
 
-        return _shard_map(
+        return jax.shard_map(
             spmd, mesh=mesh,
             in_specs=(P("shard"), P("shard"), P(), P()),
             out_specs=P(),
+            check_vma=False,
         )(src, dst, ranks, status)
 
     sds = jax.ShapeDtypeStruct

@@ -1,6 +1,7 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms per (arch × shape × mesh), TPU v5e constants:
+Three terms per (arch × shape × mesh), with the peaks of the dry run's
+target chip (:data:`DRYRUN_KIND`, a TPU v5e):
 
     compute    = FLOPs / (chips × 197e12 bf16 FLOP/s)
     memory     = bytes_accessed / (chips × 819e9 B/s HBM)
@@ -25,10 +26,45 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-# --- TPU v5e hardware constants (per chip) ---------------------------------
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    bf16_flops: float           # FLOP/s
+    int8_ops: float             # OP/s
+    hbm_bytes: float            # B
+    hbm_bw: float               # B/s
+    ici_bw: float               # B/s per link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``. A kind that is not here has no
+# roofline: :func:`chip_peaks` raises rather than borrow another chip's.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bytes=16e9, hbm_bw=819e9,
+        # 1,600 Gbit/s of inter-chip interconnect over 4 links.
+        ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip kind (``jax.devices()[0].device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
+
+
+# The dry-run tools compile for a described v5e mesh.
+DRYRUN_KIND = "TPU v5 lite"
+PEAK_FLOPS_BF16 = PEAKS[DRYRUN_KIND].bf16_flops
+HBM_BW = PEAKS[DRYRUN_KIND].hbm_bw
+ICI_BW = PEAKS[DRYRUN_KIND].ici_bw
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -486,12 +522,23 @@ class KernelRoofline:
 
 
 def ell_kernel_roofline(kernel: str, b: int, r: int, w: int, *,
-                        peak_flops: float = PEAK_FLOPS_BF16,
-                        mem_bw: float = HBM_BW) -> KernelRoofline:
-    """Roofline bound for one ``(B, R, W)`` batched ELL kernel invocation
-    (TPU v5e constants by default — on other hardware the bound is still a
-    valid *lower* bound for slower parts, which is how the perf tests use
-    it: measured walls must never beat the model)."""
+                        device_kind: Optional[str] = None,
+                        peak_flops: Optional[float] = None,
+                        mem_bw: Optional[float] = None) -> KernelRoofline:
+    """Roofline bound for one ``(B, R, W)`` batched ELL kernel invocation.
+
+    The peaks come from ``device_kind`` (:data:`PEAKS`; an unknown kind
+    raises) or from explicit ``peak_flops`` and ``mem_bw``. The compute
+    term uses the bf16 peak, so for these integer element-op kernels it is
+    a loose lower bound.
+    """
+    if device_kind is not None:
+        if peak_flops is not None or mem_bw is not None:
+            raise ValueError("pass device_kind or explicit peaks, not both")
+        peaks = chip_peaks(device_kind)
+        peak_flops, mem_bw = peaks.bf16_flops, peaks.hbm_bw
+    elif peak_flops is None or mem_bw is None:
+        raise ValueError("pass device_kind, or both peak_flops and mem_bw")
     return KernelRoofline(kernel=kernel, b=int(b), r=int(r), w=int(w),
                           flops=ell_kernel_flops(kernel, b, r, w),
                           bytes_hbm=ell_kernel_bytes(kernel, b, r, w),
@@ -499,6 +546,7 @@ def ell_kernel_roofline(kernel: str, b: int, r: int, w: int, *,
 
 
 __all__ = [
+    "ChipPeaks", "PEAKS", "chip_peaks", "DRYRUN_KIND",
     "PEAK_FLOPS_BF16", "HBM_BW", "ICI_BW",
     "collective_stats", "CollectiveStats",
     "forward_flops", "step_flops", "model_flops", "active_param_count",

@@ -23,7 +23,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 from repro.configs.base import ModelConfig
 from .common import Pm, constrain, dense_init, linear
 
@@ -289,10 +288,11 @@ def moe_ep_local(params, x, cfg: ModelConfig, capacity_factor: float,
             y_part = yc.reshape(t_loc, d)
         return jax.lax.psum(y_part, model_ax)
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_ax, None), P(None, None),
                   P(model_ax, None, None), P(model_ax, None, None),
                   P(model_ax, None, None)),
         out_specs=P(batch_ax, None),
+        check_vma=False,
     )(x, params["router"], params["wi"], params["wg"], params["wo"])

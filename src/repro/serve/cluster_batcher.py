@@ -119,7 +119,6 @@ from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import BucketBufferPool, make_executor, plan_graph
@@ -498,8 +497,10 @@ class ClusterBatcher:
         buckets ahead of serving for the same reason. Given sample graphs
         covering the expected shape buckets, this compiles every sub-batch
         program *for this engine's executor* (the sharded executor floors
-        sub-batches at its device count, so it usually has fewer) via
-        zero-filled dummy tensors; nothing is returned to callers.
+        sub-batches at its device count, so it usually has fewer) — all of
+        them side by side (:func:`~repro.core.executor.
+        compile_bucket_programs`) — and runs each once on zero-filled host
+        dummies; nothing is returned to callers.
         Returns the number of programs compiled.
 
         ``autotune=True`` first sweeps the kernel ``block_rows``
@@ -513,8 +514,8 @@ class ClusterBatcher:
         timings (the cache hit counters prove it). Sweep telemetry lands
         in ``stats.tuning``.
         """
-        from repro.core.executor import program_cache_size, \
-            run_bucket_program
+        from repro.core.executor import compile_bucket_programs, \
+            program_cache_size, run_bucket_program
 
         before = program_cache_size()
         k = self.num_samples
@@ -524,26 +525,27 @@ class ClusterBatcher:
             # a graph differently from the admission that will follow it.
             plan = self._plan_for(g)
             by_bucket.setdefault(plan.bucket, []).append(plan)
-        for bucket, plans in by_bucket.items():
-            R, W = bucket
-            pads, g_pad = set(), 1
-            while g_pad <= next_pow2(self.max_batch):
-                pads.add(self.executor.group_pad(g_pad))
-                g_pad *= 2
-            if autotune:
+        pads, g_pad = set(), 1
+        while g_pad <= next_pow2(self.max_batch):
+            pads.add(self.executor.group_pad(g_pad))
+            g_pad *= 2
+        if autotune:
+            for plans in by_bucket.values():
                 self._autotune_bucket(plans, sorted(pads),
                                       candidates, repeats)
-            for gp in sorted(pads):
-                b = gp * k
-                ell = jnp.full((b, R, W), R, dtype=jnp.int32)
-                ranks = jnp.full((b, R + 1), np.iinfo(np.int32).max,
-                                 dtype=jnp.int32)
-                elig = jnp.zeros((b, R + 1), dtype=bool)
-                m = jnp.zeros((b,), dtype=jnp.int32)
-                jax.block_until_ready(run_bucket_program(
-                    ell, ranks, elig, m, k=k, use_kernel=self.use_kernel,
-                    donate=self.pool.donate, mesh=self.executor.mesh,
-                    method=self.method, objective=self.objective))
+        shapes = [(gp * k, R, W) for R, W in by_bucket for gp in sorted(pads)]
+        opts = dict(use_kernel=self.use_kernel, donate=self.pool.donate,
+                    mesh=self.executor.mesh, method=self.method,
+                    objective=self.objective)
+        compile_bucket_programs(shapes, k, **opts)
+        for b, R, W in shapes:
+            # Host dummies: device-side fills would build one more
+            # executable per shape.
+            jax.block_until_ready(run_bucket_program(
+                np.full((b, R, W), R, dtype=np.int32),
+                np.full((b, R + 1), np.iinfo(np.int32).max, dtype=np.int32),
+                np.zeros((b, R + 1), dtype=bool),
+                np.zeros((b,), dtype=np.int32), k=k, **opts))
         if autotune:
             from repro.kernels.autotune import tuning_info
 

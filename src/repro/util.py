@@ -1,11 +1,17 @@
 """Small shared utilities used across core / serve / kernels.
 
-Kept dependency-free (stdlib only) so every layer can import it without
-cycles — ``core.batch`` packs device tensors with it and the serving layer
-uses it for slot accounting.
+Kept free of import-time dependencies beyond the stdlib so every layer can
+import it without cycles — ``core.batch`` packs device tensors with it and
+the serving layer uses it for slot accounting.
 """
 
 from __future__ import annotations
+
+import os
+from pathlib import Path
+
+# <repo>/.jax_cache: src/repro/util.py sits two levels below the repo root.
+_DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def next_pow2(x: int) -> int:
@@ -41,4 +47,24 @@ class VirtualClock:
         self.t += dt
 
 
-__all__ = ["next_pow2", "VirtualClock"]
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    For entry points (``chip_smoke.py``, ``benchmarks/``, ``examples/``),
+    called before their first compile — never at library import. Uses
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set, else the fixed
+    ``<repo>/.jax_cache``: the directory is part of what a later run must
+    find again, so it is never derived from a pid, a temporary name or the
+    time. Every program is written, however fast it compiled, because a
+    serving warmup compiles hundreds of sub-second bucket programs.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        _DEFAULT_COMPILE_CACHE)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
+__all__ = ["next_pow2", "VirtualClock", "enable_compile_cache"]

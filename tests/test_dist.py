@@ -143,14 +143,14 @@ def test_ep_local_moe_matches_sort_subprocess():
         p, _ = split_params(p_pm)
         x = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model))
         y_ref = moe_sort(p, x, cfg, capacity_factor=100.0)
-        with mesh:
+        with jax.set_mesh(mesh):
             y_ep = moe_ep_local(p, x, cfg, 100.0, plan, mesh)
         assert float(jnp.max(jnp.abs(y_ref - y_ep))) < 1e-4
 
         def loss(pp):
-            with mesh:
-                return jnp.sum(moe_ep_local(pp, x, cfg, 100.0, plan, mesh)**2)
-        g = jax.grad(loss)(p)
+            return jnp.sum(moe_ep_local(pp, x, cfg, 100.0, plan, mesh)**2)
+        with jax.set_mesh(mesh):
+            g = jax.grad(loss)(p)
         assert all(bool(jnp.isfinite(v).all()) for v in jax.tree.leaves(g))
         print("OK")
     """)

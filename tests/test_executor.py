@@ -460,6 +460,35 @@ def test_program_cache_counts_compiles():
     assert info1["compiles"] == info0["compiles"] + 1
 
 
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_warmup_builds_each_program_once(use_kernel):
+    """Warmup compiles its programs ahead of their first run, side by
+    side; jit reuses those executables, so XLA builds each program at
+    most once (fewer when a persistent cache holds it) and a second
+    warmup builds nothing."""
+    from jax._src import monitoring
+
+    builds = []
+
+    def on_duration(event, duration, **kwargs):
+        if event == "/jax/core/compile/backend_compile_duration":
+            builds.append(kwargs.get("fun_name"))
+
+    # num_samples=3 and a width-8 bucket: programs no other test compiles.
+    eng = ClusterBatcher(max_batch=4, num_samples=3, use_kernel=use_kernel)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        g = _rand_graph(40, 2, 0)
+        compiled = eng.warmup([g])
+        first = len(builds)
+        assert eng.warmup([g]) == 0
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert compiled == 3                   # pow2 sub-batches 1, 2, 4
+    assert first <= compiled
+    assert len(builds) == first
+
+
 # ---------------------------------------------------------------------------
 # Factory / protocol / sharded group padding.
 # ---------------------------------------------------------------------------

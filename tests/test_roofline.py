@@ -9,8 +9,10 @@ import pytest
 from repro.configs import SHAPES, get_config
 from repro.launch.roofline import (
     ELL_KERNELS,
+    PEAKS,
     Roofline,
     active_param_count,
+    chip_peaks,
     collective_stats,
     ell_kernel_bytes,
     ell_kernel_flops,
@@ -126,7 +128,8 @@ def test_ell_kernel_roofline_bottleneck_and_dict():
     # ~3.5 element-ops/byte max: on any real FLOPS/BW ratio these kernels
     # are memory bound; force the opposite with a tiny peak to check both
     # branches.
-    r = ell_kernel_roofline("neighbor_min", 8, 128, 16)
+    r = ell_kernel_roofline("neighbor_min", 8, 128, 16,
+                            device_kind="TPU v5 lite")
     assert r.t_model == max(r.t_compute, r.t_memory)
     assert r.bottleneck == "memory"
     slow = ell_kernel_roofline("neighbor_min", 8, 128, 16,
@@ -138,12 +141,29 @@ def test_ell_kernel_roofline_bottleneck_and_dict():
     assert d["bottleneck"] == "memory"
 
 
+def test_chip_peaks_table_refuses_unknown_kinds():
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16e9)
+    assert all(p.source for p in PEAKS.values())
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        ell_kernel_roofline("neighbor_min", 8, 128, 16, device_kind="cpu")
+    with pytest.raises(ValueError, match="device_kind"):
+        ell_kernel_roofline("neighbor_min", 8, 128, 16)
+    with pytest.raises(ValueError, match="not both"):
+        ell_kernel_roofline("neighbor_min", 8, 128, 16,
+                            device_kind="TPU v5 lite", mem_bw=1e9)
+
+
 @pytest.mark.slow
 def test_measured_kernel_walls_respect_roofline():
     """The tentpole's closed loop: sweep real packed bucket tensors, then
     assert (a) every measured wall is >= the hardware model bound — the
-    TPU-v5e roofline is a lower bound for any slower backend, so a wall
-    beating it means the timing or the model is broken — and (b) a fresh
+    TPU-v5e roofline, passed by kind, is a lower bound for any slower
+    backend, so a wall beating it means the timing or the model is broken
+    — and (b) a fresh
     best-of-repeats re-measurement of the tuned block is no slower than
     the 256-default beyond timing noise."""
     import time
@@ -175,7 +195,8 @@ def test_measured_kernel_walls_respect_roofline():
                                   repeats=2)
         assert len(records) == len(ELL_KERNELS)
         for rec in records:
-            bound = ell_kernel_roofline(rec["kernel"], b, r, w).t_model
+            bound = ell_kernel_roofline(rec["kernel"], b, r, w,
+                                        device_kind="TPU v5 lite").t_model
             for ms in rec["timings_ms"].values():
                 assert ms * 1e-3 >= bound, (
                     f"{rec['kernel']} measured {ms:.4f}ms beats the "
