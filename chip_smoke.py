@@ -14,7 +14,7 @@ checked exactly against the numpy host oracles. A subset is then served
 again with ``use_kernel=True`` (the Mosaic kernels, never the interpreter)
 and must match the jnp path bit for bit. ``--chips 4`` runs only the
 sharded phase: ``ShardedExecutor`` over four chips against
-``SyncExecutor`` on one, on the same stream.
+``SyncExecutor`` on one, on the largest buckets of the same pivot stream.
 
 Earlier lines report what ran; the last line of standard output is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -26,11 +26,9 @@ from __future__ import annotations
 import argparse
 import collections
 import json
-import os
 import sys
 import time
 import traceback
-from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
 import numpy as np
@@ -43,9 +41,9 @@ MAX_BATCH = 64
 MAX_WAIT_S = 0.25
 STREAM_GRAPHS = 2000
 # Vertex counts are log-uniform over 64 sizes from 32 to 4,096, not over
-# every integer: admission compiles one rank-permutation program per
-# distinct vertex count (about half a second each on the chip's host), and
-# a cold run must fit its time limit.
+# every integer: the engine compiles one rank-permutation program per
+# distinct vertex count (about half a second each on the chip's host, in
+# the warm-up), and a cold run must fit its time limit.
 SIZES = np.unique(np.rint(32 * 128 ** (np.arange(64) / 63)).astype(int))
 MAX_LAMBDA = 8
 BIG_GRAPHS = 3            # graphs at MAX_ROWS in the pivot stream
@@ -65,9 +63,13 @@ PRECLUSTER_MAX_LAMBDA = 4
 PRECLUSTER_BIG_W = 16     # a forest at MAX_ROWS: degree cap 12 ⇒ W ≤ 16
 KERNEL_MAX_BATCH = 8
 KERNEL_GRAPHS_PER_BUCKET = 24
-SHARDED_GRAPHS = 128
-SHARDED_MAX_N = 256
-SHARDED_MAX_BATCH = 16
+# The sharded phase serves every request of four buckets of the pivot
+# stream — the fullest and the widest at the largest R below MAX_ROWS, the
+# fullest with R <= 256, and MAX_ROWS — with no deadline, so the large
+# buckets run full flushes (at seed 0: (4096, 32) x 153 and (4096, 64) x 56,
+# up to 268 MB of ELL per flush). The MAX_ROWS engine's max_batch is the
+# four-chip group pad.
+SHARDED_BIG_MAX_BATCH = 4
 
 
 class CompileWatch:
@@ -182,37 +184,30 @@ def fresh(reqs):
 
 
 def engine(method: str, max_batch: int, use_kernel: bool = False,
-           executor="async"):
+           executor="async", max_wait=MAX_WAIT_S):
     from repro.serve.cluster_batcher import ClusterBatcher
 
     return ClusterBatcher(max_batch=max_batch, method=method,
                           num_samples=NUM_SAMPLES, use_kernel=use_kernel,
-                          executor=executor, max_wait=MAX_WAIT_S)
+                          executor=executor, max_wait=max_wait)
 
 
-def warm(eng, graphs) -> int:
-    """Compile every bucket program and rank program ``graphs`` can hit.
+def recording(executor_cls):
+    """An ``executor_cls`` that logs the packed shape and the devices
+    holding the outputs of every flush the engine submits through it."""
 
-    ``ClusterBatcher.warmup`` covers every pow2 sub-batch of each bucket;
-    the rank programs are per vertex count (admission draws the best-of-k
-    permutations of each request).
-    """
-    import jax
+    class Recording(executor_cls):
+        def __init__(self):
+            super().__init__()
+            self.flushes = []
 
-    from repro.core.api import sample_keys
-    from repro.core.mis import random_permutation_ranks_batch
-    from repro.core.plan import plan_graph
+        def _post_submit(self, handle):
+            devices = handle._outputs[0].sharding.device_set
+            self.flushes.append((handle.shape,
+                                 sorted(d.id for d in devices)))
+            super()._post_submit(handle)
 
-    reps = {}
-    for g in graphs:
-        reps.setdefault(plan_graph(g, method=eng.method).bucket, g)
-    eng.warmup(list(reps.values()))
-    keys = sample_keys(jax.random.PRNGKey(0), NUM_SAMPLES)
-    with ThreadPoolExecutor(len(os.sched_getaffinity(0))) as pool:
-        list(pool.map(lambda n: jax.block_until_ready(
-            random_permutation_ranks_batch(n, keys)),
-            sorted({g.n for g in graphs})))
-    return len(reps)
+    return Recording()
 
 
 def serve(engines, reqs, route) -> None:
@@ -280,40 +275,44 @@ def check_same(got, want, what: str) -> None:
             raise AssertionError(f"{what}: request {req.uid} differs")
 
 
-
-
 def is_big(req) -> bool:
     from repro.core.plan import MAX_ROWS
 
     return req.graph.n > MAX_ROWS // 2
 
 
-def run_method(smoke, method: str, reqs, max_batch: int, big_max_batch: int):
-    """Warm up, serve and check one method's stream; MAX_ROWS graphs go to
-    their own engine. Returns the served requests."""
+def serve_phase(smoke, label: str, method: str, reqs, max_batch: int,
+                big_max_batch: int, use_kernel: bool = False,
+                executors=("async", "async"), max_wait=MAX_WAIT_S):
+    """Warm up and serve ``reqs``: MAX_ROWS graphs go to an engine of
+    their own. Fails if the stream compiled anything. Returns the
+    engines."""
     small = [r for r in reqs if not is_big(r)]
     big = [r for r in reqs if is_big(r)]
-    engines = [engine(method, max_batch)]
+    engines = [engine(method, max_batch, use_kernel, executors[0], max_wait)]
     if big:
-        engines.append(engine(method, big_max_batch))
-
-    def warm_all():
-        buckets = warm(engines[0], [r.graph for r in small])
-        if big:
-            buckets += warm(engines[1], [r.graph for r in big])
-        return buckets
-
-    smoke.timed(f"{method} warm-up", warm_all)
+        engines.append(engine(method, big_max_batch, use_kernel,
+                              executors[1], max_wait))
+    smoke.timed(f"{label} warm-up",
+                lambda: [e.warmup([r.graph for r in rs])
+                         for e, rs in zip(engines, (small, big)) if rs])
     _, counts, programs = smoke.timed(
-        f"{method} stream",
+        f"{label} stream",
         lambda: serve(engines, reqs,
                       lambda r: engines[-1] if is_big(r) else engines[0]),
         graphs=len(reqs))
     if programs or counts["executables"]:
         raise AssertionError(
-            f"{method} stream compiled {programs} bucket programs and built "
+            f"{label} stream compiled {programs} bucket programs and built "
             f"{counts['executables']} executables: {counts['by_name']}")
-    stats = [e.stats for e in engines]
+    return engines
+
+
+def run_method(smoke, method: str, reqs, max_batch: int, big_max_batch: int):
+    """Serve one method's stream and check it against the host oracles.
+    Returns the served requests."""
+    stats = [e.stats for e in serve_phase(smoke, method, method, reqs,
+                                          max_batch, big_max_batch)]
     smoke.say(f"{method} served", graphs=sum(s.clustered for s in stats),
               flushes=sum(s.flushes for s in stats),
               deadline_flushes=sum(s.deadline_flushes for s in stats),
@@ -326,20 +325,24 @@ def run_method(smoke, method: str, reqs, max_batch: int, big_max_batch: int):
     return reqs
 
 
-def kernel_subset(served):
+def bucket_subset(reqs, bucket_of, per_bucket=None, widest=False):
     """Requests of a few buckets: the fullest at the largest R below
-    MAX_ROWS, the fullest with R ≤ 256, and the MAX_ROWS bucket."""
+    MAX_ROWS (and with ``widest`` the widest there too), the fullest with
+    R ≤ 256, and the MAX_ROWS bucket — the first ``per_bucket`` of each
+    (all when None)."""
     by_bucket = collections.defaultdict(list)
-    for r in served:
-        by_bucket[r.plan.bucket].append(r)
+    for r in reqs:
+        by_bucket[bucket_of(r)].append(r)
     normal = [b for b in by_bucket if not is_big(by_bucket[b][0])]
     top_r = max(b[0] for b in normal)
     picks = {max((b for b in normal if b[0] == top_r),
                  key=lambda b: len(by_bucket[b])),
              max((b for b in normal if b[0] <= 256),
                  key=lambda b: len(by_bucket[b]))}
+    if widest:
+        picks.add(max(b for b in normal if b[0] == top_r))
     picks |= {b for b in by_bucket if is_big(by_bucket[b][0])}
-    return {b: by_bucket[b][:KERNEL_GRAPHS_PER_BUCKET] for b in sorted(picks)}
+    return {b: by_bucket[b][:per_bucket] for b in sorted(picks)}
 
 
 def run_kernels(smoke, method: str, served, big_max_batch: int):
@@ -349,56 +352,53 @@ def run_kernels(smoke, method: str, served, big_max_batch: int):
 
     if ops.interpret_mode():
         raise AssertionError("the kernels would run in the interpreter")
-    subset = kernel_subset(served)
+    subset = bucket_subset(served, lambda r: r.plan.bucket,
+                           KERNEL_GRAPHS_PER_BUCKET)
     reqs = fresh(sorted((r for rs in subset.values() for r in rs),
                         key=lambda r: r.uid))
-    small = [r for r in reqs if not is_big(r)]
-    big = [r for r in reqs if is_big(r)]
-    engines = [engine(method, KERNEL_MAX_BATCH, use_kernel=True)]
-    if big:
-        engines.append(engine(method, big_max_batch, use_kernel=True))
-    smoke.timed(f"{method} kernel warm-up",
-                lambda: [warm(e, [r.graph for r in rs])
-                         for e, rs in zip(engines, (small, big)) if rs])
-    smoke.timed(f"{method} kernel stream",
-                lambda: serve(engines, reqs,
-                              lambda r: engines[-1] if is_big(r)
-                              else engines[0]),
-                graphs=len(reqs), buckets=list(subset))
+    serve_phase(smoke, f"{method} kernel", method, reqs, KERNEL_MAX_BATCH,
+                big_max_batch, use_kernel=True)
     check_same(reqs, served, f"{method} kernel vs jnp")
     smoke.say(f"{method} kernel", interpret=ops.interpret_mode(),
-              matched_jnp=len(reqs))
+              matched_jnp=len(reqs), buckets=list(subset))
 
 
-def run_one_chip(smoke) -> None:
+def pivot_traffic(rng, seed: int):
+    """The pivot stream: ``STREAM_GRAPHS`` graphs plus ``BIG_GRAPHS`` at
+    MAX_ROWS, inserted at seeded places."""
+    from repro.core.graph import random_arboric
+    from repro.core.plan import MAX_ROWS
+
+    draws = make_graphs(rng, STREAM_GRAPHS, SIZES, MAX_LAMBDA)
+    for _ in range(BIG_GRAPHS):
+        at = int(rng.integers(0, len(draws) + 1))
+        draws.insert(at, (MAX_ROWS,
+                          random_arboric(MAX_ROWS, BIG_LAMBDA, rng)[0]))
+    return make_requests(draws, seed)
+
+
+def precluster_traffic(rng, seed: int):
+    """The precluster stream, cut to the shapes that fit the chip."""
     from repro.core.graph import random_arboric
     from repro.core.plan import MAX_ROWS, plan_graph
 
+    draws = make_graphs(rng, PRECLUSTER_GRAPHS,
+                        SIZES[SIZES <= PRECLUSTER_MAX_R],
+                        PRECLUSTER_MAX_LAMBDA)
+    draws.append((MAX_ROWS, random_arboric(MAX_ROWS, 1, rng)[0]))
+    kept = []
+    for r in make_requests(draws, seed, uid0=len(draws) * 10):
+        R, W = plan_graph(r.graph, method="precluster").bucket
+        if W <= (PRECLUSTER_BIG_W if R == MAX_ROWS else PRECLUSTER_MAX_W):
+            kept.append(r)
+    return kept
+
+
+def run_one_chip(smoke) -> None:
     rng = np.random.default_rng(smoke.seed)
-
-    def pivot_traffic():
-        draws = make_graphs(rng, STREAM_GRAPHS, SIZES, MAX_LAMBDA)
-        for _ in range(BIG_GRAPHS):
-            at = int(rng.integers(0, len(draws) + 1))
-            draws.insert(at, (MAX_ROWS,
-                              random_arboric(MAX_ROWS, BIG_LAMBDA, rng)[0]))
-        return make_requests(draws, smoke.seed)
-
-    def precluster_traffic():
-        draws = make_graphs(rng, PRECLUSTER_GRAPHS,
-                            SIZES[SIZES <= PRECLUSTER_MAX_R],
-                            PRECLUSTER_MAX_LAMBDA)
-        draws.append((MAX_ROWS, random_arboric(MAX_ROWS, 1, rng)[0]))
-        reqs = make_requests(draws, smoke.seed, uid0=len(draws) * 10)
-        kept = []
-        for r in reqs:
-            R, W = plan_graph(r.graph, method="precluster").bucket
-            if W <= (PRECLUSTER_BIG_W if R == MAX_ROWS else PRECLUSTER_MAX_W):
-                kept.append(r)
-        return kept
-
     (pivot_reqs, pre_reqs), _, _ = smoke.timed(
-        "set-up", lambda: (pivot_traffic(), precluster_traffic()))
+        "set-up", lambda: (pivot_traffic(rng, smoke.seed),
+                           precluster_traffic(rng, smoke.seed)))
     smoke.say("traffic", pivot_graphs=len(pivot_reqs),
               precluster_graphs=len(pre_reqs),
               distinct_n=len({r.graph.n for r in pivot_reqs + pre_reqs}))
@@ -410,48 +410,57 @@ def run_one_chip(smoke) -> None:
 
 def run_sharded(smoke) -> None:
     """ShardedExecutor over four chips vs SyncExecutor on chip 0, on the
-    same stream: bit-identical, with work on every chip."""
+    largest buckets of the one-chip pivot stream: bit-identical, and every
+    sharded flush's outputs on every chip."""
     import jax
 
-    from repro.core.executor import ShardedExecutor, run_bucket_program
-    from repro.core.plan import pack_bucket
+    from repro.core.executor import ShardedExecutor, SyncExecutor
+    from repro.core.plan import plan_graph
 
     devices = jax.devices()
-    sharded = ShardedExecutor()
-    if sharded.num_devices != 4:
-        raise AssertionError(f"sharded mesh spans {sharded.num_devices} "
-                             "devices, expected 4")
-    rng = np.random.default_rng(smoke.seed + 4)
-    reqs = smoke.timed("set-up", lambda: make_requests(
-        make_graphs(rng, SHARDED_GRAPHS, SIZES[SIZES <= SHARDED_MAX_N],
-                    MAX_LAMBDA), smoke.seed + 4))[0]
-    graphs = [r.graph for r in reqs]
-    results = {}
-    for name, executor in (("sharded", sharded), ("sync", "sync")):
-        eng = engine("pivot", SHARDED_MAX_BATCH, executor=executor)
-        smoke.timed(f"{name} warm-up", lambda: warm(eng, graphs))
+    everywhere = sorted(d.id for d in devices)
+
+    def traffic():
+        reqs = pivot_traffic(np.random.default_rng(smoke.seed), smoke.seed)
+        subset = bucket_subset(
+            reqs, lambda r: plan_graph(r.graph, method="pivot").bucket,
+            widest=True)
+        return sorted((r for rs in subset.values() for r in rs),
+                      key=lambda r: r.uid), subset
+
+    (reqs, subset), _, _ = smoke.timed("set-up", traffic)
+    smoke.say("traffic", graphs=len(reqs),
+              buckets={b: len(rs) for b, rs in subset.items()})
+    runs = {}
+    for name, cls in (("sharded", ShardedExecutor), ("sync", SyncExecutor)):
+        executors = [recording(cls), recording(cls)]
         mine = fresh(reqs)
-        _, counts, programs = smoke.timed(
-            f"{name} stream", lambda: serve([eng], mine, lambda r: eng),
-            graphs=len(mine))
-        if programs or counts["executables"]:
-            raise AssertionError(f"{name} stream compiled: {counts}")
-        smoke.say(f"{name} served", flushes=eng.stats.flushes,
-                  buckets=eng.stats.buckets_seen)
-        results[name] = mine
-    check_same(results["sharded"], results["sync"], "sharded vs sync")
-    check_oracle(results["sharded"], "pivot")
-    probe = results["sharded"][0]
-    host = pack_bucket([probe.plan], [None], k=NUM_SAMPLES,
-                       g_pad=sharded.group_pad(1))[:4]
-    out = jax.block_until_ready(run_bucket_program(
-        *host, k=NUM_SAMPLES, donate=True, mesh=sharded.mesh))
-    spans = sorted(d.id for d in out[0].sharding.device_set)
+        serve_phase(smoke, name, "pivot", mine, MAX_BATCH,
+                    SHARDED_BIG_MAX_BATCH, executors=executors,
+                    max_wait=None)
+        flushes = [f for ex in executors for f in ex.flushes]
+        placed = sorted({tuple(ids) for _, ids in flushes})
+        largest = max((shape for shape, _ in flushes), key=np.prod)
+        smoke.say(f"{name} served", flushes=len(flushes),
+                  flush_shapes=sorted({shape for shape, _ in flushes}),
+                  largest_flush=largest,
+                  largest_ell_bytes=int(np.prod(largest)) * 4,
+                  output_devices=placed)
+        if name == "sharded" and (executors[0].num_devices != len(devices)
+                                  or placed != [tuple(everywhere)]):
+            raise AssertionError(
+                f"sharded flushes placed their outputs on {placed}, not on "
+                f"every one of {everywhere}")
+        runs[name] = mine
+    check_same(runs["sharded"], runs["sync"], "sharded vs sync")
+    t0 = time.perf_counter()
+    check_oracle(runs["sharded"], "pivot")
     peaks = [smoke.peak_bytes(d) for d in devices]
-    smoke.say("sharded placement", output_devices=spans,
-              peak_bytes_in_use=peaks, matched_sync=len(reqs))
-    if spans != sorted(d.id for d in devices) or not all(peaks):
-        raise AssertionError("sharded work did not land on every device")
+    smoke.say("sharded checked", matched_sync=len(reqs),
+              matched_oracle=len(reqs), oracle_wall_s=time.perf_counter() - t0,
+              peak_bytes_in_use=peaks)
+    if not all(peaks):
+        raise AssertionError(f"a device held no memory: {peaks}")
 
 
 def main(argv=None) -> int:

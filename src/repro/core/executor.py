@@ -127,8 +127,8 @@ def _program_key(shape, k: int, use_kernel: bool, donate: bool,
     """The cache key for one compiled bucket program — single definition so
     :func:`run_bucket_program` and the :func:`program_cache_contains` probe
     can never disagree about identity. ``block_rows`` is the *resolved*
-    tuned kernel block pair (None on the jnp path and for untuned
-    buckets), so a tuning-cache update yields a new program at the new
+    kernel lane-tile pair (None on the jnp path and at the default
+    tiles), so a tuning-cache update yields a new program at the new
     shape instead of mutating a compiled one. ``program`` is the method's
     *program family* (``method_spec(m).program``, so ``'pivot'`` and
     ``'pivot_raw'`` share compiled programs); the default pivot × disagree
@@ -143,20 +143,20 @@ def _program_key(shape, k: int, use_kernel: bool, donate: bool,
 
 def _resolve_block_rows(shape, use_kernel: bool,
                         block_rows=None) -> Optional[Tuple[int, int]]:
-    """Static kernel block shapes a bucket program of ``shape`` will bake
-    in: the caller's explicit pair, else the tuning-cache winners, else
-    None (kernel default — the legacy key, so untuned buckets never
-    fragment the program cache). Normalized to None when the kernels are
-    not in play at all."""
+    """Static kernel lane tiles a bucket program of ``shape`` will bake
+    in: the caller's explicit pair, else the tuning-cache winners, each
+    resolved to its lane tile; None at the default tiles (the legacy key,
+    so untuned buckets never fragment the program cache) and when the
+    kernels are not in play at all."""
     if not use_kernel:
         return None
-    if block_rows is not None:
-        if isinstance(block_rows, (tuple, list)):
-            return (int(block_rows[0]), int(block_rows[1]))
-        return (int(block_rows), int(block_rows))
-    from repro.kernels.autotune import resolve_block_rows
+    from repro.kernels.autotune import lane_tiles, resolve_block_rows
 
-    return resolve_block_rows(shape)
+    if block_rows is None:
+        return resolve_block_rows(shape)
+    if not isinstance(block_rows, (tuple, list)):
+        block_rows = (block_rows, block_rows)
+    return lane_tiles(shape[1], block_rows)
 
 
 def _key_bucket(key: tuple) -> Tuple[int, int]:

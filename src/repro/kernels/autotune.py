@@ -43,12 +43,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.neighbor_min import lane_tile
 from repro.util import next_pow2
 
 #: The hand-picked constant the kernels shipped with — the sweep baseline.
 DEFAULT_BLOCK_ROWS = 256
-#: Candidate row tiles (clamped to R per bucket before sweeping).
-CANDIDATE_BLOCK_ROWS = (64, 128, 256, 512)
+#: Candidate row tiles (resolved to lane tiles per bucket before sweeping).
+CANDIDATE_BLOCK_ROWS = (128, 256, 512)
 #: The two batched kernels on the bucket program's hot path.
 KERNELS = ("neighbor_min", "label_agree")
 #: Tier cap: batch axes beyond this share one tuning entry.
@@ -72,15 +73,26 @@ def candidate_blocks(r: int,
                      candidates: Optional[Sequence[int]] = None
                      ) -> Tuple[int, ...]:
     """Candidate ``block_rows`` for a bucket of R rows: the sweep set
-    clamped to R, deduplicated order-preserving, always containing the
-    (clamped) default so "tuned vs default" is measured, never inferred."""
+    resolved to the kernels' lane tiles (:func:`~repro.kernels.
+    neighbor_min.lane_tile`), deduplicated order-preserving, always
+    containing the default's tile so "tuned vs default" is measured, never
+    inferred. Sizes with one tile are one program and are timed once."""
     cands = CANDIDATE_BLOCK_ROWS if candidates is None else tuple(candidates)
     out: List[int] = []
     for c in (*cands, DEFAULT_BLOCK_ROWS):
-        c = max(1, min(int(c), int(r)))
+        c = lane_tile(int(c), int(r))
         if c not in out:
             out.append(c)
     return tuple(out)
+
+
+def lane_tiles(r: int, block_rows) -> Optional[Tuple[int, int]]:
+    """The ``(neighbor_min, label_agree)`` lane tiles a block-row pair
+    compiles to at R rows, or None when both are the default's tile (the
+    untuned program key, so an equivalent pair never compiles twice)."""
+    tiles = tuple(lane_tile(int(b), int(r)) for b in block_rows)
+    default = lane_tile(DEFAULT_BLOCK_ROWS, int(r))
+    return None if tiles == (default, default) else tiles
 
 
 class TuningCache:
@@ -227,10 +239,11 @@ def tuning_info() -> dict:
 
 
 def resolve_block_rows(shape) -> Optional[Tuple[int, int]]:
-    """Tuned ``(neighbor_min, label_agree)`` block rows for a packed
-    ``(B, R, W)`` shape, or None when the bucket tier is untuned (the
-    program key then stays on the legacy default and the kernels use
-    ``DEFAULT_BLOCK_ROWS``). Pure dict reads — safe on the hot path."""
+    """Tuned ``(neighbor_min, label_agree)`` lane tiles for a packed
+    ``(B, R, W)`` shape, or None when the bucket tier is untuned or tuned
+    to the default's tile (the program key then stays on the legacy
+    default and the kernels use ``DEFAULT_BLOCK_ROWS``). Pure dict reads —
+    safe on the hot path."""
     b, r, w = (int(s) for s in shape)
     tier = batch_tier(b)
     cache = tuning_cache()
@@ -238,8 +251,8 @@ def resolve_block_rows(shape) -> Optional[Tuple[int, int]]:
     la = cache.get("label_agree", r, w, tier, count=False)
     if nm is None and la is None:
         return None
-    return (nm if nm is not None else min(DEFAULT_BLOCK_ROWS, r),
-            la if la is not None else min(DEFAULT_BLOCK_ROWS, r))
+    return lane_tiles(r, (nm if nm is not None else DEFAULT_BLOCK_ROWS,
+                          la if la is not None else DEFAULT_BLOCK_ROWS))
 
 
 def sweep_bucket(ell, ranks_p, elig_p,
@@ -273,7 +286,7 @@ def sweep_bucket(ell, ranks_p, elig_p,
     b, r, w = (int(s) for s in ell.shape)
     tier = batch_tier(b)
     cands = candidate_blocks(r, candidates)
-    default_br = min(DEFAULT_BLOCK_ROWS, r)
+    default_br = lane_tile(DEFAULT_BLOCK_ROWS, r)
     # Labels for the cost-pass kernel: contents don't affect timing (the
     # memory/grid shape does), so any valid labeling with the -1 pad
     # sentinel works.
@@ -346,6 +359,7 @@ __all__ = [
     "TuningCache",
     "batch_tier",
     "candidate_blocks",
+    "lane_tiles",
     "tuning_cache",
     "set_tuning_cache",
     "tuning_info",

@@ -50,10 +50,12 @@ def _lanes(n: int) -> int:
     return max(LANES, pl.cdiv(n, LANES) * LANES)
 
 
-def _row_block(block_rows: int, r_lanes: int) -> int:
-    """Lane tile of one grid step: ``block_rows`` rounded up to 128 lanes
-    (the only tile Mosaic accepts on the lane axis), at most the row."""
-    return min(r_lanes, pl.cdiv(max(1, block_rows), LANES) * LANES)
+def lane_tile(block_rows: int, r: int) -> int:
+    """Vertices per grid step of an R-vertex bucket: ``block_rows`` rounded
+    up to whole 128-lane rows (the only tile Mosaic accepts on the lane
+    axis), at most the padded row. Block sizes with one lane tile are one
+    program; the autotuner and the program cache key by this tile."""
+    return min(_lanes(r), pl.cdiv(max(1, block_rows), LANES) * LANES)
 
 
 def _lane_major(ell: jnp.ndarray, r_lanes: int) -> jnp.ndarray:
@@ -144,7 +146,7 @@ def neighbor_min_ell_batch(ell: jnp.ndarray, ranks: jnp.ndarray,
     """
     b, r, w = ell.shape
     r_lanes = _lanes(r)
-    rb = _row_block(block_rows, r_lanes)
+    rb = lane_tile(block_rows, r)
     masked = jnp.where(active[:, :r].astype(bool), ranks[:, :r], INF_VAL)
     table = _chunk_table(masked, r, r_lanes, INF_VAL)
     ell_spec, table_spec, row_spec = _specs(w, rb, table.shape[1])
@@ -195,7 +197,7 @@ def label_agree_ell_batch(ell: jnp.ndarray, labels_p: jnp.ndarray,
     """
     b, r, w = ell.shape
     r_lanes = _lanes(r)
-    rb = _row_block(block_rows, r_lanes)
+    rb = lane_tile(block_rows, r)
     table = _chunk_table(labels_p, r, r_lanes, -1)
     own = table.reshape(b, 1, r_lanes)
     ell_spec, table_spec, row_spec = _specs(w, rb, table.shape[1])

@@ -32,7 +32,6 @@ class ChipPeaks:
     """Published per-chip peaks of one accelerator kind."""
 
     bf16_flops: float           # FLOP/s
-    int8_ops: float             # OP/s
     hbm_bytes: float            # B
     hbm_bw: float               # B/s
     ici_bw: float               # B/s per link
@@ -43,7 +42,7 @@ class ChipPeaks:
 # roofline: :func:`chip_peaks` raises rather than borrow another chip's.
 PEAKS: Dict[str, ChipPeaks] = {
     "TPU v5 lite": ChipPeaks(
-        bf16_flops=197e12, int8_ops=393e12, hbm_bytes=16e9, hbm_bw=819e9,
+        bf16_flops=197e12, hbm_bytes=16e9, hbm_bw=819e9,
         # 1,600 Gbit/s of inter-chip interconnect over 4 links.
         ici_bw=50e9,
         source='Google Cloud documentation, "TPU v5e"'),

@@ -114,8 +114,10 @@ Clocks
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -125,6 +127,7 @@ from repro.core import BucketBufferPool, make_executor, plan_graph
 from repro.core.api import ClusterResult, sample_keys
 from repro.core.executor import pack_and_submit
 from repro.core.graph import Graph
+from repro.core.mis import random_permutation_ranks_batch
 from repro.core.plan import (GraphFingerprint, GraphPlan,
                              build_packed_rows, graph_fingerprint,
                              promote_plan, result_for_plan)
@@ -500,8 +503,11 @@ class ClusterBatcher:
         sub-batches at its device count, so it usually has fewer) — all of
         them side by side (:func:`~repro.core.executor.
         compile_bucket_programs`) — and runs each once on zero-filled host
-        dummies; nothing is returned to callers.
-        Returns the number of programs compiled.
+        dummies; nothing is returned to callers. Admission draws each
+        request's best-of-k ranks with one program per vertex count, so
+        those are compiled too, for every distinct ``n`` of the samples:
+        a request whose ``n`` no sample had still compiles at admission.
+        Returns the number of bucket programs compiled.
 
         ``autotune=True`` first sweeps the kernel ``block_rows``
         candidate set (:mod:`repro.kernels.autotune`) per bucket tier over
@@ -538,6 +544,12 @@ class ClusterBatcher:
                     mesh=self.executor.mesh, method=self.method,
                     objective=self.objective)
         compile_bucket_programs(shapes, k, **opts)
+        keys = sample_keys(jax.random.PRNGKey(0), k)
+        sizes = sorted({p.n for plans in by_bucket.values() for p in plans
+                        if p.n})
+        with ThreadPoolExecutor(len(os.sched_getaffinity(0))) as pool:
+            list(pool.map(lambda n: jax.block_until_ready(
+                random_permutation_ranks_batch(n, keys)), sizes))
         for b, R, W in shapes:
             # Host dummies: device-side fills would build one more
             # executable per shape.

@@ -66,11 +66,18 @@ def test_batch_tier_and_candidates():
     assert at.batch_tier(5) == 8
     assert at.batch_tier(64) == 64
     assert at.batch_tier(10 ** 9) == at.MAX_BATCH_TIER
-    # Clamped to R, deduplicated, default always present.
-    assert at.candidate_blocks(512) == (64, 128, 256, 512)
-    assert at.candidate_blocks(128) == (64, 128)
-    assert at.candidate_blocks(32) == (32,)
-    assert at.candidate_blocks(100, candidates=(48, 512)) == (48, 100)
+    # Resolved to lane tiles (128-lane multiples, at most the padded row),
+    # deduplicated, default always present.
+    assert at.candidate_blocks(512) == (128, 256, 512)
+    assert at.candidate_blocks(128) == (128,)
+    assert at.candidate_blocks(32) == (128,)
+    assert at.candidate_blocks(100, candidates=(48, 512)) == (128,)
+    assert at.candidate_blocks(1024, candidates=(48, 64, 512)) == (128, 512,
+                                                                   256)
+    # One lane tile is one program key; the default tile is the legacy key.
+    assert at.lane_tiles(1024, (48, 100)) == (128, 128)
+    assert at.lane_tiles(1024, (256, 200)) is None
+    assert at.lane_tiles(64, (8, 512)) is None
 
 
 def test_cache_roundtrip(tmp_path):
@@ -134,10 +141,13 @@ def test_cache_env_var_path(tmp_path, monkeypatch):
 
 
 def test_resolve_block_rows_untuned_is_none():
+    assert at.resolve_block_rows((8, 512, 8)) is None
+    at.tuning_cache().put("neighbor_min", 512, 8, 8, 128)
+    # Partial tuning: the untuned kernel falls back to the default tile.
+    assert at.resolve_block_rows((8, 512, 8)) == (128, 256)
+    # A winner with the default's lane tile keeps the untuned key.
+    at.tuning_cache().put("neighbor_min", 64, 8, 8, 32)
     assert at.resolve_block_rows((8, 64, 8)) is None
-    at.tuning_cache().put("neighbor_min", 64, 8, 8, 128)
-    # Partial tuning: the untuned kernel falls back to the clamped default.
-    assert at.resolve_block_rows((8, 64, 8)) == (128, 64)
 
 
 # --- sweep mechanics -------------------------------------------------------
@@ -200,31 +210,35 @@ def test_warmup_autotune_caches_and_reuses(tmp_path):
 
 
 def test_program_key_carries_block_shape():
-    """Distinct block pairs are distinct compiled programs (re-tuning can
-    never mutate a compiled one), with identical outputs; the jnp path
-    ignores block shape entirely."""
-    ell = jnp.full((2, 16, 4), 16, jnp.int32)
-    ranks = jnp.full((2, 17), np.iinfo(np.int32).max, jnp.int32)
-    elig = jnp.zeros((2, 17), bool)
+    """Distinct lane-tile pairs are distinct compiled programs (re-tuning
+    can never mutate a compiled one), with identical outputs; block pairs
+    with one lane tile share one program; the jnp path ignores block shape
+    entirely."""
+    ell = jnp.full((2, 384, 4), 384, jnp.int32)
+    ranks = jnp.full((2, 385), np.iinfo(np.int32).max, jnp.int32)
+    elig = jnp.zeros((2, 385), bool)
     m = jnp.zeros((2,), jnp.int32)
     args = (ell, ranks, elig, m)
     before = exec_mod.program_cache_size()
     outs = [exec_mod.run_bucket_program(*args, k=2, use_kernel=True,
                                         block_rows=br)
-            for br in [(8, 8), (16, 16), None]]
+            for br in [(128, 128), (384, 384), None]]
     assert exec_mod.program_cache_size() - before == 3
     for got in outs[1:]:
         for a, b in zip(outs[0], got):
             assert (np.asarray(a) == np.asarray(b)).all()
-    # The probe resolves block shape identically to the run.
-    assert exec_mod.program_cache_contains((2, 16, 4), 2, use_kernel=True,
-                                           block_rows=(8, 8))
-    assert not exec_mod.program_cache_contains((2, 16, 4), 2,
+    # The probe resolves block shape identically to the run: (8, 8) and
+    # (100, 128) are the 128-lane program, (256, 256) the default one.
+    for br in [(128, 128), (8, 8), (100, 128), (512, 512), (256, 256)]:
+        assert exec_mod.program_cache_contains((2, 384, 4), 2,
                                                use_kernel=True,
-                                               block_rows=(4, 4))
+                                               block_rows=br)
+    assert not exec_mod.program_cache_contains((2, 384, 4), 2,
+                                               use_kernel=True,
+                                               block_rows=(128, 384))
     # use_kernel=False: block shape is normalized out of the key.
     before = exec_mod.program_cache_size()
-    exec_mod.run_bucket_program(*args, k=2, block_rows=(8, 8))
+    exec_mod.run_bucket_program(*args, k=2, block_rows=(128, 128))
     exec_mod.run_bucket_program(*args, k=2)
     assert exec_mod.program_cache_size() - before <= 1
 
@@ -232,23 +246,23 @@ def test_program_key_carries_block_shape():
 def test_tuned_cache_winner_drives_run_and_probe():
     """An untuned run and a tuned run of the same bucket are different
     programs, and the cost model's probe tracks the tuning cache."""
-    ell = jnp.full((2, 24, 4), 24, jnp.int32)
-    ranks = jnp.full((2, 25), np.iinfo(np.int32).max, jnp.int32)
-    elig = jnp.zeros((2, 25), bool)
+    ell = jnp.full((2, 320, 4), 320, jnp.int32)
+    ranks = jnp.full((2, 321), np.iinfo(np.int32).max, jnp.int32)
+    elig = jnp.zeros((2, 321), bool)
     m = jnp.zeros((2,), jnp.int32)
     args = (ell, ranks, elig, m)
     exec_mod.run_bucket_program(*args, k=1, use_kernel=True)
-    assert exec_mod.program_cache_contains((2, 24, 4), 1, use_kernel=True)
+    assert exec_mod.program_cache_contains((2, 320, 4), 1, use_kernel=True)
     for kern in at.KERNELS:
-        at.tuning_cache().put(kern, 24, 4, at.batch_tier(2), 8)
+        at.tuning_cache().put(kern, 320, 4, at.batch_tier(2), 128)
     # The tuned program is not resident yet; default resolution now points
     # at the tuned key.
-    assert not exec_mod.program_cache_contains((2, 24, 4), 1,
+    assert not exec_mod.program_cache_contains((2, 320, 4), 1,
                                                use_kernel=True)
     before = exec_mod.program_cache_size()
     exec_mod.run_bucket_program(*args, k=1, use_kernel=True)
     assert exec_mod.program_cache_size() - before == 1
-    assert exec_mod.program_cache_contains((2, 24, 4), 1, use_kernel=True)
+    assert exec_mod.program_cache_contains((2, 320, 4), 1, use_kernel=True)
 
 
 # --- bit-exactness: every candidate and the cached winner ------------------

@@ -464,9 +464,12 @@ def test_program_cache_counts_compiles():
 def test_warmup_builds_each_program_once(use_kernel):
     """Warmup compiles its programs ahead of their first run, side by
     side; jit reuses those executables, so XLA builds each program at
-    most once (fewer when a persistent cache holds it) and a second
-    warmup builds nothing."""
+    most once (fewer when a persistent cache holds it), a second warmup
+    builds nothing, and neither does serving the warmed graph — its
+    rank-permutation program included."""
     from jax._src import monitoring
+
+    from repro.core.mis import random_permutation_ranks_batch
 
     builds = []
 
@@ -476,16 +479,24 @@ def test_warmup_builds_each_program_once(use_kernel):
 
     # num_samples=3 and a width-8 bucket: programs no other test compiles.
     eng = ClusterBatcher(max_batch=4, num_samples=3, use_kernel=use_kernel)
+    g = _rand_graph(40, 2, 0)
+    # The rank program first, so the builds counted below are the bucket
+    # programs alone.
+    random_permutation_ranks_batch(g.n, sample_keys(jax.random.PRNGKey(0),
+                                                    3))
     monitoring.register_event_duration_secs_listener(on_duration)
     try:
-        g = _rand_graph(40, 2, 0)
         compiled = eng.warmup([g])
         first = len(builds)
         assert eng.warmup([g]) == 0
+        served = eng.admit(ClusterRequest(uid=0, graph=g,
+                                          key=jax.random.PRNGKey(9)))
+        served += eng.flush()
     finally:
         monitoring.unregister_event_duration_listener(on_duration)
     assert compiled == 3                   # pow2 sub-batches 1, 2, 4
     assert first <= compiled
+    assert len(served) == 1
     assert len(builds) == first
 
 
