@@ -168,12 +168,13 @@ def correlation_cluster_batch(
     # Dispatch every bucket before harvesting any: with an async or sharded
     # executor the host packs bucket i+1 while bucket i computes.
     handles: List[InFlightBucket] = []
-    for members in buckets.values():
+    for flush, members in enumerate(buckets.values()):
         bplans = [plans[gi] for gi in members]
         bkeys = [sample_keys(keys[gi], k) for gi in members]
         handle, bucket_stats = pack_and_submit(
             bplans, bkeys, k, ex, pool=pool, use_kernel=use_kernel,
-            payload=(members, bplans), track=False, objective=objective)
+            payload=(members, bplans), track=False, objective=objective,
+            flush=flush)
         handles.append(handle)
         stats.merge(bucket_stats)
 

@@ -63,7 +63,7 @@ import warnings
 from collections import OrderedDict, deque
 from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
-from functools import partial
+from functools import partial, update_wrapper
 from typing import Any, Callable, Deque, List, Optional, Protocol, Tuple, \
     runtime_checkable
 
@@ -73,7 +73,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.util import next_pow2
+from repro.util import next_pow2, span
 
 from .programs import IN_MIS, REMOVED, UNDECIDED, _gather_rows, \
     bucket_impl, method_spec, objective_spec
@@ -170,9 +170,11 @@ def _build_program(k: int, use_kernel: bool, donate: bool,
                    block_rows: Optional[Tuple[int, int]] = None,
                    program: str = "pivot",
                    objective: str = "disagree") -> Callable:
-    impl = partial(bucket_impl, k=k, use_kernel=use_kernel,
-                   block_rows=block_rows, program=program,
-                   objective=objective)
+    # The partial keeps its function's name, so XLA names the program
+    # ``jit_bucket_impl`` (a bare partial lowers as ``jit__unknown``).
+    impl = update_wrapper(partial(bucket_impl, k=k, use_kernel=use_kernel,
+                                  block_rows=block_rows, program=program,
+                                  objective=objective), bucket_impl)
     if mesh is not None:
         axis = mesh.axis_names[0]
         spec = P(axis)
@@ -379,7 +381,8 @@ def run_bucket_program(ell, ranks_p, elig_p, m_edges, k: int,
     if not fresh:
         return _invoke()
     t0 = time.perf_counter()
-    out = _invoke()
+    with span("compile", R=ell.shape[1], W=ell.shape[2]):
+        out = _invoke()
     _last_compile_wall = time.perf_counter() - t0
     _record_compile_wall(key, _last_compile_wall)
     return out
@@ -448,10 +451,11 @@ def compile_bucket_programs(shapes, k: int, use_kernel: bool = False,
         key, fn = entry
         b, r, w = key[0]
         t0 = time.perf_counter()
-        fn.lower(jax.ShapeDtypeStruct((b, r, w), jnp.int32),
-                 jax.ShapeDtypeStruct((b, r + 1), jnp.int32),
-                 jax.ShapeDtypeStruct((b, r + 1), jnp.bool_),
-                 jax.ShapeDtypeStruct((b,), jnp.int32)).compile()
+        with span("compile", R=r, W=w):
+            fn.lower(jax.ShapeDtypeStruct((b, r, w), jnp.int32),
+                     jax.ShapeDtypeStruct((b, r + 1), jnp.int32),
+                     jax.ShapeDtypeStruct((b, r + 1), jnp.bool_),
+                     jax.ShapeDtypeStruct((b,), jnp.int32)).compile()
         return key, time.perf_counter() - t0
 
     if fresh:
@@ -496,12 +500,14 @@ class InFlightBucket:
     time, filled in when the outputs are first fetched. The serving layer
     feeds these into its :class:`~repro.serve.scheduler.FlushTelemetry`
     so scheduling policies can adapt to observed flush latency.
+    ``flush`` is the submitter's ordinal of the flush (set by
+    :func:`pack_and_submit`), which names it on the harvest's span.
     """
 
     __slots__ = ("payload", "_outputs", "_fetched", "_lease",
                  "shape", "assemble_seconds", "submitted_at",
                  "wall_seconds", "inflight_at_submit", "compile_seconds",
-                 "method", "objective")
+                 "method", "objective", "flush")
 
     def __init__(self, outputs, payload: Any = None, lease=None,
                  shape: Optional[Tuple[int, ...]] = None,
@@ -529,11 +535,7 @@ class InFlightBucket:
         # Compile wall this flush paid (None on program-cache hits) — the
         # serving layer feeds these into the learned compile-cost stream.
         self.compile_seconds = compile_seconds
-
-    @property
-    def pack_seconds(self) -> float:
-        """Deprecated pre-PR-8 name of :attr:`assemble_seconds`."""
-        return self.assemble_seconds
+        self.flush: Optional[int] = None
 
     @property
     def harvested(self) -> bool:
@@ -637,11 +639,15 @@ class _QueueExecutor:
                method: str = "pivot",
                objective: str = "disagree") -> InFlightBucket:
         shape = tuple(int(s) for s in np.shape(ell))
+        nbytes = sum(a.nbytes for a in (ell, ranks_p, elig_p, m_edges))
         submitted_at = time.perf_counter()
-        outputs = run_bucket_program(ell, ranks_p, elig_p, m_edges, k=k,
-                                     use_kernel=use_kernel, donate=donate,
-                                     mesh=self.mesh, method=method,
-                                     objective=objective)
+        # Dispatch, starting the input transfer (which may finish after
+        # the span); ``bytes`` is what crosses to the device.
+        with span("submit", bytes=nbytes):
+            outputs = run_bucket_program(
+                ell, ranks_p, elig_p, m_edges, k=k, use_kernel=use_kernel,
+                donate=donate, mesh=self.mesh, method=method,
+                objective=objective)
         handle = InFlightBucket(outputs, payload=payload, lease=lease,
                                 shape=shape,
                                 assemble_seconds=assemble_seconds,
@@ -744,7 +750,8 @@ class ShardedExecutor(AsyncExecutor):
 
 def pack_and_submit(plans, group_keys, k: int, executor: "BucketExecutor",
                     pool=None, use_kernel: bool = False, payload: Any = None,
-                    track: bool = True, objective: str = "disagree"):
+                    track: bool = True, objective: str = "disagree",
+                    flush: int = 0):
     """Pack one bucket and dispatch it through an executor.
 
     The single lease → ``pack_bucket`` → ``submit`` sequence shared by
@@ -765,6 +772,9 @@ def pack_and_submit(plans, group_keys, k: int, executor: "BucketExecutor",
     (``GraphPlan.method``): one flush is one method by construction, so a
     mixed-method plan list is rejected here — the last line of defence
     behind the scheduler's cross-method steal refusal.
+
+    ``flush`` is the caller's ordinal of this flush: it rides on the
+    handle and names the flush's ``assemble`` span.
     """
     from .plan import estimate_pack_stats, pack_bucket
 
@@ -782,9 +792,10 @@ def pack_and_submit(plans, group_keys, k: int, executor: "BucketExecutor",
     lease = pool.acquire(b_pad, R, W) if pool is not None else None
     try:
         t_pack = time.perf_counter()
-        ell, ranks, elig, m_edges, _ = pack_bucket(
-            plans, group_keys, k=k, g_pad=g_pad,
-            staging=lease.arrays if lease is not None else None)
+        with span("assemble", flush=flush):
+            ell, ranks, elig, m_edges, _ = pack_bucket(
+                plans, group_keys, k=k, g_pad=g_pad,
+                staging=lease.arrays if lease is not None else None)
         assemble_seconds = time.perf_counter() - t_pack
         handle = executor.submit(
             ell, ranks, elig, m_edges, k=k, use_kernel=use_kernel,
@@ -796,6 +807,7 @@ def pack_and_submit(plans, group_keys, k: int, executor: "BucketExecutor",
         if lease is not None:
             lease.release()
         raise
+    handle.flush = flush
     # The same pure formula the serving cost model prices candidate
     # flushes with, so priced pads and reported pads can never drift.
     stats = estimate_pack_stats(plans, k, g_pad=g_pad)

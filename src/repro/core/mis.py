@@ -51,7 +51,10 @@ def _perm_ranks_batch_for(n: int):
     # One jitted vmap per vertex count, held in a bounded LRU: a long-lived
     # server seeing arbitrarily many distinct n must not accumulate one
     # resident executable per size forever (evicted sizes just recompile).
-    return jax.jit(jax.vmap(lambda k: random_permutation_ranks(n, k)))
+    def rank_draw(key):
+        return random_permutation_ranks(n, key)
+
+    return jax.jit(jax.vmap(rank_draw))
 
 
 @lru_cache(maxsize=1024)
@@ -59,7 +62,10 @@ def _perm_ranks_single_for(n: int):
     # k=1 fastpath: the broadcast to a (1, n) batch happens inside the
     # trace, so a single-sample caller pays one dispatch instead of a host
     # jnp.stack plus the vmapped call. Bit-identical to the batch of one.
-    return jax.jit(lambda k: random_permutation_ranks(n, k)[None])
+    def rank_draw(key):
+        return random_permutation_ranks(n, key)[None]
+
+    return jax.jit(rank_draw)
 
 
 def random_permutation_ranks_batch(n: int, keys) -> jax.Array:

@@ -52,7 +52,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from repro.util import next_pow2
+from repro.util import next_pow2, span
 
 from .arboricity import arboricity_bounds
 from .degree_cap import degree_threshold
@@ -130,7 +130,8 @@ def plan_graph(g: Graph, method: str = "pivot", eps: float = 2.0,
     n = g.n
     if spec.degree_cap:
         if lam is None:
-            _, lam = arboricity_bounds(g, exact=n <= 200_000)
+            with span("degeneracy", n=n):
+                _, lam = arboricity_bounds(g, exact=n <= 200_000)
         threshold = degree_threshold(lam, eps)
         eligible = ~(np.asarray(g.deg) > threshold)
     else:
@@ -234,7 +235,10 @@ class PackedRows:
         if self._ranks is None:
             out = np.full((self.k, self.R + 1), _INT32_MAX, dtype=np.int32)
             if self._ranks_dev is not None:
-                out[:, : self.n] = np.asarray(self._ranks_dev)
+                # Blocks until the rank program ran: on one device it
+                # queues behind every program dispatched before it.
+                with span("rank_wait"):
+                    out[:, : self.n] = np.asarray(self._ranks_dev)
                 self._ranks_dev = None
             self._ranks = out
         return self._ranks

@@ -132,7 +132,7 @@ from repro.core.plan import (GraphFingerprint, GraphPlan,
                              build_packed_rows, graph_fingerprint,
                              promote_plan, result_for_plan)
 from repro.core.programs import method_spec, objective_spec
-from repro.util import next_pow2
+from repro.util import next_pow2, span
 
 from .engine import AdmissionRejected, EngineStats
 from .resultcache import ResultCacheStats, make_result_cache
@@ -335,21 +335,28 @@ class ClusterBatcher:
         protects. Subscribers never appear in the bucket queues, so
         policies cannot double-count them in queue depth or ages.
         """
+        with span("admit", uid=req.uid):
+            return self._admit(req, now)
+
+    def _admit(self, req: ClusterRequest,
+               now: Optional[float]) -> List[ClusterRequest]:
         self._harvest()
         now = self.clock() if now is None else now
         if req.plan is None:
             # Resolved once; a retry after AdmissionRejected (and the
             # flush itself) reuses the plan verbatim.
-            req.plan = self._plan_for(req.graph, lam=req.lam,
-                                      method=req.method)
+            with span("plan", uid=req.uid):
+                req.plan = self._plan_for(req.graph, lam=req.lam,
+                                          method=req.method)
             req.lam = req.plan.lam
         plan = req.plan
         if self.result_cache is not None:
             if req.fingerprint is None:
-                req.fingerprint = graph_fingerprint(
-                    plan, req.key, method=plan.method,
-                    num_samples=self.num_samples, eps=self.eps,
-                    objective=self.objective)
+                with span("fingerprint", uid=req.uid):
+                    req.fingerprint = graph_fingerprint(
+                        plan, req.key, method=plan.method,
+                        num_samples=self.num_samples, eps=self.eps,
+                        objective=self.objective)
             cached = self.result_cache.get(req.fingerprint)
             if cached is not None:
                 req.admitted_at = now
@@ -381,8 +388,9 @@ class ClusterBatcher:
             # the cache/single-flight/backpressure gates: only requests
             # that will actually pack pay the build.
             t_build = time.perf_counter()
-            plan.rows = build_packed_rows(
-                plan, sample_keys(req.key, self.num_samples))
+            with span("rows", uid=req.uid):
+                plan.rows = build_packed_rows(
+                    plan, sample_keys(req.key, self.num_samples))
             self.stats.latency.record_build(
                 plan.queue_key, time.perf_counter() - t_build)
         self.buckets.setdefault(plan.queue_key, []).append(req)
@@ -697,39 +705,11 @@ class ClusterBatcher:
         all_reqs = reqs + stolen
         if not all_reqs:
             return None
-        k = self.num_samples
-        method, R, W = decision.bucket
-        bad = next((r for r in all_reqs if r.plan.method != method), None)
-        if bad is not None:
-            # The built-in policies never propose this (their steal filters
-            # require queue_key method equality); a custom policy that does
-            # is refused here with the requests safely requeued — a bucket
-            # program runs exactly one registered method per flush.
-            self._requeue(all_reqs)
-            raise ValueError(
-                f"flush decision for method {method!r} names a "
-                f"{bad.plan.method!r} request: a bucket program runs "
-                "exactly one registered method — cross-method "
-                "coalescing/stealing is refused")
-        # Promotion is a no-op for native requests; for stolen ones it
-        # re-targets the plan at the flush's larger shape (bit-exact),
-        # relaying any prebuilt rows via pad-copies. Prebuilt plans drew
-        # their rank permutations at admission, so no sample keys are
-        # derived for them here — that fold_in work is off the flush path.
-        plans = [promote_plan(r.plan, R, W) for r in all_reqs]
-        bkeys = [None if p.rows is not None else sample_keys(r.key, k)
-                 for r, p in zip(all_reqs, plans)]
-        try:
-            _, pack = pack_and_submit(
-                plans, bkeys, k, self.executor, pool=self.pool,
-                use_kernel=self.use_kernel, payload=all_reqs,
-                objective=self.objective)
-        except BaseException:
-            # Nothing was dispatched (the helper released the staging
-            # lease): requeue the popped requests so none are lost, then
-            # surface the error to the caller.
-            self._requeue(all_reqs)
-            raise
+        # The flush's ordinal: how many flushes this engine submitted
+        # before it (a failed attempt and its retry share one).
+        ordinal = self.stats.flushes
+        with span("flush", flush=ordinal, requests=len(all_reqs)):
+            pack = self._submit(decision, all_reqs, ordinal)
         self._in_flight_reqs += len(all_reqs)
         self.stats.flushes += 1
         if decision.deadline:
@@ -743,6 +723,46 @@ class ClusterBatcher:
         self.stats.in_flight_peak = max(self.stats.in_flight_peak,
                                         self.executor.in_flight)
         return self._harvest(defer=True)
+
+    def _submit(self, decision: FlushDecision,
+                reqs: List[ClusterRequest], ordinal: int):
+        """Promote, pack and dispatch one flush's popped requests; returns
+        its :class:`~repro.core.plan.PackStats`. On any error nothing was
+        dispatched and the requests are back in their queues."""
+        k = self.num_samples
+        method, R, W = decision.bucket
+        bad = next((r for r in reqs if r.plan.method != method), None)
+        if bad is not None:
+            # The built-in policies never propose this (their steal filters
+            # require queue_key method equality); a custom policy that does
+            # is refused here with the requests safely requeued — a bucket
+            # program runs exactly one registered method per flush.
+            self._requeue(reqs)
+            raise ValueError(
+                f"flush decision for method {method!r} names a "
+                f"{bad.plan.method!r} request: a bucket program runs "
+                "exactly one registered method — cross-method "
+                "coalescing/stealing is refused")
+        # Promotion is a no-op for native requests; for stolen ones it
+        # re-targets the plan at the flush's larger shape (bit-exact),
+        # relaying any prebuilt rows via pad-copies. Prebuilt plans drew
+        # their rank permutations at admission, so no sample keys are
+        # derived for them here — that fold_in work is off the flush path.
+        plans = [promote_plan(r.plan, R, W) for r in reqs]
+        bkeys = [None if p.rows is not None else sample_keys(r.key, k)
+                 for r, p in zip(reqs, plans)]
+        try:
+            _, pack = pack_and_submit(
+                plans, bkeys, k, self.executor, pool=self.pool,
+                use_kernel=self.use_kernel, payload=reqs,
+                objective=self.objective, flush=ordinal)
+        except BaseException:
+            # Nothing was dispatched (the helper released the staging
+            # lease): requeue the popped requests so none are lost, then
+            # surface the error to the caller.
+            self._requeue(reqs)
+            raise
+        return pack
 
     def _deliver(self, req: ClusterRequest, labels_row: np.ndarray,
                  cost: int, picked: int, rounds: int) -> None:
@@ -777,54 +797,60 @@ class ClusterBatcher:
         handles = self.executor.drain() if block else self.executor.retire()
         first_err: Optional[BaseException] = None
         for handle in handles:
-            reqs = handle.payload
-            try:
-                labels, costs, picked, rounds = handle.result()
-            except BaseException as err:
-                self._in_flight_reqs -= len(reqs)
-                if reqs:
-                    self._requeue(reqs)
-                if first_err is None:
-                    first_err = err
-                continue
-            for slot, req in enumerate(reqs):
-                row = labels[slot]
-                cost, pick = int(costs[slot]), int(picked[slot])
-                depth = int(rounds[slot])
-                self._deliver(req, row, cost, pick, depth)
-                self.stats.clustered += 1
-                if req.subscribers:
-                    subs, req.subscribers = req.subscribers, []
-                    for sub in subs:
-                        # Same device row, the subscriber's own plan —
-                        # identical content by fingerprint equality, so
-                        # the result is bit-identical to a cold flush.
-                        self._deliver(sub, row, cost, pick, depth)
-                        self.stats.clustered += 1
-                        self._subscribed_pending -= 1
-                if req.fingerprint is not None:
-                    self._single_flight.pop(req.fingerprint.digest, None)
-                    if self.result_cache is not None:
-                        self.result_cache.put(
-                            req.fingerprint, row[: req.plan.n],
-                            cost, pick, depth)
-            self._in_flight_reqs -= len(reqs)
-            if handle.shape is not None and handle.wall_seconds is not None:
-                bucket = (handle.method, handle.shape[1], handle.shape[2])
-                self.stats.latency.record(bucket, handle.wall_seconds,
-                                          handle.assemble_seconds,
-                                          depth=handle.inflight_at_submit,
-                                          compile_s=handle.compile_seconds)
-                if handle.compile_seconds is not None:
-                    # Program-cache miss: feed the observed compile wall
-                    # into the learned compile-cost stream.
-                    self.stats.latency.record_compile(
-                        bucket, handle.compile_seconds)
-                self.policy.on_retire(bucket, self.stats.latency)
+            with span("harvest", flush=handle.flush):
+                err = self._harvest_one(handle)
+            first_err = first_err or err
         if defer:
             return first_err
         if first_err is not None:
             raise first_err
+        return None
+
+    def _harvest_one(self, handle) -> Optional[BaseException]:
+        """Deliver one finished flush (see :meth:`_harvest`); returns the
+        error its fetch raised, its requests requeued, or None."""
+        reqs = handle.payload
+        try:
+            labels, costs, picked, rounds = handle.result()
+        except BaseException as err:
+            self._in_flight_reqs -= len(reqs)
+            if reqs:
+                self._requeue(reqs)
+            return err
+        for slot, req in enumerate(reqs):
+            row = labels[slot]
+            cost, pick = int(costs[slot]), int(picked[slot])
+            depth = int(rounds[slot])
+            self._deliver(req, row, cost, pick, depth)
+            self.stats.clustered += 1
+            if req.subscribers:
+                subs, req.subscribers = req.subscribers, []
+                for sub in subs:
+                    # Same device row, the subscriber's own plan —
+                    # identical content by fingerprint equality, so the
+                    # result is bit-identical to a cold flush.
+                    self._deliver(sub, row, cost, pick, depth)
+                    self.stats.clustered += 1
+                    self._subscribed_pending -= 1
+            if req.fingerprint is not None:
+                self._single_flight.pop(req.fingerprint.digest, None)
+                if self.result_cache is not None:
+                    self.result_cache.put(
+                        req.fingerprint, row[: req.plan.n],
+                        cost, pick, depth)
+        self._in_flight_reqs -= len(reqs)
+        if handle.shape is not None and handle.wall_seconds is not None:
+            bucket = (handle.method, handle.shape[1], handle.shape[2])
+            self.stats.latency.record(bucket, handle.wall_seconds,
+                                      handle.assemble_seconds,
+                                      depth=handle.inflight_at_submit,
+                                      compile_s=handle.compile_seconds)
+            if handle.compile_seconds is not None:
+                # Program-cache miss: feed the observed compile wall into
+                # the learned compile-cost stream.
+                self.stats.latency.record_compile(
+                    bucket, handle.compile_seconds)
+            self.policy.on_retire(bucket, self.stats.latency)
         return None
 
     # -- Back-compat aliases (pre-engine API) ------------------------------

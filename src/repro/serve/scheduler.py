@@ -265,8 +265,8 @@ class FlushTelemetry:
 
     @property
     def ewma_assemble(self) -> Optional[float]:
-        """EWMA host bucket-assembly seconds per flush across all buckets
-        (the pre-PR-8 ``ewma_pack``)."""
+        """EWMA host bucket-assembly seconds per flush across all
+        buckets."""
         return self._ewma_assemble
 
     @property
@@ -274,11 +274,6 @@ class FlushTelemetry:
         """EWMA per-request admission-time row-build seconds (None until
         a prebuilt admission is recorded)."""
         return self._ewma_build
-
-    @property
-    def ewma_pack(self) -> Optional[float]:
-        """Deprecated pre-PR-8 name of :attr:`ewma_assemble`."""
-        return self._ewma_assemble
 
     def bucket_ewma_wall(self, bucket: BucketKey) -> Optional[float]:
         rec = self._per_bucket.get(bucket)

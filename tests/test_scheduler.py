@@ -928,8 +928,6 @@ def test_flush_latency_telemetry_reaches_stats():
     assert tele.total_flushes == batcher.stats.flushes == 2
     assert tele.ewma_wall is not None and tele.ewma_wall >= 0.0
     assert tele.ewma_assemble is not None and tele.ewma_assemble >= 0.0
-    # Deprecated pre-split alias must keep answering with the new stream.
-    assert tele.ewma_pack == tele.ewma_assemble
     # Default engine prebuilds rows at admission: one build per request,
     # in its own telemetry stream, off every flush's wall.
     assert tele.total_builds == 4
