@@ -165,14 +165,28 @@ def _key_bucket(key: tuple) -> Tuple[int, int]:
     return (shape[1], shape[2])
 
 
+def _bucket_and_tiles(ell, ranks_p, elig_p, m_edges, **static):
+    """:func:`bucket_impl`'s outputs and, per batch entry, the ELL tiles
+    the kernels sweep and all of them (``kernels.neighbor_min.
+    tile_counts``), from the one layout the program prepares."""
+    from repro.kernels.neighbor_min import prepare_ell, tile_counts
+
+    layout = prepare_ell(ell) if static["use_kernel"] else None
+    outs = bucket_impl(ell, ranks_p, elig_p, m_edges, layout=layout,
+                       **static)
+    return (*outs, tile_counts(ell, layout))
+
+
 def _build_program(k: int, use_kernel: bool, donate: bool,
                    mesh: Optional[Mesh],
                    block_rows: Optional[Tuple[int, int]] = None,
                    program: str = "pivot",
                    objective: str = "disagree") -> Callable:
-    # The partial keeps its function's name, so XLA names the program
-    # ``jit_bucket_impl`` (a bare partial lowers as ``jit__unknown``).
-    impl = update_wrapper(partial(bucket_impl, k=k, use_kernel=use_kernel,
+    # The partial takes the bucket program's name, so XLA names the
+    # program ``jit_bucket_impl`` (a bare partial lowers as
+    # ``jit__unknown``).
+    impl = update_wrapper(partial(_bucket_and_tiles, k=k,
+                                  use_kernel=use_kernel,
                                   block_rows=block_rows, program=program,
                                   objective=objective), bucket_impl)
     if mesh is not None:
@@ -182,7 +196,7 @@ def _build_program(k: int, use_kernel: bool, donate: bool,
         # inputs; nothing is replicated for the checker to verify.
         impl = jax.shard_map(impl, mesh=mesh,
                              in_specs=(spec, spec, spec, spec),
-                             out_specs=(spec, spec, spec, spec),
+                             out_specs=(spec,) * 5,
                              check_vma=False)
     return jax.jit(impl, donate_argnums=(0, 1, 2, 3) if donate else ())
 
@@ -502,6 +516,9 @@ class InFlightBucket:
     so scheduling policies can adapt to observed flush latency.
     ``flush`` is the submitter's ordinal of the flush (set by
     :func:`pack_and_submit`), which names it on the harvest's span.
+    ``ell_tiles`` is, once fetched, ``(swept, full)``: the ELL tiles the
+    program's kernels swept per call and all of them, summed over the
+    batch entries.
     """
 
     __slots__ = ("payload", "_outputs", "_fetched", "_lease",
@@ -551,6 +568,13 @@ class InFlightBucket:
             return True
         return all(o.is_ready() for o in self._outputs)
 
+    @property
+    def ell_tiles(self) -> Optional[Tuple[int, int]]:
+        if self._fetched is None:
+            return None
+        swept, full = self._fetched[4].sum(axis=0)
+        return int(swept), int(full)
+
     def result(self) -> Tuple[np.ndarray, ...]:
         """(labels, costs, picked, rounds) as numpy; blocks if needed.
 
@@ -572,7 +596,7 @@ class InFlightBucket:
                 if self._lease is not None:
                     self._lease.release()
                     self._lease = None
-        return self._fetched
+        return self._fetched[:4]
 
 
 @runtime_checkable

@@ -14,12 +14,14 @@ what it inherits for free:
 
 A method provides exactly one traced function, ``rounds_body``::
 
-    rounds_body(ell, ranks_p, elig_p, *, use_kernel, nm_rows)
+    rounds_body(ell, ranks_p, elig_p, *, use_kernel, nm_rows, layout)
         -> (labels (B, R) int32, rounds (B,) int32)
 
 over the shared packed tensors: ``ell`` the (B, R, W) int32 ELL adjacency
 (pad id ``R``), ``ranks_p`` the (B, R+1) int32 rank rows (slot R = INF),
-``elig_p`` the (B, R+1) bool eligibility rows (slot R False). It must
+``elig_p`` the (B, R+1) bool eligibility rows (slot R False); on the
+kernel path ``layout`` is ``ell`` prepared once for the ragged kernels
+(:func:`repro.kernels.neighbor_min.prepare_ell`), else None. It must
 label ineligible and padded vertices with their own index (singletons) so
 the cost identity and result slicing hold, and report a per-entry
 ``rounds`` counter (its notion of parallel depth). Everything else is
@@ -33,7 +35,7 @@ LRU, staging leases, donation, sharding, and the whole serving layer.
 
 An objective provides one traced function, ``cost_pass``::
 
-    cost_pass(ell, labels, m_edges, *, use_kernel, la_rows)
+    cost_pass(ell, labels, m_edges, *, use_kernel, la_rows, layout)
         -> costs (B,) int32
 
 scored per batch entry *before* best-of-k selection, so the argmin picks
@@ -109,11 +111,11 @@ def _gather_rows(table: jnp.ndarray, ell: jnp.ndarray) -> jnp.ndarray:
 
 
 def _pivot_rounds_body(ell, ranks_p, elig_p, *, use_kernel: bool,
-                       nm_rows: Optional[int]):
+                       nm_rows: Optional[int], layout=None):
     """MIS rounds (``lax.while_loop``) + PIVOT capture — the paper's method.
 
-    Verbatim the pre-registry fused pipeline, so the 'pivot' program family
-    stays bit- and trace-identical to every earlier release.
+    On the kernel path every round's two calls and the capture sweep the
+    one ``layout`` prepared before the loop.
     """
     B, R, W = ell.shape
     ranks = ranks_p[:, :R]
@@ -129,9 +131,10 @@ def _pivot_rounds_body(ell, ranks_p, elig_p, *, use_kernel: bool,
             from repro.kernels import ops as _kops  # kernels stay optional
 
             if nm_rows is not None:
-                return _kops.neighbor_min_ell_batch(ell, ranks_p, active_p,
+                return _kops.neighbor_min_ell_batch(layout, ranks_p,
+                                                    active_p,
                                                     block_rows=nm_rows)
-            return _kops.neighbor_min_ell_batch(ell, ranks_p, active_p)
+            return _kops.neighbor_min_ell_batch(layout, ranks_p, active_p)
         act = _gather_rows(active_p, ell)
         return jnp.min(jnp.where(act, nbr_ranks, INF_RANK), axis=2)
 
@@ -173,7 +176,7 @@ def _pivot_rounds_body(ell, ranks_p, elig_p, *, use_kernel: bool,
 
 
 def _precluster_rounds_body(ell, ranks_p, elig_p, *, use_kernel: bool,
-                            nm_rows: Optional[int]):
+                            nm_rows: Optional[int], layout=None):
     """Constant-round pre-clustering by neighbourhood agreement.
 
     Three straight-line stages, no data-dependent loop:
@@ -228,18 +231,22 @@ def _precluster_rounds_body(ell, ranks_p, elig_p, *, use_kernel: bool,
     agree = real & (BETA_DEN * sym_diff
                     < BETA_NUM * (jnp.maximum(du, dv) + 1))
     agree_ell = jnp.where(agree, ell, R)
+    if use_kernel:
+        from repro.kernels import ops as _kops  # kernels stay optional
+        from repro.kernels.neighbor_min import prepare_ell
+
+        agree_layout = prepare_ell(agree_ell)
 
     def agree_min(state: jnp.ndarray) -> jnp.ndarray:
         state_p = jnp.concatenate(
             [state, jnp.full((B, 1), INF_RANK, jnp.int32)], axis=1)
         if use_kernel:
-            from repro.kernels import ops as _kops  # kernels stay optional
-
             if nm_rows is not None:
-                return _kops.neighbor_min_ell_batch(agree_ell, state_p,
+                return _kops.neighbor_min_ell_batch(agree_layout, state_p,
                                                     elig_p,
                                                     block_rows=nm_rows)
-            return _kops.neighbor_min_ell_batch(agree_ell, state_p, elig_p)
+            return _kops.neighbor_min_ell_batch(agree_layout, state_p,
+                                                elig_p)
         act = _gather_rows(elig_p, agree_ell)
         vals = _gather_rows(state_p, agree_ell)
         return jnp.min(jnp.where(act, vals, INF_RANK), axis=2)
@@ -269,8 +276,9 @@ def _precluster_rounds_body(ell, ranks_p, elig_p, *, use_kernel: bool,
 
 
 def _label_agree_counts(ell, labels, *, use_kernel: bool,
-                        la_rows: Optional[int]) -> jnp.ndarray:
-    """(B, R) per-vertex same-label neighbour counts over the packed ELL."""
+                        la_rows: Optional[int], layout) -> jnp.ndarray:
+    """(B, R) per-vertex same-label neighbour counts over the packed ELL
+    (its prepared ``layout`` on the kernel path)."""
     B, R, W = ell.shape
     labels_p = jnp.concatenate(
         [labels, jnp.full((B, 1), -1, jnp.int32)], axis=1)
@@ -278,9 +286,9 @@ def _label_agree_counts(ell, labels, *, use_kernel: bool,
         from repro.kernels import ops as _kops
 
         if la_rows is not None:
-            return _kops.label_agree_ell_batch(ell, labels_p,
+            return _kops.label_agree_ell_batch(layout, labels_p,
                                                block_rows=la_rows)
-        return _kops.label_agree_ell_batch(ell, labels_p)
+        return _kops.label_agree_ell_batch(layout, labels_p)
     nbr_lab = _gather_rows(labels_p, ell)
     return jnp.sum((nbr_lab == labels[:, :, None]).astype(jnp.int32), axis=2)
 
@@ -292,7 +300,7 @@ def _cluster_sizes(labels: jnp.ndarray) -> jnp.ndarray:
 
 
 def _disagree_cost_pass(ell, labels, m_edges, *, use_kernel: bool,
-                        la_rows: Optional[int]) -> jnp.ndarray:
+                        la_rows: Optional[int], layout=None) -> jnp.ndarray:
     """Total disagreement count — the paper's objective.
 
     Every kept (eligible-induced) undirected edge appears twice in the
@@ -302,7 +310,7 @@ def _disagree_cost_pass(ell, labels, m_edges, *, use_kernel: bool,
       cost = (m − intra_pos) + (intra_pairs − intra_pos).
     """
     agree = _label_agree_counts(ell, labels, use_kernel=use_kernel,
-                                la_rows=la_rows)
+                                la_rows=la_rows, layout=layout)
     intra_pos2 = jnp.sum(agree, axis=1)
     sizes = _cluster_sizes(labels)
     intra_pairs = jnp.sum(sizes * (sizes - 1) // 2, axis=1)
@@ -310,7 +318,7 @@ def _disagree_cost_pass(ell, labels, m_edges, *, use_kernel: bool,
 
 
 def _minmax_cost_pass(ell, labels, m_edges, *, use_kernel: bool,
-                      la_rows: Optional[int]) -> jnp.ndarray:
+                      la_rows: Optional[int], layout=None) -> jnp.ndarray:
     """Worst-vertex disagreement (min-max objective, arXiv 2502.12519).
 
     Per vertex v: cut positive edges (deg(v) − samelabel(v)) plus missing
@@ -322,7 +330,7 @@ def _minmax_cost_pass(ell, labels, m_edges, *, use_kernel: bool,
     """
     B, R, W = ell.shape
     agree = _label_agree_counts(ell, labels, use_kernel=use_kernel,
-                                la_rows=la_rows)
+                                la_rows=la_rows, layout=layout)
     deg = jnp.sum(ell != R, axis=2).astype(jnp.int32)
     sizes = _cluster_sizes(labels)
     size_of = jnp.take_along_axis(sizes, labels, axis=1)
@@ -444,7 +452,7 @@ register_objective(ObjectiveSpec(
 
 def bucket_impl(ell, ranks_p, elig_p, m_edges, k: int, use_kernel: bool,
                 block_rows: Optional[Tuple[int, int]],
-                program: str, objective: str):
+                program: str, objective: str, layout=None):
     """Cluster + cost + select every graph of one shape bucket on device.
 
     ``rounds_body × cost_pass`` composed with the shared best-of-k argmin
@@ -452,16 +460,23 @@ def bucket_impl(ell, ranks_p, elig_p, m_edges, k: int, use_kernel: bool,
     same rule as the host loop's strict ``<`` — only winners cross back to
     the host. ``program`` is a program *family* name; resolution through
     the method registry happens in the executor so two methods of one
-    family compile (and cache) identical programs.
+    family compile (and cache) identical programs. On the kernel path the
+    ELL is prepared for the ragged kernels once, here, unless the caller
+    passes its ``layout``.
     """
     spec = _METHODS[program]
     obj = _OBJECTIVES[objective]
     B, R, W = ell.shape
     nm_rows, la_rows = block_rows if block_rows is not None else (None, None)
+    if use_kernel and layout is None:
+        from repro.kernels.neighbor_min import prepare_ell
+
+        layout = prepare_ell(ell)
     labels, rounds = spec.rounds_body(ell, ranks_p, elig_p,
-                                      use_kernel=use_kernel, nm_rows=nm_rows)
+                                      use_kernel=use_kernel, nm_rows=nm_rows,
+                                      layout=layout)
     costs = obj.cost_pass(ell, labels, m_edges, use_kernel=use_kernel,
-                          la_rows=la_rows)
+                          la_rows=la_rows, layout=layout)
     G = B // k
     cost_g = costs.reshape(G, k)
     picked = jnp.argmin(cost_g, axis=1).astype(jnp.int32)

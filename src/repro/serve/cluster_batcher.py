@@ -165,6 +165,10 @@ class ClusterStats(EngineStats):
     stolen_requests: int = 0     # requests promoted into a larger bucket
     clustered: int = 0
     padded_slots: int = 0        # empty device entries, from the packer
+    # ELL tiles (8 rows × 128 vertices) the harvested flushes' kernels
+    # swept per call, and all of them: the ragged sweep's share of work.
+    ell_tiles_swept: int = 0
+    ell_tiles_full: int = 0
     pad_vertex_waste: int = 0    # Σ (R − n) over clustered graphs
     buckets_seen: int = 0        # distinct (method, R, W) queues admitted
     rejected: int = 0            # admissions refused by backpressure
@@ -797,8 +801,12 @@ class ClusterBatcher:
         handles = self.executor.drain() if block else self.executor.retire()
         first_err: Optional[BaseException] = None
         for handle in handles:
-            with span("harvest", flush=handle.flush):
+            with span("harvest", flush=handle.flush) as harvest:
                 err = self._harvest_one(handle)
+                if handle.ell_tiles is not None:
+                    swept, full = handle.ell_tiles
+                    harvest.set_metadata(ell_tiles_swept=swept,
+                                         ell_tiles_full=full)
             first_err = first_err or err
         if defer:
             return first_err
@@ -817,6 +825,9 @@ class ClusterBatcher:
             if reqs:
                 self._requeue(reqs)
             return err
+        swept, full = handle.ell_tiles
+        self.stats.ell_tiles_swept += swept
+        self.stats.ell_tiles_full += full
         for slot, req in enumerate(reqs):
             row = labels[slot]
             cost, pick = int(costs[slot]), int(picked[slot])

@@ -625,3 +625,75 @@ def test_engine_close_is_pin_refcount_idempotent():
     b.close()
     assert (8, 4) not in exec_mod.program_cache_info()["pinned"]
     b.close()                    # close after the pin is gone: still safe
+
+
+# ---------------------------------------------------------------------------
+# Ragged kernels inside the bucket programs, on a power-law graph.
+# ---------------------------------------------------------------------------
+
+
+def _kronecker(scale, edge_factor, rng, a=0.57, b=0.19, c=0.19):
+    """Graph500 Kronecker edges (specification section 3): ``scale``
+    quadrant draws per edge, then vertex labels permuted."""
+    m = edge_factor << scale
+    c_norm, a_norm = c / (1.0 - a - b), a / (a + b)
+    ij = np.zeros((m, 2), dtype=np.int64)
+    for bit in range(scale):
+        ii = rng.random(m) > a + b
+        jj = rng.random(m) > np.where(ii, c_norm, a_norm)
+        ij[:, 0] += ii.astype(np.int64) << bit
+        ij[:, 1] += jj.astype(np.int64) << bit
+    return rng.permutation(1 << scale)[ij]
+
+
+@pytest.fixture(scope="module")
+def kron_graph():
+    return build_graph(1 << 9, _kronecker(9, 16, np.random.default_rng(9)))
+
+
+def _kron_pack(graph, method, k=2):
+    plan = plan_graph(graph, method=method)
+    keys = [sample_keys(jax.random.PRNGKey(3), k)]
+    return pack_bucket([plan], keys, k=k)[:4]
+
+
+@pytest.mark.parametrize("objective", ["disagree", "minmax"])
+@pytest.mark.parametrize("method", ["pivot", "pivot_raw", "precluster"])
+def test_ragged_kernel_program_bit_equal_to_jnp(kron_graph, method,
+                                                objective):
+    """``bucket_impl`` on the kernel path (degree-ordered, ragged sweep)
+    equals the jnp path bit for bit: labels, costs, picks and rounds."""
+    import functools
+
+    from repro.core.programs import bucket_impl, method_spec
+
+    args = _kron_pack(kron_graph, method)
+    outs = [jax.jit(functools.partial(
+        bucket_impl, k=2, use_kernel=use_kernel, block_rows=None,
+        program=method_spec(method).program, objective=objective))(*args)
+        for use_kernel in (False, True)]
+    for jnp_out, kern_out in zip(*outs):
+        assert (np.asarray(jnp_out) == np.asarray(kern_out)).all()
+
+
+def test_swept_tiles_reach_stats(kron_graph):
+    """The program counts the ELL tiles its kernels sweep — per entry,
+    Σ over 128-lane groups of ceil(widest row / 8) once the rows are
+    ordered by width — and all of them, R_lanes/128 · W/8; the harvest
+    adds both to ``ClusterBatcher.stats``."""
+    k = 2
+    ell = np.asarray(_kron_pack(kron_graph, "pivot", k)[0])
+    b, r, w = ell.shape
+    width = np.where(ell < r, np.arange(1, w + 1), 0).max(axis=2)
+    swept = sum(-(-int(np.sort(row)[::-1][g]) // 8)
+                for row in width for g in range(0, r, 128))
+    full = b * (-(-r // 128)) * (w // 8)
+    assert swept < full
+
+    eng = ClusterBatcher(max_batch=1, num_samples=k, use_kernel=True)
+    done = eng.admit(ClusterRequest(uid=0, graph=kron_graph,
+                                    key=jax.random.PRNGKey(3)))
+    done += eng.flush()
+    assert len(done) == 1
+    assert (eng.stats.ell_tiles_swept, eng.stats.ell_tiles_full) \
+        == (swept, full)

@@ -195,6 +195,144 @@ def test_interpret_mode_resolved_once():
     assert ops.interpret_mode() == (jax.default_backend() != "tpu")
 
 
+# --- ragged, degree-ordered sweep -------------------------------------------
+
+def _ell_of_degrees(degs, r, w, rng):
+    """(R, W) ELL whose row v holds ``degs[v]`` random distinct ids < R in
+    its first slots, pad id R after them."""
+    ell = np.full((r, w), r, np.int32)
+    for v, d in enumerate(degs):
+        ell[v, :d] = rng.choice(r, size=d, replace=False)
+    return ell
+
+
+def _power_law(r, w, rng, hubs=8):
+    """Zipf-like degrees with a few hubs at random rows (permuted labels)."""
+    degs = np.minimum(rng.zipf(1.8, size=r) - 1, w)
+    degs[rng.choice(r, size=hubs, replace=False)] = rng.integers(
+        w // 2, w + 1, size=hubs)
+    return degs
+
+
+def _ragged_case(case, rng):
+    """(B, R, W) ELL of one named degree profile for the ragged kernels."""
+    if case == "skewed_permuted":
+        r, w = 1024, 128
+        return np.stack([_ell_of_degrees(_power_law(r, w, rng), r, w, rng)])
+    if case == "empty_rows":
+        r, w = 256, 16
+        degs = np.where(rng.random(r) < 0.7, 0, rng.integers(1, w + 1, r))
+        return np.stack([_ell_of_degrees(degs, r, w, rng)])
+    if case == "mid_row_pads":
+        r, w = 256, 32
+        ells = [_ell_of_degrees(_power_law(r, w, rng), r, w, rng)
+                for _ in range(2)]
+        ell = np.stack(ells)
+        return np.where(rng.random(ell.shape) < 0.5, ell, r).astype(np.int32)
+    if case == "r_200":
+        r, w = 200, 16
+        return np.stack([_ell_of_degrees(_power_law(r, w, rng), r, w, rng)
+                         for _ in range(2)])
+    if case == "batch_profiles":
+        r, w = 384, 64
+        return np.stack([_ell_of_degrees(np.full(r, w), r, w, rng),
+                         _ell_of_degrees(_power_law(r, w, rng), r, w, rng),
+                         _ell_of_degrees(np.zeros(r, int), r, w, rng)])
+    if case == "promoted_w":
+        r, w, wreq = 256, 64, 20
+        return np.stack([_ell_of_degrees(_power_law(r, wreq, rng), r, w,
+                                         rng)])
+    if case == "row_of_degree_w":
+        r, w = 256, 32
+        degs = rng.integers(0, 4, size=r)
+        degs[rng.integers(r)] = w
+        return np.stack([_ell_of_degrees(degs, r, w, rng)])
+    raise ValueError(case)
+
+
+RAGGED_CASES = ["skewed_permuted", "empty_rows", "mid_row_pads", "r_200",
+                "batch_profiles", "promoted_w", "row_of_degree_w"]
+
+
+@pytest.mark.parametrize("block_rows", [128, 256])
+@pytest.mark.parametrize("case", RAGGED_CASES)
+def test_ragged_kernels_match_oracle_and_jnp(case, block_rows, rng):
+    """Both ragged kernels, from the raw ELL and from a prepared layout,
+    equal ``kernels.ref``'s oracles and the bucket programs' jnp path."""
+    from repro.core.programs import _gather_rows, _label_agree_counts
+    from repro.kernels.neighbor_min import prepare_ell
+
+    ell = jnp.asarray(_ragged_case(case, rng))
+    b, r, _ = ell.shape
+    ranks = np.stack([rng.permutation(r) for _ in range(b)])
+    ranks_p = jnp.asarray(np.concatenate(
+        [ranks, np.full((b, 1), 2**31 - 1)], axis=1), jnp.int32)
+    active_p = jnp.asarray(np.concatenate(
+        [rng.random((b, r)) < 0.6, np.zeros((b, 1), bool)], axis=1))
+    labels = jnp.asarray(rng.integers(0, max(1, r // 8), size=(b, r)),
+                         jnp.int32)
+    labels_p = jnp.concatenate([labels, jnp.full((b, 1), -1, jnp.int32)],
+                               axis=1)
+    layout = prepare_ell(ell)
+
+    nm_jnp = jnp.min(jnp.where(_gather_rows(active_p, ell),
+                               _gather_rows(ranks_p, ell), 2**31 - 1), axis=2)
+    la_jnp = _label_agree_counts(ell, labels, use_kernel=False, la_rows=None,
+                                 layout=None)
+    for src in (ell, layout):
+        nm = np.asarray(ops.neighbor_min_ell_batch(src, ranks_p, active_p,
+                                                   block_rows=block_rows))
+        la = np.asarray(ops.label_agree_ell_batch(src, labels_p,
+                                                  block_rows=block_rows))
+        assert (nm == np.asarray(nm_jnp)).all()
+        assert (la == np.asarray(la_jnp)).all()
+        for i in range(b):
+            assert (nm[i] == np.asarray(ref.neighbor_min_ref(
+                ell[i], ranks_p[i], active_p[i]))).all()
+            assert (la[i] == np.asarray(ref.label_agree_ref(
+                ell[i], labels_p[i]))).all()
+
+
+def _numpy_tiles(ell):
+    """Σ over 128-lane groups of ceil(widest row / 8) once each entry's
+    rows are ordered by width, widest first; and R_lanes/128 · W/8."""
+    b, r, w = ell.shape
+    slot = np.arange(1, w + 1)
+    width = np.where(ell < r, slot, 0).max(axis=2)
+    lanes = -(-r // 128) * 128
+    swept = []
+    for row in width:
+        ordered = np.sort(row)[::-1]
+        swept.append(sum(-(-int(ordered[g]) // 8)
+                         for g in range(0, r, 128)))
+    return np.array(swept), (lanes // 128) * (w // 8)
+
+
+@pytest.mark.parametrize("case", RAGGED_CASES)
+def test_prepare_ell_orders_rows_and_counts_tiles(case, rng):
+    """The layout holds every row once, widest first (stable), lane-major;
+    its tile counts are the numpy count and cover every real id."""
+    from repro.kernels.neighbor_min import prepare_ell, tile_counts
+
+    ell = _ragged_case(case, rng)
+    b, r, w = ell.shape
+    layout = prepare_ell(jnp.asarray(ell))
+    order = np.asarray(layout.order)
+    lm = np.asarray(layout.ell)
+    width = np.where(ell < r, np.arange(1, w + 1), 0).max(axis=2)
+    for i in range(b):
+        assert (order[i] == np.argsort(-width[i], kind="stable")).all()
+        assert (lm[i, :, :r].T == ell[i][order[i]]).all()
+        assert (lm[i, :, r:] == r).all()
+        tiles = np.asarray(layout.tiles[i])
+        for g, t in enumerate(tiles):      # no real id past a group's tiles
+            assert (lm[i, 8 * t:, 128 * g:128 * (g + 1)] >= r).all()
+    swept, full = _numpy_tiles(ell)
+    counts = np.asarray(tile_counts(jnp.asarray(ell), layout))
+    assert (counts[:, 0] == swept).all() and (counts[:, 1] == full).all()
+    assert (np.asarray(tile_counts(jnp.asarray(ell)))[:, 0] == full).all()
+
+
 # --- flash attention --------------------------------------------------------
 
 SHAPES = [

@@ -203,3 +203,16 @@ def test_rank_program_is_named_rank_draw(samples):
     else:
         lowered = mis._perm_ranks_batch_for(10).lower(keys)
     assert _module_name(lowered) == "@jit_rank_draw"
+
+
+def test_harvest_carries_the_swept_tiles(served):
+    spans, eng, executor = served[0], served[1], served[2]
+    harvests = {s[4]["flush"]: s[4] for s in _named(spans, "harvest")}
+    for handle in executor.handles:
+        args = harvests[handle.flush]
+        assert (args["ell_tiles_swept"], args["ell_tiles_full"]) \
+            == handle.ell_tiles
+    assert eng.stats.ell_tiles_swept == sum(
+        h.ell_tiles[0] for h in executor.handles)
+    assert eng.stats.ell_tiles_full == sum(
+        h.ell_tiles[1] for h in executor.handles)
