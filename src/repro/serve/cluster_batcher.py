@@ -712,7 +712,9 @@ class ClusterBatcher:
         # The flush's ordinal: how many flushes this engine submitted
         # before it (a failed attempt and its retry share one).
         ordinal = self.stats.flushes
-        with span("flush", flush=ordinal, requests=len(all_reqs)):
+        _, R, W = decision.bucket
+        with span("flush", flush=ordinal, R=R, W=W, graphs=len(all_reqs),
+                  g_pad=self.executor.group_pad(len(all_reqs))):
             pack = self._submit(decision, all_reqs, ordinal)
         self._in_flight_reqs += len(all_reqs)
         self.stats.flushes += 1

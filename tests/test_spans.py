@@ -151,7 +151,7 @@ def test_submit_bytes_are_the_packed_inputs(served):
 def test_harvest_names_its_flush(served):
     spans, executor = served[0], served[2]
     flushes = _named(spans, "flush")
-    assert [(s[4]["flush"], s[4]["requests"]) for s in flushes] \
+    assert [(s[4]["flush"], s[4]["graphs"]) for s in flushes] \
         == [(0, 2), (1, 1)]
     assert [h.flush for h in executor.handles] == [0, 1]
     assert [s[4]["flush"] for s in _named(spans, "assemble")] == [0, 1]
@@ -160,6 +160,19 @@ def test_harvest_names_its_flush(served):
     for harvest in harvests:
         flush = flushes[harvest[4]["flush"]]
         assert harvest[1] >= flush[2]
+
+
+def test_flush_span_carries_the_submitted_shape(served):
+    spans, executor = served[0], served[2]
+    flushes = _named(spans, "flush")
+    assert len(flushes) == len(executor.handles) == 2
+    for span, handle in zip(flushes, executor.handles):
+        b, r, w = handle.shape
+        args = span[4]
+        assert (args["R"], args["W"]) == (r, w)
+        assert args["graphs"] == len(handle.payload)
+        assert args["g_pad"] * K == b
+    assert [s[4]["g_pad"] for s in flushes] == [2, 1]
 
 
 def test_compile_spans_name_the_bucket(served):

@@ -96,6 +96,9 @@ def test_batched_kernel_lowers(kernel, b, r, w, one_chip, no_compile_cache):
     ("pivot", False, 8, 32768, 32, 1.0),
     # One power-law graph, best of 4: the ragged kernels at a wide ELL.
     ("pivot", True, 4, 16384, 1024, 1.0),
+    # The LFR grid's two buckets, 16 graphs a flush, best of 4.
+    ("pivot", True, 64, 1024, 64, 1.0),
+    ("pivot", True, 64, 8192, 64, 1.0),
     # The smoke's precluster shapes: its O(B·R·W²) common-neighbour pass
     # must stay under half of HBM.
     ("precluster", False, 32, 1024, 64, 0.5),
