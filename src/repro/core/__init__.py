@@ -19,7 +19,7 @@ Layout:
 """
 
 from .api import ClusterResult, correlation_cluster, correlation_cluster_batch
-from .arboricity import arboricity_bounds, degeneracy_parallel, degeneracy_sequential
+from .arboricity import arboricity_bounds, degeneracy_parallel, degeneracy_peel
 from .batch import (
     BucketBufferPool,
     GraphPlan,
@@ -106,7 +106,7 @@ __all__ = [
     "build_graph",
     "arboricity_bounds",
     "degeneracy_parallel",
-    "degeneracy_sequential",
+    "degeneracy_peel",
     "clique_clustering",
     "connected_components",
     "brute_force_opt",

@@ -54,7 +54,7 @@ import numpy as np
 
 from repro.util import next_pow2, span
 
-from .arboricity import arboricity_bounds
+from .arboricity import Peel, arboricity_bounds, degeneracy_peel
 from .degree_cap import degree_threshold
 from .graph import Graph
 from .mis import random_permutation_ranks_batch
@@ -96,6 +96,9 @@ class GraphPlan:
     # Registered clustering method this plan was resolved for. Part of the
     # serving-layer queue key: one flush runs one method's bucket program.
     method: str = "pivot"
+    # How the exact degeneracy was peeled, when plan_graph peeled it (None
+    # for a given lam, an uncapped method or the doubling bound).
+    peel: Optional[Peel] = None
 
     @property
     def bucket(self) -> Tuple[int, int]:
@@ -128,10 +131,17 @@ def plan_graph(g: Graph, method: str = "pivot", eps: float = 2.0,
 
     spec = method_spec(method)     # ValueError lists registered methods
     n = g.n
+    peel = None
     if spec.degree_cap:
         if lam is None:
-            with span("degeneracy", n=n):
-                _, lam = arboricity_bounds(g, exact=n <= 200_000)
+            with span("degeneracy", n=n) as sp:
+                if n <= 200_000:
+                    peel = degeneracy_peel(g)
+                    sp.set_metadata(peel_rounds=peel.rounds,
+                                    peel_single=peel.single)
+                    lam = max(1, peel.d)
+                else:
+                    _, lam = arboricity_bounds(g, exact=False)
         threshold = degree_threshold(lam, eps)
         eligible = ~(np.asarray(g.deg) > threshold)
     else:
@@ -169,7 +179,7 @@ def plan_graph(g: Graph, method: str = "pivot", eps: float = 2.0,
             "the per-graph engine")
     return GraphPlan(g=g, n=n, lam=lam, threshold=threshold,
                      eligible=eligible, wreq=wreq, R=R, W=W,
-                     canonical_edges=kept, method=method)
+                     canonical_edges=kept, method=method, peel=peel)
 
 
 def plan_canonical_edges(plan: GraphPlan) -> np.ndarray:

@@ -169,6 +169,10 @@ class ClusterStats(EngineStats):
     # swept per call, and all of them: the ragged sweep's share of work.
     ell_tiles_swept: int = 0
     ell_tiles_full: int = 0
+    # The admitted plans' exact degeneracy peels: vectorized strip rounds,
+    # and vertices the per-vertex cascade stripped.
+    peel_rounds: int = 0
+    peel_single: int = 0
     pad_vertex_waste: int = 0    # Σ (R − n) over clustered graphs
     buckets_seen: int = 0        # distinct (method, R, W) queues admitted
     rejected: int = 0            # admissions refused by backpressure
@@ -353,6 +357,9 @@ class ClusterBatcher:
                 req.plan = self._plan_for(req.graph, lam=req.lam,
                                           method=req.method)
             req.lam = req.plan.lam
+            if req.plan.peel is not None:
+                self.stats.peel_rounds += req.plan.peel.rounds
+                self.stats.peel_single += req.plan.peel.single
         plan = req.plan
         if self.result_cache is not None:
             if req.fingerprint is None:

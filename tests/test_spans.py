@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import build_graph, plan_graph
+from repro.core import build_graph, degeneracy_peel, plan_graph
 from repro.core import executor as ex
 from repro.core import mis
 from repro.core.dist import pow2_device_mesh
@@ -229,3 +229,19 @@ def test_harvest_carries_the_swept_tiles(served):
         h.ell_tiles[0] for h in executor.handles)
     assert eng.stats.ell_tiles_full == sum(
         h.ell_tiles[1] for h in executor.handles)
+
+
+def test_degeneracy_span_carries_the_peel_counts(served):
+    spans, eng = served[0], served[1]
+    plans = {s[4]["uid"]: s for s in _named(spans, "plan")}
+    for uid, graph, _ in _requests():
+        plan = plans[uid]
+        inner = [s for s in _named(spans, "degeneracy")
+                 if plan[1] <= s[1] and s[2] <= plan[2]]
+        assert len(inner) == 1
+        peel = degeneracy_peel(graph)
+        assert (inner[0][4]["peel_rounds"], inner[0][4]["peel_single"]) \
+            == (peel.rounds, peel.single)
+    peels = [degeneracy_peel(graph) for _, graph, _ in _requests()]
+    assert eng.stats.peel_rounds == sum(p.rounds for p in peels)
+    assert eng.stats.peel_single == sum(p.single for p in peels) > 0
