@@ -1,4 +1,4 @@
-"""Benchmark harness: one function per paper claim + system benchmarks.
+"""Benchmark harness: one function per paper claim.
 
 Prints ``name,us_per_call,derived`` CSV. The ``derived`` column carries the
 quantity each theorem bounds (approximation ratio, round count, component
@@ -23,9 +23,9 @@ def main() -> None:
                     help="run benchmarks whose name contains this substring")
     args = ap.parse_args()
 
-    from . import paper_claims, system_bench
+    from . import paper_claims
 
-    benches = list(paper_claims.ALL) + list(system_bench.ALL)
+    benches = list(paper_claims.ALL)
     print("name,us_per_call,derived")
     failed = 0
     for bench in benches:

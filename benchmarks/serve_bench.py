@@ -9,9 +9,8 @@ Three questions answered, machine-readably (``BENCH_serve.json``):
   any bucket whose oldest request waited past ``max_wait``), and — when
   ``--policy`` selects them — the adaptive and coalescing policies from
   ``repro.serve.scheduler``. Every pass emits its per-bucket flush-latency
-  telemetry (p50/p99 wall + assemble, plus per-request build stats when
-  rows are prebuilt at admission — the PR 8 ``pack`` split) so scheduling
-  quality is tracked across PRs.
+  telemetry (p50/p99 wall + assemble, plus the per-request row-build
+  stats of admission) so scheduling quality is tracked across PRs.
 * **Starvation** (the coalescing acceptance scenario) — a skewed
   two-bucket arrival stream on a *virtual* clock: a hot bucket fills
   constantly while a cold bucket trickles. Under the full-bucket policy
@@ -38,13 +37,6 @@ Three questions answered, machine-readably (``BENCH_serve.json``):
   graphs/s speedup are asserted, and every served result — hit,
   subscriber, or cold — is checked bit-identical to the per-graph
   engine.
-* **Pack split** (the admission-time packing acceptance scenario) —
-  identical engines with ``prebuild_rows`` on vs off on a pack-bound
-  small-bucket stream. Asserted: flush-time assemble p50 ≤ 0.5× the
-  legacy flush repack p50, flush-path graphs/s ≥ 1.1×, and — through a
-  deterministic coalescing leg — every result of a promoted (stolen)
-  prebuilt flush bit-identical to the per-graph engine. Emitted as
-  ``pack_split`` in the JSON.
 * **Mixed-method trace** (the PR 10 method-registry acceptance scenario;
   always runs) — requests alternating ``method='pivot'`` /
   ``method='precluster'`` through one engine under the cost policy. Each
@@ -568,207 +560,6 @@ def repeat_traffic_comparison(smoke: bool, max_batch: int = 16,
     return results
 
 
-def pack_split_comparison(smoke: bool, max_batch: int = 16):
-    """Admission-time packing split (the PR 8 acceptance scenario).
-
-    Two identical engines on the same pack-bound small-bucket stream
-    (n ∈ [8, 24): host packing dwarfs the device program at these
-    shapes): ``prebuild_rows=True`` (rows built once at admission,
-    flushes only assemble) vs ``prebuild_rows=False`` (the pre-split
-    engine: every flush re-derives every graph's ELL rows). Both run the
-    closed steady-state loop of :func:`steady_throughput`, so jit caches,
-    pools and staging are warm and the flush-latency telemetry holds the
-    full pass history.
-
-    Two asserted ratios:
-
-    * **assemble p50** — the host time left on the flush critical path.
-      With prebuilt rows a flush copies finished rows into staging; the
-      legacy arm's "assemble" is the whole per-graph repack. Asserted
-      ≤ 0.5× (measured ≈ 0.1–0.2×).
-    * **flush-path graphs/s** — graphs retired per second spent *in the
-      flush path* (bucket assembly + device + harvest; measured on the
-      real clock as the pass wall minus the admission time, where an
-      admit that triggered an inline full-bucket flush is charged the
-      running mean of pure-admission walls). This is the engine's
-      sustainable retire rate when admissions ride the arrival stream —
-      the serving regime the split targets, where per-request builds
-      land in inter-arrival gaps instead of on the flush path. Asserted
-      ≥ 1.1× (measured ≈ 2×).
-
-    End-to-end closed-loop graphs/s for both arms is emitted un-asserted
-    for transparency: with zero inter-arrival idle the build work has
-    nowhere to hide and the arms bracket a ~1× wash — the split moves
-    host work off the flush path, it does not delete it.
-
-    A second leg re-runs the starvation shape (hot path-graph bucket, a
-    trickle of cold small graphs, coalescing policy on a virtual clock)
-    through both arms and asserts every retired result bit-identical to
-    the per-graph engine — with ``stolen_requests > 0`` in both arms, so
-    the prebuilt path is exercised *through shape promotion* (stolen
-    rows relayouted by ``PackedRows.promote`` into the hot flush).
-    """
-    n_graphs = 96 if smoke else 256
-    # Best-of-2 sampling: the per-graph key folding and two-key rank
-    # dispatches are exactly the per-request costs the split moves to
-    # admission, so k=2 is where the flush path has the most to lose to
-    # a legacy repack (and the asserted ratios their widest margin).
-    num_samples = 2
-    reqs = make_requests(n_graphs, seed=13, n_lo=8, n_hi=24,
-                         lam_lo=1, lam_hi=2)
-    ClusterBatcher(max_batch=max_batch, num_samples=num_samples).warmup(
-        g for _, g, _ in reqs)
-    engines = {
-        "legacy": ClusterBatcher(max_batch=max_batch, result_cache=False,
-                                 num_samples=num_samples,
-                                 prebuild_rows=False),
-        "prebuild": ClusterBatcher(max_batch=max_batch, result_cache=False,
-                                   num_samples=num_samples),
-    }
-
-    def pass_once(eng):
-        """One closed-loop pass; returns (pass_wall, flush_path_seconds).
-
-        The full-bucket policy flushes inline inside ``admit`` when a
-        bucket fills, so flush-path time is the pass wall minus the
-        admission walls: a non-flushing admit is pure admission (plan,
-        and on the prebuild arm the row build); a flushing admit is
-        charged the running mean of the pure ones and contributes the
-        rest to the flush path.
-        """
-        retired = 0
-        admit_s = 0.0
-        admits = 0
-        t_pass = time.perf_counter()
-        for uid, g, lam in reqs:
-            req = ClusterRequest(uid=uid, graph=g,
-                                 key=jax.random.PRNGKey(uid), lam=lam)
-            flushes0 = eng.stats.flushes
-            t0 = time.perf_counter()
-            retired += len(eng.admit(req))
-            dt = time.perf_counter() - t0
-            if eng.stats.flushes == flushes0:
-                admit_s += dt
-                admits += 1
-            elif admits:
-                admit_s += admit_s / admits
-        retired += len(eng.flush())
-        wall = time.perf_counter() - t_pass
-        assert retired == len(reqs), "requests lost in the engine"
-        return wall, max(1e-9, wall - admit_s)
-
-    repeat = 3 if smoke else 5
-    best = {name: (None, None) for name in engines}
-    for eng in engines.values():                     # warm pass per arm
-        pass_once(eng)
-    for _ in range(repeat):                          # interleaved best-of-N
-        for name, eng in engines.items():
-            wall, flushpath = pass_once(eng)
-            bw, bf = best[name]
-            best[name] = (wall if bw is None else min(bw, wall),
-                          flushpath if bf is None else min(bf, flushpath))
-
-    results = {}
-    for name, eng in engines.items():
-        tele = eng.stats.latency
-        assemble = tele.samples("assemble")
-        results[name] = {
-            "gps_e2e": n_graphs / best[name][0],
-            "flushpath_gps": n_graphs / best[name][1],
-            "assemble_p50_ms": pct(assemble, 50) * 1e3,
-            "assemble_p99_ms": pct(assemble, 99) * 1e3,
-            "flushes": tele.total_flushes,
-            "builds": tele.total_builds,
-            "build_p50_ms": pct(tele.samples("build"), 50) * 1e3
-            if tele.total_builds else None,
-        }
-        r = results[name]
-        build = (f"build p50={r['build_p50_ms']:.3f}ms  "
-                 if r["build_p50_ms"] is not None else "")
-        print(f"[pack:{name:8s}] flush-path {r['flushpath_gps']:8.1f} g/s   "
-              f"e2e {r['gps_e2e']:8.1f} g/s   "
-              f"assemble p50={r['assemble_p50_ms']:.3f}ms  {build}"
-              f"flushes={r['flushes']}")
-    assert results["legacy"]["builds"] == 0, \
-        "legacy arm recorded admission builds — it is not the pre-split arm"
-    assert results["prebuild"]["builds"] > 0, \
-        "prebuild arm recorded no admission builds"
-    assemble_ratio = (results["prebuild"]["assemble_p50_ms"]
-                      / results["legacy"]["assemble_p50_ms"])
-    flushpath_ratio = (results["prebuild"]["flushpath_gps"]
-                       / results["legacy"]["flushpath_gps"])
-    results.update(assemble_ratio=assemble_ratio,
-                   flushpath_ratio=flushpath_ratio)
-    assert assemble_ratio <= 0.5, (
-        f"prebuilt assembly p50 is {assemble_ratio:.2f}x the legacy flush "
-        "pack p50 (expected <= 0.5x) — the flush path is still rebuilding "
-        "rows")
-    assert flushpath_ratio >= 1.1, (
-        f"prebuilt rows bought only {flushpath_ratio:.2f}x flush-path "
-        "throughput over the legacy repack (expected >= 1.1x)")
-    print(f"[pack] assemble p50 ratio={assemble_ratio:.2f}x  "
-          f"flush-path speedup={flushpath_ratio:.2f}x")
-
-    # Bit-exactness through promotion: the starvation shape forces the
-    # coalescing policy to steal cold requests into hot flushes, so the
-    # prebuild arm assembles *promoted* PackedRows. Virtual clock =
-    # deterministic steal schedule, identical across arms.
-    from repro.serve.scheduler import CoalescingPolicy
-
-    # Same shape as starvation_comparison: the hot bucket's fill time
-    # (max_batch · gap) must exceed the deadline or every flush is full
-    # and steals never find spare room.
-    n_hot = 64 if smoke else 144
-    cold_every = 16
-    gap = 0.002
-    stolen = {}
-    for name, prebuild in (("legacy", False), ("prebuild", True)):
-        rng = np.random.default_rng(29)
-        clock = VirtualClock()
-        batcher = ClusterBatcher(
-            max_batch=max_batch, clock=clock, result_cache=False,
-            prebuild_rows=prebuild,
-            policy=CoalescingPolicy(max_batch, max_wait=10 * gap,
-                                    steal_wait=gap / 2))
-        done = {}
-
-        def account(rs):
-            for r in rs:
-                done[r.uid] = r.result
-        uid = 0
-        graphs = {}
-        for i in range(n_hot):
-            if i % cold_every == 0:
-                graphs[uid] = build_graph(6, path(6))
-            else:
-                n = int(rng.integers(17, 30))
-                graphs[uid] = build_graph(n, path(n))
-            clock.advance(gap)
-            account(batcher.admit(ClusterRequest(
-                uid=uid, graph=graphs[uid], key=jax.random.PRNGKey(uid))))
-            account(batcher.poll())
-            uid += 1
-        account(batcher.flush())
-        assert len(done) == n_hot, "requests lost in the engine"
-        assert batcher.stats.stolen_requests > 0, (
-            f"{name} arm stole nothing — the promotion path was not "
-            "exercised")
-        stolen[name] = batcher.stats.stolen_requests
-        for uid, g in graphs.items():
-            ref = correlation_cluster(g, key=jax.random.PRNGKey(uid))
-            assert (done[uid].labels == ref.labels).all() \
-                and done[uid].cost == ref.cost, (
-                f"{name} arm diverged from the per-graph engine on "
-                f"request {uid} (coalesced/promoted flush)")
-    assert stolen["legacy"] == stolen["prebuild"], \
-        "the two arms saw different steal schedules — virtual clock broken"
-    print(f"[pack] promotion bit-exactness: {n_hot} requests x 2 arms "
-          f"match the per-graph engine ({stolen['prebuild']} stolen)")
-    results["promotion_check"] = {"requests": n_hot,
-                                  "stolen_requests": stolen["prebuild"]}
-    return results
-
-
 def mixed_method_comparison(smoke: bool, max_batch: int = 16,
                             executor: str = "sync"):
     """One engine serving both registered bucket programs in one trace,
@@ -939,11 +730,6 @@ def main():
     pad_hostile = pad_hostile_comparison(args.smoke) \
         if args.policy == "cost" else None
 
-    # Pack split: the admission-time packing acceptance scenario —
-    # asserted assemble-p50 and flush-path ratios plus bit-exactness
-    # through promoted (coalesced) prebuilt flushes.
-    pack_split = pack_split_comparison(args.smoke, max_batch=args.max_batch)
-
     # Executor comparison: closed-loop steady state, sync vs pipelined
     # (vs the selected executor when it is neither). The async win is the
     # host packing bucket i+1 while bucket i computes and transfers, so it
@@ -1077,7 +863,6 @@ def main():
             "warmup_programs": compiled,
             "policies": policies_payload,
             "starvation": starvation,
-            "pack_split": pack_split,
             "executor_steady_gps": comparison,
             "async_speedup_vs_sync": async_speedup,
             "inflight_window_gps": window_cmp,

@@ -9,8 +9,8 @@ count is O(#buckets · log B), not O(#graphs).
 The engine is layered (this module is the thin composition of the two):
 
 * :mod:`repro.core.plan` — host side: ``plan_graph`` bucketing, the
-  ``pack_bucket`` ELL packer (with prebuilt ``PackedRows`` assembly for
-  the serving layer's admission-time packing), ``PackStats`` pad
+  ``build_packed_rows`` row builder (the same call serving makes at
+  admission), the ``pack_bucket`` row assembler, ``PackStats`` pad
   accounting, and the lease-based ``BucketBufferPool`` staging reuse.
 * :mod:`repro.core.executor` — device side: the fused bucket programs
   (rounds body × cost pass × best-of-k, composed from the method/objective
@@ -39,19 +39,17 @@ import numpy as np
 
 from .graph import Graph
 
-# Backward-compatible re-exports: the pre-split module exposed all of these
-# (_pack_bucket is the deprecated shim of pack_bucket).
+# Re-exports: the pre-split module exposed all of these.
 from .plan import (  # noqa: F401
     MAX_ROWS, MAX_WIDTH, MIN_ROWS, MIN_WIDTH, BucketBufferPool, GraphPlan,
-    PackedRows, PackStats, StagingLease, _pack_bucket, build_packed_rows,
-    pack_bucket, plan_graph, promote_plan, result_for_plan,
+    PackedRows, PackStats, StagingLease, build_packed_rows, pack_bucket,
+    plan_graph, promote_plan, result_for_plan,
 )
 from .executor import (  # noqa: F401
     IN_MIS, REMOVED, UNDECIDED, AsyncExecutor, BucketExecutor, InFlightBucket,
-    ShardedExecutor, SyncExecutor, _batch_pivot_cost_impl, _gather_rows,
-    make_executor, pack_and_submit, program_cache_capacity,
-    program_cache_info, program_cache_size, run_bucket_program,
-    set_program_cache_capacity,
+    ShardedExecutor, SyncExecutor, make_executor, pack_and_submit,
+    program_cache_capacity, program_cache_info, program_cache_size,
+    run_bucket_program, set_program_cache_capacity,
 )
 
 
@@ -160,6 +158,10 @@ def correlation_cluster_batch(
     ex = make_executor(executor)
     plans = [plan_graph(g, method=method, eps=eps, lam=lam)
              for g, lam in zip(graphs, lams)]
+    # The same row build admission does: ELL scatter plus an async rank
+    # dispatch per graph, so the packs below only copy rows.
+    for plan, key in zip(plans, keys):
+        plan.rows = build_packed_rows(plan, sample_keys(key, k))
 
     buckets: dict = {}
     for gi, plan in enumerate(plans):
@@ -170,9 +172,8 @@ def correlation_cluster_batch(
     handles: List[InFlightBucket] = []
     for flush, members in enumerate(buckets.values()):
         bplans = [plans[gi] for gi in members]
-        bkeys = [sample_keys(keys[gi], k) for gi in members]
         handle, bucket_stats = pack_and_submit(
-            bplans, bkeys, k, ex, pool=pool, use_kernel=use_kernel,
+            bplans, k, ex, pool=pool, use_kernel=use_kernel,
             payload=(members, bplans), track=False, objective=objective,
             flush=flush)
         handles.append(handle)

@@ -12,16 +12,14 @@ agreement for ``'precluster'``), the objective's cost pass
 entirely on device, so only winning labels / costs / sample indices cross
 back to the host. Every batch entry is independent of every other, which
 is what makes both async overlap and data-parallel sharding
-semantics-preserving. (:func:`_batch_pivot_cost_impl` survives as the
-pre-registry name of the pivot × disagree composition.)
+semantics-preserving.
 
 **The compiled-program cache** — :func:`run_bucket_program` resolves each
 ``(shape, k, kernel, donation, mesh, method, objective)`` request through
 a bounded LRU of jit instances. Methods sharing one *program family*
-(``'pivot'`` / ``'pivot_raw'``) share compiled programs, and the legacy
-pivot × disagree keys are preserved verbatim so the refactor cannot
-fragment a warmed cache. Long-lived servers seeing many bucket shapes hold at
-most :func:`program_cache_capacity` compiled programs; evictions and
+(``'pivot'`` / ``'pivot_raw'``) share compiled programs. Long-lived
+servers seeing many bucket shapes hold at most
+:func:`program_cache_capacity` compiled programs; evictions and
 compiles are counted (:func:`program_cache_info`) instead of growing
 memory without limit. The LRU takes *hints* from layers that know more
 than the access order: :func:`program_cache_contains` is a non-mutating
@@ -75,27 +73,13 @@ from jax.sharding import PartitionSpec as P
 
 from repro.util import next_pow2, span
 
-from .programs import IN_MIS, REMOVED, UNDECIDED, _gather_rows, \
-    bucket_impl, method_spec, objective_spec
+from .programs import IN_MIS, REMOVED, UNDECIDED, bucket_impl, \
+    method_spec, objective_spec
 
 # ---------------------------------------------------------------------------
 # Fused device programs: rounds body + cost pass + best-of-k argmin, composed
 # from the method/objective registries in repro.core.programs.
 # ---------------------------------------------------------------------------
-
-
-def _batch_pivot_cost_impl(ell, ranks_p, elig_p, m_edges, k: int,
-                           use_kernel: bool,
-                           block_rows: Optional[Tuple[int, int]] = None):
-    """Pre-registry name of the pivot × disagree bucket program.
-
-    Kept as a thin wrapper over :func:`repro.core.programs.bucket_impl`
-    (same signature, bit-identical outputs) for callers that imported the
-    fused pipeline directly before the method registry existed.
-    """
-    return bucket_impl(ell, ranks_p, elig_p, m_edges, k=k,
-                       use_kernel=use_kernel, block_rows=block_rows,
-                       program="pivot", objective="disagree")
 
 
 # ---------------------------------------------------------------------------
@@ -131,23 +115,19 @@ def _program_key(shape, k: int, use_kernel: bool, donate: bool,
     tiles), so a tuning-cache update yields a new program at the new
     shape instead of mutating a compiled one. ``program`` is the method's
     *program family* (``method_spec(m).program``, so ``'pivot'`` and
-    ``'pivot_raw'`` share compiled programs); the default pivot × disagree
-    combination keeps the pre-registry 6-tuple key verbatim, so a warmed
-    resident cache never fragments across the refactor."""
-    base = (tuple(int(s) for s in shape), k, use_kernel, donate,
-            _mesh_cache_key(mesh), block_rows)
-    if program == "pivot" and objective == "disagree":
-        return base
-    return base + (program, objective)
+    ``'pivot_raw'`` share compiled programs) and ``objective`` its cost
+    pass; every key carries both."""
+    return (tuple(int(s) for s in shape), k, use_kernel, donate,
+            _mesh_cache_key(mesh), block_rows, program, objective)
 
 
 def _resolve_block_rows(shape, use_kernel: bool,
                         block_rows=None) -> Optional[Tuple[int, int]]:
     """Static kernel lane tiles a bucket program of ``shape`` will bake
     in: the caller's explicit pair, else the tuning-cache winners, each
-    resolved to its lane tile; None at the default tiles (the legacy key,
-    so untuned buckets never fragment the program cache) and when the
-    kernels are not in play at all."""
+    resolved to its lane tile; None at the default tiles (so untuned
+    buckets share one program) and when the kernels are not in play at
+    all."""
     if not use_kernel:
         return None
     from repro.kernels.autotune import lane_tiles, resolve_block_rows
@@ -772,7 +752,7 @@ class ShardedExecutor(AsyncExecutor):
         return max(self.num_devices, next_pow2(max(1, n_groups)))
 
 
-def pack_and_submit(plans, group_keys, k: int, executor: "BucketExecutor",
+def pack_and_submit(plans, k: int, executor: "BucketExecutor",
                     pool=None, use_kernel: bool = False, payload: Any = None,
                     track: bool = True, objective: str = "disagree",
                     flush: int = 0):
@@ -781,16 +761,14 @@ def pack_and_submit(plans, group_keys, k: int, executor: "BucketExecutor",
     The single lease → ``pack_bucket`` → ``submit`` sequence shared by
     ``correlation_cluster_batch`` and the serving-layer flush, so group
     padding, donation policy and pad accounting cannot drift between the
-    two paths. Plans carrying prebuilt :class:`~repro.core.plan.
-    PackedRows` assemble by row copies (their ``group_keys`` entries may
-    be ``None``); plans without get the legacy derive-at-flush build —
-    the measured host time is stamped on the handle as
-    ``assemble_seconds`` either way. Returns ``(handle, stats)`` where
-    ``stats`` is this one flush's :class:`~repro.core.plan.PackStats` —
-    the single source every caller's pad accounting merges from. If
-    packing or dispatch raises, the staging lease is released before
-    re-raising — nothing was dispatched, so the buffers are genuinely
-    free.
+    two paths. Every plan carries its :class:`~repro.core.plan.
+    PackedRows`, so the pack is row copies; its measured host time is
+    stamped on the handle as ``assemble_seconds``. Returns ``(handle,
+    stats)`` where ``stats`` is this one flush's :class:`~repro.core.
+    plan.PackStats` — the single source every caller's pad accounting
+    merges from. If packing or dispatch raises, the staging lease is
+    released before re-raising — nothing was dispatched, so the buffers
+    are genuinely free.
 
     The clustering method rides on the plans themselves
     (``GraphPlan.method``): one flush is one method by construction, so a
@@ -818,7 +796,7 @@ def pack_and_submit(plans, group_keys, k: int, executor: "BucketExecutor",
         t_pack = time.perf_counter()
         with span("assemble", flush=flush):
             ell, ranks, elig, m_edges, _ = pack_bucket(
-                plans, group_keys, k=k, g_pad=g_pad,
+                plans, k=k, g_pad=g_pad,
                 staging=lease.arrays if lease is not None else None)
         assemble_seconds = time.perf_counter() - t_pack
         handle = executor.submit(

@@ -9,20 +9,19 @@ host:
   *eligible-induced* degree rounded to a power of two — the Theorem 26 cap
   is what keeps ``W ≤ 12λ`` and makes ELL padding cheap). It also
   canonicalises the eligible-induced edge list (lexsorted) exactly once;
-  :func:`graph_fingerprint` and the packer both read
+  :func:`graph_fingerprint` and the row builder both read
   ``GraphPlan.canonical_edges`` instead of re-deriving it.
 * :func:`build_packed_rows` turns one plan into a :class:`PackedRows`
   artifact — the graph's finished ``(R, W)`` ELL rows, rank rows, and
-  eligibility row. Serving builds it once per request at admission, so the
-  argsort/bincount/scatter work leaves the flush critical path.
-* :func:`pack_bucket` lays one bucket's graphs (× k best-of-k samples)
+  eligibility row. It is the one row builder: serving calls it once per
+  request at admission and ``correlation_cluster_batch`` once per graph
+  after planning, so the argsort/bincount/scatter work never runs on the
+  flush path.
+* :func:`pack_bucket` lays one bucket's rows (× k best-of-k samples)
   into the ``(B, R, W)`` ELL tensor plus ``(B, R+1)`` rank/eligibility
   state the device program consumes, with the group axis padded to a power
   of two (callers may request extra group padding, e.g. to a device-count
-  multiple for the sharded executor). Plans carrying prebuilt
-  :class:`PackedRows` assemble by row copies only; plans without fall back
-  to the legacy derive-at-flush build — the two paths are bit-identical
-  and compose freely within one bucket.
+  multiple for the sharded executor). It only copies rows.
 * :class:`PackStats` is the packer's own padding accounting — the single
   source serving stats are derived from, so they cannot drift from what was
   actually padded onto the device. :func:`estimate_pack_stats` is the pure
@@ -46,7 +45,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import struct
-import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -87,11 +85,11 @@ class GraphPlan:
     W: int                      # width bucket (pow2)
     # Eligible-induced undirected edge list in canonical (lexsorted (u, v))
     # order, int64 C-contiguous. Built once by plan_graph; both
-    # graph_fingerprint and the packer consume it, so the keep-mask/sort
-    # happens exactly once per request and the two can never diverge.
+    # graph_fingerprint and build_packed_rows consume it, so the
+    # keep-mask/sort happens exactly once per request and the two can
+    # never diverge.
     canonical_edges: Optional[np.ndarray] = None
-    # Prebuilt device rows (admission-time packing). None = the packer
-    # derives rows at flush time from canonical_edges instead.
+    # The graph's device rows (build_packed_rows); pack_bucket needs them.
     rows: Optional["PackedRows"] = None
     # Registered clustering method this plan was resolved for. Part of the
     # serving-layer queue key: one flush runs one method's bucket program.
@@ -156,7 +154,8 @@ def plan_graph(g: Graph, method: str = "pivot", eps: float = 2.0,
         kept = np.zeros((0, 2), dtype=np.int64)
     if len(kept):
         # Canonical order: lexsorted by (u, v). This is the byte order the
-        # fingerprint hashes and the edge order the packer scatters from.
+        # fingerprint hashes and the edge order build_packed_rows scatters
+        # from.
         kept = kept[np.lexsort((kept[:, 1], kept[:, 0]))]
         wreq = int(np.bincount(kept.ravel(), minlength=n).max())
     else:
@@ -186,8 +185,8 @@ def plan_canonical_edges(plan: GraphPlan) -> np.ndarray:
     """The plan's canonical (lexsorted) eligible-induced edge list.
 
     ``plan_graph`` always attaches it; plans constructed by hand get it
-    derived (and memoised) here so the fingerprint and the packer keep one
-    source of truth either way.
+    derived (and memoised) here so the fingerprint and the row builder
+    keep one source of truth either way.
     """
     if plan.canonical_edges is None:
         und = plan.g.undirected_edges()
@@ -203,10 +202,10 @@ def plan_canonical_edges(plan: GraphPlan) -> np.ndarray:
 
 
 class PackedRows:
-    """Prebuilt device rows for one planned graph (admission-time packing).
+    """The device rows of one planned graph (see :func:`build_packed_rows`).
 
-    Everything :func:`pack_bucket` would derive for this graph at flush
-    time, finished once up front: the ``(R, W)`` int32 ELL adjacency rows
+    Everything the device program reads of this graph, finished once
+    before any flush: the ``(R, W)`` int32 ELL adjacency rows
     (pad id ``R``), the ``(k, R+1)`` rank rows for the request's best-of-k
     sample keys (``INT32_MAX`` beyond ``n``), the ``(R+1,)`` eligibility
     row (slot ``R`` False), and the full edge count ``m`` the cost
@@ -216,7 +215,7 @@ class PackedRows:
     The rank permutations are dispatched to the device when the artifact
     is built (one fused async call) and materialised into the padded
     numpy layout lazily on first access — by flush time they have long
-    finished, so admission keeps the overlap the flush-time packer had.
+    finished, so the host work after the build overlaps them.
     """
 
     __slots__ = ("R", "W", "n", "m", "k", "ell", "elig",
@@ -421,7 +420,7 @@ def graph_fingerprint(plan: GraphPlan, key: jax.Array, *,
     """
     g = plan.g
     # The canonical lexsorted edge list is built once by plan_graph and
-    # shared with the packer — hashing here re-derives nothing.
+    # shared with the row builder — hashing here re-derives nothing.
     kept = plan_canonical_edges(plan)
     elig = np.ascontiguousarray(np.asarray(plan.eligible, dtype=bool))
     payload = b"".join([
@@ -499,9 +498,7 @@ def estimate_pack_stats(plans: Sequence[GraphPlan], k: int,
     )
 
 
-def pack_bucket(plans: Sequence[GraphPlan],
-                group_keys: Sequence[Optional[Sequence[jax.Array]]],
-                k: int,
+def pack_bucket(plans: Sequence[GraphPlan], k: int,
                 staging: Optional[dict] = None,
                 g_pad: Optional[int] = None):
     """Assemble one bucket's graphs (× k samples each) into device tensors.
@@ -515,19 +512,12 @@ def pack_bucket(plans: Sequence[GraphPlan],
     (a lease from :class:`BucketBufferPool`) reuses host arrays across
     flushes instead of reallocating.
 
-    Per graph, one of two bit-identical paths runs:
-
-    * **prebuilt** — a plan carrying :class:`PackedRows` (built at
-      admission by :func:`build_packed_rows`, promoted with its plan for
-      coalesced flushes) assembles by row copies only; its ``group_keys``
-      entry may be ``None`` because the rank permutations were drawn when
-      the rows were built. A flush of all-prebuilt plans skips the full
-      staging reset too: every real row is wholly overwritten by its copy,
-      so only the group-padding tail is (re)stamped with the pad pattern.
-    * **legacy** — a plan without rows gets the derive-at-flush build,
-      scattering from the plan's canonical edge list (the same array the
-      fingerprint hashes) with its rank batch dispatched up front (async)
-      and harvested after the host-side scatters.
+    Every plan carries its :class:`PackedRows` (built by
+    :func:`build_packed_rows`, promoted with its plan for coalesced
+    flushes), so assembly is row copies only: each real entry is wholly
+    overwritten by its copy, and only the group-padding tail is (re)stamped
+    with the pad pattern. Raises ``ValueError`` for a plan without rows or
+    with rows at another bucket or sample count.
     """
     R, W = plans[0].bucket
     if g_pad is None:
@@ -535,102 +525,38 @@ def pack_bucket(plans: Sequence[GraphPlan],
     elif g_pad < len(plans):
         raise ValueError(f"g_pad={g_pad} < {len(plans)} graphs in bucket")
     b_pad = g_pad * k
-    rows_list = [p.rows for p in plans]
-    for pr in rows_list:
-        if pr is not None and (pr.bucket != (R, W) or pr.k != k):
+    for plan in plans:
+        pr = plan.rows
+        if pr is None:
+            raise ValueError(
+                "plan has no rows: build them with build_packed_rows(plan, "
+                "sample_keys(key, k)) before packing")
+        if pr.bucket != (R, W) or pr.k != k:
             raise ValueError(
                 f"prebuilt rows at bucket {pr.bucket} with k={pr.k} cannot "
                 f"assemble into a ({R}, {W}) flush with k={k}; promote the "
                 "plan first (promote_plan relays its PackedRows)")
-    all_prebuilt = all(pr is not None for pr in rows_list)
     n_real = len(plans) * k
     if staging is None:
-        if all_prebuilt:
-            ell = np.empty((b_pad, R, W), dtype=np.int32)
-            ranks = np.empty((b_pad, R + 1), dtype=np.int32)
-            elig = np.empty((b_pad, R + 1), dtype=bool)
-            m_edges = np.empty((b_pad,), dtype=np.int32)
-        else:
-            ell = np.full((b_pad, R, W), R, dtype=np.int32)
-            ranks = np.full((b_pad, R + 1), _INT32_MAX, dtype=np.int32)
-            elig = np.zeros((b_pad, R + 1), dtype=bool)
-            m_edges = np.zeros((b_pad,), dtype=np.int32)
+        ell = np.empty((b_pad, R, W), dtype=np.int32)
+        ranks = np.empty((b_pad, R + 1), dtype=np.int32)
+        elig = np.empty((b_pad, R + 1), dtype=bool)
+        m_edges = np.empty((b_pad,), dtype=np.int32)
     else:
         ell, ranks, elig, m_edges = (staging["ell"], staging["ranks"],
                                      staging["elig"], staging["m_edges"])
-        if not all_prebuilt:
-            ell.fill(R)
-            ranks.fill(_INT32_MAX)
-            elig.fill(False)
-            m_edges.fill(0)
-    if all_prebuilt:
-        # Rows [0, n_real) are wholly overwritten below; only the
-        # group-padding tail needs the pad pattern.
-        ell[n_real:] = R
-        ranks[n_real:] = _INT32_MAX
-        elig[n_real:] = False
-        m_edges[n_real:] = 0
-
-    # Dispatch the legacy graphs' rank batches first (one fused device
-    # call per graph, async under JAX dispatch): the permutations compute
-    # while the numpy ELL packing below runs on the host. Same per-graph
-    # permutation as the single-graph engine — ranks are a function of
-    # (n, key) only, and the batched call is row-bit-identical to per-key
-    # calls — so the result stays bit-exact per graph. Prebuilt graphs
-    # dispatched theirs at admission.
-    rank_batches = [
-        random_permutation_ranks_batch(plan.n, keys)
-        if pr is None and plan.n else None
-        for plan, keys, pr in zip(plans, group_keys, rows_list)
-    ]
-
-    for gi, (plan, keys) in enumerate(zip(plans, group_keys)):
-        n = plan.n
+    ell[n_real:] = R
+    ranks[n_real:] = _INT32_MAX
+    elig[n_real:] = False
+    m_edges[n_real:] = 0
+    for gi, plan in enumerate(plans):
+        pr = plan.rows
         base = gi * k
-        pr = rows_list[gi]
-        if pr is not None:
-            ell[base: base + k] = pr.ell
-            ranks[base: base + k] = pr.ranks
-            elig[base: base + k] = pr.elig
-            m_edges[base: base + k] = pr.m
-            continue
-        e = plan_canonical_edges(plan)
-        if len(e):
-            src = np.concatenate([e[:, 0], e[:, 1]])
-            dst = np.concatenate([e[:, 1], e[:, 0]])
-            order = np.argsort(src, kind="stable")
-            src, dst = src[order], dst[order]
-            deg = np.bincount(src, minlength=n)
-            starts = np.zeros(n + 1, dtype=np.int64)
-            np.cumsum(deg, out=starts[1:])
-            slot = np.arange(len(src)) - starts[src]
-            ell[base, src, slot] = dst
-        # The adjacency is identical across the k sample replicas; only the
-        # permutation (hence ranks) differs per sample key.
-        for si in range(1, k):
-            ell[base + si] = ell[base]
-        for si in range(len(keys)):
-            if n:
-                elig[base + si, :n] = plan.eligible
-            m_edges[base + si] = plan.g.m
-
-    # Harvest the (by now computed) rank batches into the staging arrays.
-    for gi, (plan, batch) in enumerate(zip(plans, rank_batches)):
-        if batch is None:
-            continue
-        base = gi * k
-        rk = np.asarray(batch)
-        for si in range(rk.shape[0]):
-            ranks[base + si, : plan.n] = rk[si]
+        ell[base: base + k] = pr.ell
+        ranks[base: base + k] = pr.ranks
+        elig[base: base + k] = pr.elig
+        m_edges[base: base + k] = pr.m
     return ell, ranks, elig, m_edges, g_pad - len(plans)
-
-
-def _pack_bucket(plans, group_keys, k, staging=None, g_pad=None):
-    """Deprecated pre-PR-8 private name of :func:`pack_bucket`."""
-    warnings.warn(
-        "repro.core.plan._pack_bucket is deprecated; use pack_bucket",
-        DeprecationWarning, stacklevel=2)
-    return pack_bucket(plans, group_keys, k, staging=staging, g_pad=g_pad)
 
 
 def result_for_plan(plan: GraphPlan, labels_row: np.ndarray, cost: int,

@@ -241,8 +241,8 @@ def tuning_info() -> dict:
 def resolve_block_rows(shape) -> Optional[Tuple[int, int]]:
     """Tuned ``(neighbor_min, label_agree)`` lane tiles for a packed
     ``(B, R, W)`` shape, or None when the bucket tier is untuned or tuned
-    to the default's tile (the program key then stays on the legacy
-    default and the kernels use ``DEFAULT_BLOCK_ROWS``). Pure dict reads —
+    to the default's tile (the program key then carries None and the
+    kernels use ``DEFAULT_BLOCK_ROWS``). Pure dict reads —
     safe on the hot path."""
     b, r, w = (int(s) for s in shape)
     tier = batch_tier(b)

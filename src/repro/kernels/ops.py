@@ -7,14 +7,10 @@ interpreter.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from . import flash_attention as _fa
 from . import neighbor_min as _nm
-from . import ref as _ref
 
 
 # Resolved ONCE at import: ``interpret`` is a jit static arg on every
@@ -72,43 +68,5 @@ def label_agree_ell_batch(ell, labels_p, block_rows: int = 256):
                                      interpret=_INTERPRET)
 
 
-def _pad_to(x, mult, axis):
-    size = x.shape[axis]
-    rem = (-size) % mult
-    if rem == 0:
-        return x, size
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, rem)
-    return jnp.pad(x, pad), size
-
-
-def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128):
-    """Padded/unpadded flash attention. q (B,H,Sq,D), k/v (B,KH,Sk,D).
-
-    Sequence lengths are padded up to the block size; padded KV columns are
-    masked out by giving them -inf scores via an explicit active length —
-    here we rely on causal masking for Sq==Sk and pad-safe softmax (padded
-    rows are sliced away, padded KV columns only matter for non-causal
-    inputs, where we pre-mask keys by padding V with zeros and K with a
-    -inf-producing sentinel handled below).
-    """
-    b, h, sq, d = q.shape
-    _, kh, sk, _ = k.shape
-    qp, sq0 = _pad_to(q, block_q, 2)
-    kp, sk0 = _pad_to(k, block_k, 2)
-    vp, _ = _pad_to(v, block_k, 2)
-    if kp.shape[2] != sk0 and not causal:
-        # Ragged non-causal KV (padded keys would need an explicit length
-        # mask): take the oracle path — only hit by tiny encoder shapes.
-        return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
-    out = _fa.flash_attention(qp, kp, vp, causal=causal, scale=scale,
-                              block_q=block_q, block_k=block_k,
-                              interpret=_INTERPRET,
-                              row_offset=sk0 - sq0)
-    return out[:, :, :sq0, :]
-
-
 __all__ = ["neighbor_min", "neighbor_min_ell", "neighbor_min_ell_batch",
-           "label_agree_ell_batch", "flash_attention",
-           "interpret_mode", "set_interpret_mode"]
+           "label_agree_ell_batch", "interpret_mode", "set_interpret_mode"]

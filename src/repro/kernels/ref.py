@@ -7,7 +7,6 @@ fallbacks used on platforms without the kernels.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 INF_I32 = jnp.int32(2**31 - 1)
@@ -37,26 +36,4 @@ def label_agree_ref(ell: jnp.ndarray, labels_p: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum((nbr == own[:, None]).astype(jnp.int32), axis=1)
 
 
-def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                  causal: bool = True, scale: float | None = None
-                  ) -> jnp.ndarray:
-    """Naive attention oracle (f32 math). q (B,H,Sq,D), k/v (B,KH,Sk,D)."""
-    b, h, sq, d = q.shape
-    _, kh, sk, _ = k.shape
-    group = h // kh
-    if scale is None:
-        scale = float(1.0 / (d ** 0.5))
-    kr = jnp.repeat(k, group, axis=1)
-    vr = jnp.repeat(v, group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   kr.astype(jnp.float32)) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
-        s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, vr.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-__all__ = ["neighbor_min_ref", "label_agree_ref", "attention_ref",
-           "INF_I32"]
+__all__ = ["neighbor_min_ref", "label_agree_ref", "INF_I32"]

@@ -1,30 +1,25 @@
-"""Roofline analysis from compiled dry-run artifacts.
+"""Roofline tooling for the clustering engine's compiled programs.
 
-Three terms per (arch × shape × mesh), with the peaks of the dry run's
-target chip (:data:`DRYRUN_KIND`, a TPU v5e):
+Three pieces, all against the peaks of one chip kind (:data:`PEAKS`):
 
-    compute    = FLOPs / (chips × 197e12 bf16 FLOP/s)
-    memory     = bytes_accessed / (chips × 819e9 B/s HBM)
-    collective = collective_bytes / (chips × 50e9 B/s ICI per link)
-
-FLOPs/bytes sources. XLA's ``compiled.cost_analysis()`` counts a while-loop
-body ONCE (verified empirically — a scan of 8 matmuls reports 1 matmul of
-FLOPs), and every layer stack here is scanned. We therefore report BOTH:
-``hlo_flops_raw`` (cost_analysis, undercounted) and the corrected values
-obtained by walking the post-partitioning HLO with while-loop trip-count
-multipliers (parsed from each loop condition's comparison constant — scans
-lower to exactly that pattern). The same walk accumulates per-op collective
-bytes (result-shape bytes × executions), which cost_analysis does not
-expose at all. MODEL_FLOPS = 6·N·D (dense) / 6·N_active·D (MoE) is computed
-from the config, and the ratio MODEL_FLOPS / HLO_FLOPs reports how much
-compiled compute is "useful".
+* :func:`chip_peaks` — the published bf16 FLOP/s, HBM bandwidth and ICI
+  bandwidth of a ``jax.Device.device_kind`` (the dry runs target a TPU
+  v5e, :data:`DRYRUN_KIND`).
+* :func:`collective_stats` — per-kind collective bytes and counts of a
+  post-partitioning HLO module. XLA's ``compiled.cost_analysis()`` counts
+  a while-loop body once and does not expose collective traffic at all,
+  so the walk multiplies each collective's result-shape bytes by its
+  participants and by the trip counts of the loops enclosing it.
+* :func:`ell_kernel_roofline` — analytic FLOP/byte models of the two
+  batched ELL kernels (``neighbor_min``, ``label_agree``), a hardware
+  lower bound for measured kernel walls.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -230,206 +225,6 @@ def collective_stats(hlo: str, default_participants: int = 1
     return CollectiveStats(bytes_by, count_by)
 
 
-# ---------------------------------------------------------------------------
-# Analytic FLOPs/bytes per (config × shape) — scan-corrected ground truth.
-# ---------------------------------------------------------------------------
-
-
-def _attn_flops(cfg, tokens: int, kv_len: int) -> float:
-    """Matmul FLOPs for attention projections + scores+values per token set."""
-    d, hd = cfg.d_model, cfg.head_dim
-    nq = cfg.num_heads * hd
-    nkv = cfg.num_kv_heads * hd
-    proj = 2.0 * tokens * d * (nq + 2 * nkv) + 2.0 * tokens * nq * d
-    scores = 2.0 * tokens * kv_len * cfg.num_heads * hd * 2  # qk^T + pv
-    return proj + scores
-
-
-def _mlp_flops(cfg, tokens: int, ff: Optional[int] = None) -> float:
-    f = ff or cfg.d_ff
-    return 2.0 * tokens * cfg.d_model * f * 3
-
-
-def forward_flops(cfg, batch: int, seq: int, kv_len: Optional[int] = None,
-                  moe_impl: str = "sort", is_decode: bool = False) -> float:
-    """Forward-pass matmul FLOPs (the quantity XLA would count, corrected).
-
-    ``is_decode``: cross-attention K/V and encoder/image towers are cached —
-    only the new token's q/self-kv projections and scores are paid.
-    """
-    t = batch * seq
-    kv = kv_len if kv_len is not None else seq
-    total = 0.0
-    if cfg.family in ("dense", "vlm"):
-        per = _attn_flops(cfg, t, kv) + _mlp_flops(cfg, t)
-        if cfg.family == "vlm":
-            g = cfg.num_layers // cfg.cross_attn_every
-            n_self = cfg.num_layers - g
-            total += n_self * (_attn_flops(cfg, t, kv) + _mlp_flops(cfg, t))
-            timg = 0 if is_decode else batch * cfg.num_image_tokens
-            d, hd = cfg.d_model, cfg.head_dim
-            xproj = (2.0 * t * d * cfg.num_heads * hd
-                     + 2.0 * timg * d * 2 * cfg.num_kv_heads * hd
-                     + 2.0 * t * cfg.num_heads * hd * d)
-            xscores = 2.0 * t * cfg.num_image_tokens * cfg.num_heads * hd * 2
-            total += g * (xproj + xscores + _mlp_flops(cfg, t))
-        else:
-            total += cfg.num_layers * per
-    elif cfg.family == "moe":
-        ff = cfg.moe_d_ff or cfg.d_ff
-        router = 2.0 * t * cfg.d_model * cfg.num_experts
-        expert = _mlp_flops(cfg, t, ff) * cfg.experts_per_tok
-        if moe_impl == "einsum":
-            cap = t * cfg.experts_per_tok * 1.25
-            expert = _mlp_flops(cfg, int(cap / max(1, t) * t), ff)
-            expert = 2.0 * cap * cfg.d_model * ff * 3
-            dispatch = 2.0 * t * cfg.num_experts * (
-                cap / cfg.num_experts) * cfg.d_model * 2
-            expert += dispatch
-        total += cfg.num_layers * (_attn_flops(cfg, t, kv) + router + expert)
-    elif cfg.family == "ssm":   # rwkv6
-        d = cfg.d_model
-        per_tm = 2.0 * t * d * d * 4 + 2.0 * t * d * d  # r,k,v,g proj + out
-        h, n = d // cfg.rwkv_head_dim, cfg.rwkv_head_dim
-        per_wkv = 2.0 * t * h * n * n * 3               # scores/state/out
-        per_cm = 2.0 * t * d * cfg.d_ff * 2 + 2.0 * t * d * d
-        total += cfg.num_layers * (per_tm + per_wkv + per_cm)
-    elif cfg.family == "hybrid":
-        d = cfg.d_model
-        d_in = cfg.ssm_expand * d
-        h = d_in // cfg.ssm_head_dim
-        n = cfg.ssm_state
-        proj = 2.0 * t * d * (2 * d_in + 2 * n + h) + 2.0 * t * d_in * d
-        chunk = 64.0
-        ssd = 2.0 * t * chunk * n + 2.0 * t * chunk * cfg.ssm_head_dim * h
-        ssd += 2.0 * t * n * d_in * 2
-        total += cfg.num_layers * (proj + ssd)
-        g = cfg.num_layers // cfg.attn_every
-        total += g * (_attn_flops(cfg, t, kv) + _mlp_flops(cfg, t))
-    elif cfg.family == "encdec":
-        te = 0 if is_decode else batch * cfg.encoder_seq
-        if not is_decode:
-            total += cfg.encoder_layers * (
-                _attn_flops(cfg, te, cfg.encoder_seq) + _mlp_flops(cfg, te))
-        d, hd = cfg.d_model, cfg.head_dim
-        self_part = _attn_flops(cfg, t, kv)
-        xproj = (2.0 * t * d * cfg.num_heads * hd
-                 + 2.0 * te * d * 2 * cfg.num_kv_heads * hd
-                 + 2.0 * t * cfg.num_heads * hd * d)
-        xscores = 2.0 * t * cfg.encoder_seq * cfg.num_heads * hd * 2
-        total += cfg.num_layers * (self_part + xproj + xscores
-                                   + _mlp_flops(cfg, t))
-    # embedding lookup ~ free; lm head:
-    total += 2.0 * t * cfg.d_model * cfg.padded_vocab
-    return total
-
-
-def step_flops(cfg, shape, moe_impl: str = "sort") -> float:
-    """Total FLOPs of the lowered program for this cell."""
-    b, s = shape.global_batch, shape.seq_len
-    if shape.kind == "train":
-        return 3.0 * forward_flops(cfg, b, s, moe_impl=moe_impl)  # fwd+bwd
-    if shape.kind == "prefill":
-        return forward_flops(cfg, b, s, moe_impl=moe_impl)
-    # decode: one token against kv_len cache
-    return forward_flops(cfg, b, 1, kv_len=s, moe_impl=moe_impl,
-                         is_decode=True)
-
-
-def model_flops(cfg, shape) -> float:
-    """6·N·D with N = (active) params, D = processed tokens (train);
-    2·N·D for inference kinds (fwd only)."""
-    n = active_param_count(cfg)
-    d_tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode"
-                                     else 1)
-    mult = 6.0 if shape.kind == "train" else 2.0
-    return mult * n * d_tokens
-
-
-def active_param_count(cfg) -> int:
-    n = cfg.param_count()
-    if cfg.num_experts:
-        ff = cfg.moe_d_ff or cfg.d_ff
-        inactive = (cfg.num_experts - cfg.experts_per_tok) * 3 * cfg.d_model * ff
-        n -= cfg.num_layers * inactive
-    return n
-
-
-def hbm_bytes(cfg, shape, param_bytes: int, cache_bytes: int = 0,
-              opt_bytes: int = 0) -> float:
-    """Analytic HBM traffic per step: weights are read once per microbatch
-    pass (fwd + bwd re-read + optimizer read/write), caches read+written,
-    activations ~ 2× residual stream per layer."""
-    b, s = shape.global_batch, shape.seq_len
-    if shape.kind == "train":
-        traffic = param_bytes * 3.0 + opt_bytes * 2.0
-    elif shape.kind == "prefill":
-        traffic = param_bytes + cache_bytes
-    else:
-        traffic = param_bytes + cache_bytes  # full cache read each token
-    t = b * (s if shape.kind != "decode" else 1)
-    act = 2.0 * t * cfg.d_model * 2 * max(1, cfg.num_layers)
-    return traffic + act
-
-
-@dataclasses.dataclass
-class Roofline:
-    chips: int
-    flops: float
-    bytes_hbm: float
-    coll_bytes: float
-    hlo_flops_raw: float
-    hlo_bytes_raw: float
-    model_flops_: float
-
-    @property
-    def t_compute(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS_BF16)
-
-    @property
-    def t_memory(self) -> float:
-        return self.bytes_hbm / (self.chips * HBM_BW)
-
-    @property
-    def t_collective(self) -> float:
-        return self.coll_bytes / (self.chips * ICI_BW)
-
-    @property
-    def bottleneck(self) -> str:
-        terms = {"compute": self.t_compute, "memory": self.t_memory,
-                 "collective": self.t_collective}
-        return max(terms, key=terms.get)
-
-    @property
-    def useful_ratio(self) -> float:
-        return self.model_flops_ / max(1.0, self.flops)
-
-    @property
-    def roofline_fraction(self) -> float:
-        """Fraction of the dominant-term-bound step time that is useful
-        model compute."""
-        t_star = max(self.t_compute, self.t_memory, self.t_collective)
-        t_model = self.model_flops_ / (self.chips * PEAK_FLOPS_BF16)
-        return t_model / max(t_star, 1e-30)
-
-    def as_dict(self) -> dict:
-        return {
-            "chips": self.chips,
-            "flops": self.flops,
-            "bytes_hbm": self.bytes_hbm,
-            "collective_bytes": self.coll_bytes,
-            "hlo_flops_raw": self.hlo_flops_raw,
-            "hlo_bytes_raw": self.hlo_bytes_raw,
-            "model_flops": self.model_flops_,
-            "t_compute_s": self.t_compute,
-            "t_memory_s": self.t_memory,
-            "t_collective_s": self.t_collective,
-            "bottleneck": self.bottleneck,
-            "useful_ratio": self.useful_ratio,
-            "roofline_fraction": self.roofline_fraction,
-        }
-
-
 # --- Batched ELL kernel models (the clustering engine's hot loop) ----------
 #
 # The two Pallas kernels the fused bucket program spends its rounds in
@@ -548,8 +343,6 @@ __all__ = [
     "ChipPeaks", "PEAKS", "chip_peaks", "DRYRUN_KIND",
     "PEAK_FLOPS_BF16", "HBM_BW", "ICI_BW",
     "collective_stats", "CollectiveStats",
-    "forward_flops", "step_flops", "model_flops", "active_param_count",
-    "hbm_bytes", "Roofline",
     "ELL_KERNELS", "ell_kernel_flops", "ell_kernel_bytes",
     "KernelRoofline", "ell_kernel_roofline",
 ]

@@ -56,27 +56,23 @@ Admission backpressure (bounded in-flight work)
   from flush-latency telemetry.
 
 Admission-time packing (build/assemble split)
-  With ``prebuild_rows=True`` (default) every cold admission finishes its
-  per-graph packing work right away: :func:`repro.core.plan.
-  build_packed_rows` scatters the plan's canonical edge list into the
-  graph's :class:`~repro.core.plan.PackedRows` and dispatches its rank
-  permutations, once per request. Flushes then *assemble* buckets by row
-  copies into the leased staging arrays — the argsort/bincount host work
-  leaves the flush critical path, which is what the admission-time split
-  buys (JetStream-style: per-request preprocessing at admission, batch
-  assembly a memcpy). ``prebuild_rows=False`` keeps the legacy
-  derive-at-flush packing; both paths are bit-identical and the
-  ``pack_split`` scenario in ``benchmarks/serve_bench.py`` asserts the
-  assemble-vs-pack latency win.
+  Every cold admission finishes its per-graph packing work right away:
+  :func:`repro.core.plan.build_packed_rows` scatters the plan's canonical
+  edge list into the graph's :class:`~repro.core.plan.PackedRows` and
+  dispatches its rank permutations, once per request. Flushes then
+  *assemble* buckets by row copies into the leased staging arrays — the
+  argsort/bincount host work stays off the flush critical path
+  (JetStream-style: per-request preprocessing at admission, batch
+  assembly a memcpy).
 
 Telemetry (the policies' stats surface)
   Every harvested flush records its host bucket-assembly time and
   submit→fetch wall time — stamped by the executor layer on the
   :class:`~repro.core.executor.InFlightBucket` handle — into
   ``stats.latency`` (a :class:`~repro.serve.scheduler.FlushTelemetry`),
-  keyed by bucket shape; prebuilt admissions record their per-request
-  row-build time into the same telemetry's ``build`` stream. Policies
-  read the EWMAs; benchmarks emit the p50/p99 summaries.
+  keyed by bucket shape; admissions record their per-request row-build
+  time into the same telemetry's ``build`` stream. Policies read the
+  EWMAs; benchmarks emit the p50/p99 summaries.
 
 Buffer reuse
   All flushes route through one :class:`repro.core.plan.BucketBufferPool`:
@@ -244,12 +240,6 @@ class ClusterBatcher:
         hit retires at admission, bit-identical to a cold flush — the
         fingerprint covers the exact PRNG key, so caching never trades
         determinism for speed.
-      prebuild_rows: build each cold admission's
-        :class:`~repro.core.plan.PackedRows` at admission (default), so
-        flushes assemble buckets by row copies instead of re-deriving
-        every graph's ELL rows. ``False`` restores the legacy
-        derive-at-flush packing — bit-identical results either way (the
-        benchmark's ``pack_split`` scenario runs both arms).
     """
 
     def __init__(self, max_batch: int = 64, method: str = "pivot",
@@ -262,8 +252,7 @@ class ClusterBatcher:
                  executor="sync",
                  max_in_flight: Optional[int] = None,
                  policy=None,
-                 result_cache=True,
-                 prebuild_rows: bool = True):
+                 result_cache=True):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if max_wait is not None and max_wait < 0:
@@ -284,7 +273,6 @@ class ClusterBatcher:
         self.pool = pool if pool is not None else BucketBufferPool()
         self.executor = make_executor(executor)
         self.max_in_flight = max_in_flight
-        self.prebuild_rows = prebuild_rows
         self.policy = make_policy(policy, max_batch=max_batch,
                                   max_wait=max_wait,
                                   max_in_flight=max_in_flight)
@@ -392,7 +380,7 @@ class ClusterBatcher:
                 f"{self.executor.in_flight} flushes in flight; retry after "
                 "retiring")
         req.admitted_at = now
-        if self.prebuild_rows and plan.rows is None:
+        if plan.rows is None:
             # The request's per-graph packing work, done once here — the
             # ELL scatter from the plan's canonical edges plus the async
             # rank dispatch — so its flushes only copy rows. Placed after
@@ -592,12 +580,13 @@ class ClusterBatcher:
         kernel path). Tier check goes through ``TuningCache.get`` with
         counting on, so warmup hits/misses are observable engine-side.
 
-        Sweep tensors pack into leased pool staging — the same
-        ``pack_bucket`` + :class:`~repro.core.plan.BucketBufferPool` path
-        flushes use, not ad-hoc buffers — so the pool's lease invariant
-        covers the sweep too. The lease is released right after the sweep
-        returns: ``sweep_bucket`` copies host→device and blocks on every
-        timing, so nothing in flight reads the staging afterwards.
+        Sweep tensors are built and packed as flushes build and pack them
+        (:func:`~repro.core.plan.build_packed_rows`, then ``pack_bucket``
+        into leased :class:`~repro.core.plan.BucketBufferPool` staging),
+        so the pool's lease invariant covers the sweep too. The lease is
+        released right after the sweep returns: ``sweep_bucket`` copies
+        host→device and blocks on every timing, so nothing in flight reads
+        the staging afterwards.
         """
         from repro.core.plan import pack_bucket
         from repro.kernels import autotune as _at
@@ -620,12 +609,13 @@ class ClusterBatcher:
             while len(use) < gp:
                 use.extend(plans)
             use = use[:gp]
-            keys = [sample_keys(jax.random.PRNGKey(i), k)
-                    for i in range(len(use))]
+            use = [dataclasses.replace(p, rows=build_packed_rows(
+                       p, sample_keys(jax.random.PRNGKey(i), k)))
+                   for i, p in enumerate(use)]
             lease = self.pool.acquire(gp * k, R, W)
             try:
                 ell, ranks, elig, _m, _pad = pack_bucket(
-                    use, keys, k=k, g_pad=gp, staging=lease.arrays)
+                    use, k=k, g_pad=gp, staging=lease.arrays)
                 _at.sweep_bucket(ell, ranks, elig, cache=cache,
                                  candidates=candidates, repeats=repeats)
             finally:
@@ -758,15 +748,12 @@ class ClusterBatcher:
                 "coalescing/stealing is refused")
         # Promotion is a no-op for native requests; for stolen ones it
         # re-targets the plan at the flush's larger shape (bit-exact),
-        # relaying any prebuilt rows via pad-copies. Prebuilt plans drew
-        # their rank permutations at admission, so no sample keys are
-        # derived for them here — that fold_in work is off the flush path.
+        # relaying its rows via pad-copies. The rows drew their rank
+        # permutations at admission, so no sample keys are derived here.
         plans = [promote_plan(r.plan, R, W) for r in reqs]
-        bkeys = [None if p.rows is not None else sample_keys(r.key, k)
-                 for r, p in zip(reqs, plans)]
         try:
             _, pack = pack_and_submit(
-                plans, bkeys, k, self.executor, pool=self.pool,
+                plans, k, self.executor, pool=self.pool,
                 use_kernel=self.use_kernel, payload=reqs,
                 objective=self.objective, flush=ordinal)
         except BaseException:

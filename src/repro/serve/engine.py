@@ -1,23 +1,21 @@
-"""Unified serving-engine protocol: admit / flush / retire / stats.
+"""Serving-engine protocol: admit / flush / retire / stats.
 
 JetStream structures its serving stack around a small engine API that an
-outer scheduling loop drives (``prefill`` / ``generate`` / ``insert`` over
-shape-static device programs); vLLM's continuous batching is the same idea
-with slots. This module distils the discipline both of this repo's serving
-paths share into one :class:`ClusterEngine` protocol so the token path
-(:class:`repro.serve.batching.ContinuousBatcher`) and the clustering path
-(:class:`repro.serve.cluster_batcher.ClusterBatcher`) stop duplicating
-queue/retire bookkeeping and can be driven by the same outer loop:
+outer scheduling loop drives over shape-static device programs. This
+module states that API for the clustering service as one
+:class:`ClusterEngine` protocol, implemented by
+:class:`repro.serve.cluster_batcher.ClusterBatcher`, so an outer loop
+(:func:`serve_all`, or the benchmark's driver) needs nothing else:
 
 * ``admit(request)`` — hand one request to the engine. The engine may run
-  device work immediately (a bucket filled, a slot freed) and returns any
-  requests that *retired* as a direct consequence; otherwise ``[]``.
+  device work immediately (a bucket filled) and returns any requests
+  that *retired* as a direct consequence; otherwise ``[]``.
   Engines with admission control may refuse instead: ``ClusterBatcher``
   raises ``AdmissionRejected`` (and counts ``stats.rejected``) while its
   ``max_in_flight`` backpressure bound is hit — the caller sheds load or
   retries after the next retire, rather than queueing unboundedly.
 * ``flush()`` — force pending work through the device: drain partially
-  filled buckets / decode remaining slots. Returns the retired requests.
+  filled buckets. Returns the retired requests.
   Engines with a deadline policy also expose ``poll(now)`` to flush only
   what has waited past its budget.
 * ``retire()`` — drain the finished-request queue without running device
@@ -49,7 +47,7 @@ class AdmissionRejected(RuntimeError):
 @dataclasses.dataclass
 class EngineStats:
     """Counters every serving engine keeps; subclasses add path-specific
-    fields (padding accounting, decode-step counts, ...). ``policy`` names
+    fields (padding accounting, ...). ``policy`` names
     the scheduling policy driving the engine's flush/admission decisions —
     part of the protocol's stats surface so outer loops and benchmarks can
     report which scheduler produced the numbers. ``cache_hits`` counts
@@ -76,7 +74,7 @@ class EngineStats:
 
 @runtime_checkable
 class ClusterEngine(Protocol):
-    """Structural protocol for slot/bucket serving engines (see module doc)."""
+    """Structural protocol for bucket serving engines (see module doc)."""
 
     stats: Any
 

@@ -155,12 +155,13 @@ def test_resolve_block_rows_untuned_is_none():
 
 def _packed_bucket(graphs, g_pad=None, k=1):
     from repro.core.api import sample_keys
-    from repro.core.plan import pack_bucket
+    from repro.core.plan import build_packed_rows, pack_bucket
 
     plans = [plan_graph(g) for g in graphs]
-    keys = [sample_keys(jax.random.PRNGKey(i), k)
-            for i in range(len(plans))]
-    return pack_bucket(plans, keys, k=k, g_pad=g_pad)
+    for i, plan in enumerate(plans):
+        plan.rows = build_packed_rows(
+            plan, sample_keys(jax.random.PRNGKey(i), k))
+    return pack_bucket(plans, k=k, g_pad=g_pad)
 
 
 def test_sweep_records_winner_and_cache():

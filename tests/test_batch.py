@@ -47,7 +47,8 @@ def test_batch_bucket_boundaries_bit_exact(n):
     _assert_matches(g, key, res)
 
 
-@pytest.mark.parametrize("method", ["pivot", "pivot_raw"])
+@pytest.mark.parametrize("method",
+                         ["pivot", "pivot_raw", "precluster"])
 def test_batch_64_graphs_bit_exact(method):
     """Acceptance: ≥64 mixed-shape graphs, labels/costs ≡ per-graph engine."""
     rng = np.random.default_rng(0)
@@ -64,7 +65,7 @@ def test_batch_64_graphs_bit_exact(method):
         _assert_matches(g, key, res, method=method)
 
 
-@pytest.mark.parametrize("method", ["pivot", "precluster"])
+@pytest.mark.parametrize("method", ["pivot", "pivot_raw", "precluster"])
 def test_minmax_objective_matches_host_oracle(method):
     """objective='minmax' scores the same labels with the worst-vertex
     disagreement: the rounds body is untouched (labels identical to the
@@ -99,23 +100,33 @@ def test_batch_edgeless_graph():
     assert res.cost == 0
 
 
-def test_batch_num_samples_matches_single():
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("num_samples", [2, 3, 4])
+def test_batch_num_samples_matches_single(num_samples, use_kernel):
     graphs = [_rand_graph(n, 2, seed=n) for n in (10, 20, 30)]
     keys = [jax.random.PRNGKey(i) for i in range(3)]
-    results = correlation_cluster_batch(graphs, keys=keys, num_samples=4)
+    results = correlation_cluster_batch(graphs, keys=keys,
+                                        num_samples=num_samples,
+                                        use_kernel=use_kernel)
     for g, key, res in zip(graphs, keys, results):
-        _assert_matches(g, key, res, num_samples=4)
-        assert res.info["num_samples"] == 4
+        _assert_matches(g, key, res, num_samples=num_samples,
+                        use_kernel=use_kernel)
+        assert res.info["num_samples"] == num_samples
 
 
-def test_batch_kernel_path_bit_exact():
+@pytest.mark.parametrize("objective", ["disagree", "minmax"])
+@pytest.mark.parametrize("method", ["pivot", "pivot_raw", "precluster"])
+def test_batch_kernel_path_bit_exact(method, objective):
     graphs = [_rand_graph(n, 2, seed=n) for n in (9, 16, 33)]
     keys = [jax.random.PRNGKey(i) for i in range(3)]
-    jnp_res = correlation_cluster_batch(graphs, keys=keys, use_kernel=False)
-    ker_res = correlation_cluster_batch(graphs, keys=keys, use_kernel=True)
+    opts = dict(keys=keys, method=method, objective=objective,
+                num_samples=2)
+    jnp_res = correlation_cluster_batch(graphs, use_kernel=False, **opts)
+    ker_res = correlation_cluster_batch(graphs, use_kernel=True, **opts)
     for a, b in zip(jnp_res, ker_res):
         assert (a.labels == b.labels).all()
         assert a.cost == b.cost
+        assert a.info["picked_sample"] == b.info["picked_sample"]
 
 
 @settings(max_examples=15, deadline=None)

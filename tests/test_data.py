@@ -1,11 +1,10 @@
-"""Data pipeline: dedup-by-correlation-clustering quality + deterministic
-batching (the paper's first-class integration point)."""
+"""Data pipeline: dedup-by-correlation-clustering quality and the token
+stream it keeps (the paper's first-class integration point)."""
 
 import numpy as np
 import pytest
 
 from repro.data.dedup import dedup_corpus, dedup_quality, minhash_signatures, similarity_edges
-from repro.data.pipeline import PipelineConfig, TokenPipeline
 from repro.data.synthetic import synthetic_corpus, token_stream
 
 
@@ -49,31 +48,6 @@ def test_similarity_graph_is_sparse():
     edges = similarity_edges(sigs, threshold=0.45)
     n = len(corpus.docs)
     assert len(edges) < 0.1 * n * (n - 1) / 2, "graph should be sparse"
-
-
-def test_pipeline_determinism_and_resume():
-    stream = np.arange(100_000, dtype=np.int32) % 977
-    cfg = PipelineConfig(seq_len=64, global_batch=8, seed=0)
-    p1 = TokenPipeline(stream, cfg)
-    p2 = TokenPipeline(stream, cfg)
-    for step in (0, 3, 17):
-        b1 = p1.batch_at(step)
-        b2 = p2.batch_at(step)
-        assert (b1["tokens"] == b2["tokens"]).all()
-        assert (b1["labels"] == b2["labels"]).all()
-    # labels are next-token shifted
-    b = p1.batch_at(5)
-    assert (b["tokens"][:, 1:] == b["labels"][:, :-1]).all()
-
-
-def test_pipeline_sharding_partitions_batch():
-    stream = np.arange(50_000, dtype=np.int32)
-    cfg = PipelineConfig(seq_len=32, global_batch=8, seed=1)
-    p = TokenPipeline(stream, cfg)
-    full = p.batch_at(2)["tokens"]
-    parts = [p.batch_at(2, shard=i, num_shards=4)["tokens"]
-             for i in range(4)]
-    assert (np.concatenate(parts) == full).all()
 
 
 def test_token_stream_respects_keep_mask():

@@ -11,7 +11,7 @@ core/batch.py):
 * flushes through a :class:`BucketBufferPool` (staging reuse + donated
   device inputs) return identical results on reuse;
 * the packer's ``PackStats`` is the single source of pad accounting;
-* both serving paths satisfy the :class:`ClusterEngine` protocol.
+* ``ClusterBatcher`` satisfies the :class:`ClusterEngine` protocol.
 """
 
 import jax
@@ -28,7 +28,6 @@ from repro.core import (
 from repro.core import batch as batch_mod
 from repro.core.batch import _cost_host
 from repro.core.graph import gnp, random_arboric, star
-from repro.serve.batching import ContinuousBatcher
 from repro.serve.cluster_batcher import ClusterBatcher, ClusterRequest
 from repro.serve.engine import ClusterEngine, EngineStats, serve_all
 from repro.util import VirtualClock, next_pow2
@@ -303,67 +302,17 @@ def test_empty_graph_request_is_graceful():
 
 
 # ---------------------------------------------------------------------------
-# Protocol conformance (tentpole: one engine API for both paths).
+# Protocol conformance.
 # ---------------------------------------------------------------------------
 
 
-def test_both_paths_satisfy_engine_protocol():
+def test_cluster_batcher_satisfies_engine_protocol():
     cluster = ClusterBatcher(max_batch=2)
-    token = ContinuousBatcher(model=None, params=None, max_slots=1)
     assert isinstance(cluster, ClusterEngine)
-    assert isinstance(token, ClusterEngine)
-    # Idle engines: flush/retire are safe no-ops returning [].
-    assert token.flush() == [] and token.retire() == []
+    # An idle engine: flush/retire are safe no-ops returning [].
     assert cluster.flush() == [] and cluster.retire() == []
-    assert token.pending() == 0 and cluster.pending() == 0
+    assert cluster.pending() == 0
     assert isinstance(cluster.stats, EngineStats)
-    assert isinstance(token.stats, EngineStats)
-
-
-class _ConstLogitModel:
-    """Fake decode model: prefill/decode always argmax to a fixed token."""
-
-    class cfg:
-        vocab_size = 4
-
-    def __init__(self, token, fail_on_decode=False):
-        self.token = token
-        self.fail_on_decode = fail_on_decode
-
-    def _logits(self):
-        import jax.numpy as jnp
-        return jnp.zeros((1, 4)).at[0, self.token].set(5.0)
-
-    def prefill(self, params, batch, cache_len):
-        return self._logits(), {}
-
-    def decode_step(self, params, tok, caches, pos):
-        assert not self.fail_on_decode, \
-            "decode ran for a request already finished at prefill"
-        return self._logits(), caches
-
-
-def test_token_path_retires_at_prefill():
-    """EOS (or max_new_tokens) hit by the prefill token retires the request
-    before any decode tick — no garbage token past the stop condition."""
-    from repro.serve.batching import Request
-
-    # Prefill emits EOS directly.
-    eos_model = _ConstLogitModel(token=1, fail_on_decode=True)
-    b = ContinuousBatcher(eos_model, params=None, max_slots=2, eos_token=1)
-    done = b.admit(Request(uid=0, prompt=np.array([2, 3], np.int32),
-                           max_new_tokens=5))
-    assert [r.uid for r in done] == [0]
-    assert done[0].out_tokens == [1]
-    assert b.pending() == 0
-
-    # max_new_tokens=1 satisfied by the prefill token (non-EOS).
-    one_model = _ConstLogitModel(token=2, fail_on_decode=True)
-    b2 = ContinuousBatcher(one_model, params=None, max_slots=1, eos_token=1)
-    done = b2.admit(Request(uid=1, prompt=np.array([3], np.int32),
-                            max_new_tokens=1))
-    assert len(done) == 1 and done[0].out_tokens == [2]
-    assert b2.flush() == []
 
 
 def test_streaming_dedup_rejects_mismatched_reused_batcher():

@@ -46,7 +46,7 @@ from repro.core import executor as exec_mod
 from repro.core.api import sample_keys
 from repro.core.executor import run_bucket_program
 from repro.core.graph import path, random_arboric
-from repro.core.plan import pack_bucket
+from repro.core.plan import build_packed_rows, pack_bucket
 from repro.serve.cluster_batcher import (
     AdmissionRejected,
     ClusterBatcher,
@@ -139,7 +139,7 @@ def test_precluster_executors_bit_exact(executor, use_kernel):
                         method="precluster", num_samples=2)
 
 
-@pytest.mark.parametrize("executor", ["async", "sharded"])
+@pytest.mark.parametrize("executor", ["sync", "async", "sharded"])
 def test_batch_api_executor_param_bit_exact(executor):
     graphs = [_rand_graph(n, 2, seed=n) for n in (7, 9, 16, 33)]
     keys = [jax.random.PRNGKey(i) for i in range(4)]
@@ -314,10 +314,10 @@ def test_handle_result_releases_lease_exactly_once():
     pool = BucketBufferPool()
     g = build_graph(6, path(6))
     plan = plan_graph(g)
+    plan.rows = build_packed_rows(plan, sample_keys(jax.random.PRNGKey(0), 1))
     lease = pool.acquire(1, plan.R, plan.W)
     ell, ranks, elig, m, _ = pack_bucket(
-        [plan], [sample_keys(jax.random.PRNGKey(0), 1)], k=1,
-        staging=lease.arrays, g_pad=1)
+        [plan], k=1, staging=lease.arrays, g_pad=1)
     ex = AsyncExecutor()
     h = ex.submit(ell, ranks, elig, m, k=1, donate=pool.donate, lease=lease)
     assert pool.leased == 1
@@ -530,8 +530,8 @@ def test_sync_executor_completes_at_submit():
     ex = SyncExecutor()
     g = build_graph(6, path(6))
     plan = plan_graph(g)
-    ell, ranks, elig, m, _ = pack_bucket(
-        [plan], [sample_keys(jax.random.PRNGKey(0), 1)], k=1)
+    plan.rows = build_packed_rows(plan, sample_keys(jax.random.PRNGKey(0), 1))
+    ell, ranks, elig, m, _ = pack_bucket([plan], k=1)
     h = ex.submit(ell, ranks, elig, m, k=1)
     assert h.ready() and h.harvested
     assert ex.retire() == [h]           # delivered exactly once
@@ -653,8 +653,8 @@ def kron_graph():
 
 def _kron_pack(graph, method, k=2):
     plan = plan_graph(graph, method=method)
-    keys = [sample_keys(jax.random.PRNGKey(3), k)]
-    return pack_bucket([plan], keys, k=k)[:4]
+    plan.rows = build_packed_rows(plan, sample_keys(jax.random.PRNGKey(3), k))
+    return pack_bucket([plan], k=k)[:4]
 
 
 @pytest.mark.parametrize("objective", ["disagree", "minmax"])

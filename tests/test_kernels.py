@@ -331,57 +331,3 @@ def test_prepare_ell_orders_rows_and_counts_tiles(case, rng):
     counts = np.asarray(tile_counts(jnp.asarray(ell), layout))
     assert (counts[:, 0] == swept).all() and (counts[:, 1] == full).all()
     assert (np.asarray(tile_counts(jnp.asarray(ell)))[:, 0] == full).all()
-
-
-# --- flash attention --------------------------------------------------------
-
-SHAPES = [
-    (1, 4, 4, 128, 128, 64, True, jnp.float32),
-    (2, 4, 2, 128, 128, 64, True, jnp.float32),     # GQA
-    (1, 8, 1, 256, 256, 64, True, jnp.bfloat16),    # MQA bf16
-    (2, 4, 4, 128, 384, 64, True, jnp.float32),     # kv longer (decode-ish)
-    (1, 2, 2, 192, 192, 32, False, jnp.float32),    # non-causal, ragged
-    (1, 9, 3, 130, 130, 64, True, jnp.float32),     # odd sizes (padding)
-    (1, 4, 4, 64, 64, 128, True, jnp.bfloat16),     # big head dim
-]
-
-
-@pytest.mark.parametrize("b,h,kh,sq,sk,d,causal,dtype", SHAPES)
-def test_flash_attention_matches_ref(b, h, kh, sq, sk, d, causal, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (b, h, sq, d), jnp.float32).astype(dtype)
-    k = jax.random.normal(ks[1], (b, kh, sk, d), jnp.float32).astype(dtype)
-    v = jax.random.normal(ks[2], (b, kh, sk, d), jnp.float32).astype(dtype)
-    out = ops.flash_attention(q, k, v, causal=causal)
-    expect = ref.attention_ref(q, k, v, causal=causal)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
-                                - expect.astype(jnp.float32))))
-    assert err < tol, (err, tol)
-
-
-def test_flash_attention_block_sweep():
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (1, 4, 256, 64))
-    k = jax.random.normal(ks[1], (1, 4, 256, 64))
-    v = jax.random.normal(ks[2], (1, 4, 256, 64))
-    expect = ref.attention_ref(q, k, v, causal=True)
-    for bq, bk in [(64, 64), (128, 256), (256, 128)]:
-        out = ops.flash_attention(q, k, v, causal=True, block_q=bq,
-                                  block_k=bk)
-        assert float(jnp.max(jnp.abs(out - expect))) < 2e-5
-
-
-def test_chunked_xla_attention_matches_ref():
-    """The pure-XLA blocked softmax (production CPU/dry-run path) — same
-    contract as the kernel."""
-    from repro.models.attention import _chunked_attention, _naive_attention
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    b, sq, kh, g, hd, sk = 2, 200, 2, 2, 32, 200
-    q = jax.random.normal(ks[0], (b, sq, kh, g, hd))
-    k = jax.random.normal(ks[1], (b, sk, kh, hd))
-    v = jax.random.normal(ks[2], (b, sk, kh, hd))
-    for causal in (True, False):
-        a = _chunked_attention(q, k, v, causal, q_chunk=64, kv_chunk=96)
-        e = _naive_attention(q, k, v, causal)
-        assert float(jnp.max(jnp.abs(a - e))) < 2e-5

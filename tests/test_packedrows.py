@@ -1,28 +1,28 @@
-"""Admission-time packing split: PackedRows build, assemble, promotion.
+"""Admission-time packing: PackedRows build, assemble, promotion.
 
 The contracts under test (core/plan.py build_packed_rows / pack_bucket /
-PackedRows.promote, serve/cluster_batcher.py prebuild admission):
+PackedRows.promote, serve/cluster_batcher.py admission, core/batch.py):
 
 * one canonical edge list per plan — ``plan_graph`` lexsorts once and
-  both ``graph_fingerprint`` and the packer consume it, so the PR 6
-  fingerprint payload is byte-identical whether or not rows are prebuilt;
-* a bucket assembled from prebuilt rows is **byte-identical** to the
-  legacy full repack — same ELL/rank/eligibility/m_edges staging tensors,
-  not merely the same clustering (device reductions are order-invariant,
-  but we hold the stronger property so the bit-exactness contract can
-  never hinge on it);
+  both ``graph_fingerprint`` and the row builder consume it;
+* a bucket assembled from :func:`build_packed_rows` rows is
+  **byte-identical** to the derive-at-flush pack the engine once kept
+  beside it (:func:`_reference_staging`, this file's own numpy reference)
+  — same ELL/rank/eligibility/m_edges staging tensors, not merely the
+  same clustering (device reductions are order-invariant, but we hold the
+  stronger property so the bit-exactness contract can never hinge on it);
 * ``PackedRows.promote`` relayouts into any larger ``(R, W)`` and the
-  promoted rows assemble byte-identically to a legacy pack of the
+  promoted rows assemble byte-identically to the reference pack of the
   promoted plans (the coalesced-flush path);
-* the serving engine retires bit-identical results with ``prebuild_rows``
-  on and off, across executors, kernel paths, partial deadline
-  sub-batches and coalesced (stolen) flushes;
-* ``_pack_bucket`` survives as a deprecation shim of ``pack_bucket``;
+* ``pack_bucket`` only copies rows: a plan without them is refused;
+* the batch API and a serving flush stage the same bytes for the same
+  graphs and keys, since both build rows with ``build_packed_rows``;
+* the serving engine retires bit-identical results across executors,
+  kernel paths, partial deadline sub-batches and coalesced (stolen)
+  flushes;
 * ``warmup(autotune=True)`` stages its sweep tensors through pool leases
   (and releases them).
 """
-
-import warnings
 
 import jax
 import numpy as np
@@ -31,21 +31,26 @@ import pytest
 from repro.core import (
     BucketBufferPool,
     PackedRows,
+    SyncExecutor,
     build_graph,
     build_packed_rows,
     correlation_cluster,
+    correlation_cluster_batch,
     pack_bucket,
     plan_graph,
     promote_plan,
 )
 from repro.core.api import sample_keys
 from repro.core.graph import path, random_arboric
-from repro.core.mis import random_permutation_ranks_batch
-from repro.core.plan import _pack_bucket, graph_fingerprint, plan_canonical_edges
+from repro.core.mis import (random_permutation_ranks,
+                             random_permutation_ranks_batch)
+from repro.core.plan import graph_fingerprint, plan_canonical_edges
 from repro.serve.cluster_batcher import ClusterBatcher, ClusterRequest
 from repro.serve.engine import serve_all
 from repro.serve.scheduler import CoalescingPolicy
-from repro.util import VirtualClock
+from repro.util import VirtualClock, next_pow2
+
+_INT32_MAX = np.iinfo(np.int32).max
 
 
 def _graphs(num, lo, hi, seed, lam_hi=2):
@@ -63,15 +68,51 @@ def _path_graphs(ns):
     return [build_graph(n, path(n)) for n in ns]
 
 
-def _legacy(plans):
-    """Strip prebuilt rows so pack_bucket takes the full-repack path."""
-    for p in plans:
-        p.rows = None
+def _with_rows(plans, keys):
+    """Attach each plan's rows, built as admission builds them."""
+    for p, ks in zip(plans, keys):
+        p.rows = build_packed_rows(p, ks)
     return plans
 
 
+def _reference_staging(plans, keys, k, g_pad=None):
+    """The derive-at-flush pack, kept as this file's numpy reference.
+
+    Vertex ``x``'s ELL row lists its neighbours where it is the first
+    endpoint of a canonical edge, then where it is the second, each in
+    canonical order; pad id ``R``. Ranks are the per-graph engine's
+    ``random_permutation_ranks(n, key)`` per sample key (``INT32_MAX``
+    beyond ``n``), eligibility the plan's mask (slot ``R`` False), and
+    ``m_edges`` the full edge count. Group padding entries are all pad.
+    """
+    R, W = plans[0].bucket
+    g_pad = next_pow2(len(plans)) if g_pad is None else g_pad
+    b_pad = g_pad * k
+    ell = np.full((b_pad, R, W), R, dtype=np.int32)
+    ranks = np.full((b_pad, R + 1), _INT32_MAX, dtype=np.int32)
+    elig = np.zeros((b_pad, R + 1), dtype=bool)
+    m_edges = np.zeros((b_pad,), dtype=np.int32)
+    for gi, (plan, ks) in enumerate(zip(plans, keys)):
+        n = plan.n
+        adj = [[] for _ in range(n)]
+        edges = plan_canonical_edges(plan).tolist()
+        for u, v in edges:
+            adj[u].append(v)
+        for u, v in edges:
+            adj[v].append(u)
+        for si in range(k):
+            b = gi * k + si
+            for x, nbrs in enumerate(adj):
+                ell[b, x, :len(nbrs)] = nbrs
+            if n:
+                ranks[b, :n] = np.asarray(random_permutation_ranks(n, ks[si]))
+                elig[b, :n] = plan.eligible
+            m_edges[b] = plan.g.m
+    return ell, ranks, elig, m_edges, g_pad - len(plans)
+
+
 def _pack_pair(graphs, k=1, promote=False, seed=0):
-    """(prebuilt staging, legacy staging) for the same graphs and keys.
+    """(assembled staging, reference staging) for the same graphs and keys.
 
     With ``promote`` the plans are relayed into a bucket one pow2 step
     above the component-wise max of the group — the coalesced-flush path.
@@ -79,18 +120,14 @@ def _pack_pair(graphs, k=1, promote=False, seed=0):
     """
     keys = [sample_keys(jax.random.PRNGKey(seed + i), k)
             for i in range(len(graphs))]
-    mk = lambda: [plan_graph(g) for g in graphs]          # noqa: E731
-    pre, leg = mk(), _legacy(mk())
-    for p, ks in zip(pre, keys):
-        p.rows = build_packed_rows(p, ks)
+    built = _with_rows([plan_graph(g) for g in graphs], keys)
+    ref = [plan_graph(g) for g in graphs]
     if promote:
-        R = 2 * max(p.R for p in pre)
-        W = 2 * max(p.W for p in pre)
-        pre = [promote_plan(p, R, W) for p in pre]
-        leg = [promote_plan(p, R, W) for p in leg]
-    packed_pre = pack_bucket(pre, [None] * len(pre), k=k)
-    packed_leg = pack_bucket(leg, keys, k=k)
-    return packed_pre, packed_leg
+        R = 2 * max(p.R for p in built)
+        W = 2 * max(p.W for p in built)
+        built = [promote_plan(p, R, W) for p in built]
+        ref = [promote_plan(p, R, W) for p in ref]
+    return pack_bucket(built, k=k), _reference_staging(ref, keys, k)
 
 
 def _assert_staging_equal(a, b):
@@ -104,7 +141,7 @@ def _assert_staging_equal(a, b):
 
 
 # ---------------------------------------------------------------------------
-# Staging byte-equality: prebuilt assembly == legacy repack.
+# Staging byte-equality: assembled rows == the reference pack.
 # ---------------------------------------------------------------------------
 
 
@@ -124,37 +161,41 @@ def test_promoted_rows_match_legacy_pack_at_promoted_shape():
 
 
 def test_mixed_prebuilt_and_legacy_bucket():
+    # Rows from two engines' admissions, interleaved into one bucket,
+    # assemble to the reference pack of the same graphs and keys.
     graphs = _path_graphs([10, 16, 9, 13, 15, 12])
-    keys = [sample_keys(jax.random.PRNGKey(i), 1) for i in range(6)]
-    mixed = [plan_graph(g) for g in graphs]
-    for i, (p, ks) in enumerate(zip(mixed, keys)):
-        p.rows = build_packed_rows(p, ks) if i % 2 == 0 else None
-    group_keys = [None if p.rows is not None else ks
-                  for p, ks in zip(mixed, keys)]
-    legacy = _legacy([plan_graph(g) for g in graphs])
-    _assert_staging_equal(pack_bucket(mixed, group_keys, k=1),
-                          pack_bucket(legacy, keys, k=1))
+    engines = [ClusterBatcher(max_batch=64, result_cache=False)
+               for _ in range(2)]
+    reqs = [ClusterRequest(uid=i, graph=g, key=jax.random.PRNGKey(i))
+            for i, g in enumerate(graphs)]
+    for i, req in enumerate(reqs):
+        assert engines[i % 2].admit(req) == []
+    plans = [r.plan for r in reqs]
+    assert all(isinstance(p.rows, PackedRows) for p in plans)
+    keys = [sample_keys(r.key, 1) for r in reqs]
+    _assert_staging_equal(pack_bucket(plans, k=1),
+                          _reference_staging(plans, keys, k=1))
 
 
 def test_staging_reuse_resets_stale_tail():
     # A lease previously filled by a larger group must not leak rows into
-    # a smaller all-prebuilt pack (only the tail is re-stamped).
+    # a smaller pack (only the tail is re-stamped).
     pool = BucketBufferPool()
-    big = [plan_graph(g) for g in _path_graphs([9, 11, 13, 15, 16])]
+    keys = [sample_keys(jax.random.PRNGKey(i), 1) for i in range(5)]
+    big = _with_rows([plan_graph(g)
+                      for g in _path_graphs([9, 11, 13, 15, 16])], keys)
     R, W = big[0].bucket
     small = big[:2]
-    keys = [sample_keys(jax.random.PRNGKey(i), 1) for i in range(5)]
-    for p, ks in zip(big, keys):
-        p.rows = build_packed_rows(p, ks)
     lease = pool.acquire(8, R, W)
-    pack_bucket(big, [None] * 5, k=1, staging=lease.arrays, g_pad=8)
+    pack_bucket(big, k=1, staging=lease.arrays, g_pad=8)
     lease.release()
     lease = pool.acquire(8, R, W)      # same pooled (now dirty) buffers
-    reused = pack_bucket(small, [None] * 2, k=1, staging=lease.arrays,
-                         g_pad=8)
+    reused = pack_bucket(small, k=1, staging=lease.arrays, g_pad=8)
     lease.release()
-    fresh = pack_bucket(small, [None] * 2, k=1, g_pad=8)
+    fresh = pack_bucket(small, k=1, g_pad=8)
     _assert_staging_equal(reused, fresh)
+    _assert_staging_equal(reused,
+                          _reference_staging(small, keys[:2], k=1, g_pad=8))
 
 
 def test_pack_bucket_rejects_mismatched_prebuilt_shape():
@@ -163,9 +204,21 @@ def test_pack_bucket_rejects_mismatched_prebuilt_shape():
     bigger = promote_plan(plan, plan.R * 2, plan.W)
     bigger.rows = plan.rows            # stale rows at the old bucket
     with pytest.raises(ValueError, match="prebuilt rows"):
-        pack_bucket([bigger], [None], k=1)
+        pack_bucket([bigger], k=1)
     with pytest.raises(ValueError, match="prebuilt rows"):
-        pack_bucket([plan], [None], k=2)   # k mismatch
+        pack_bucket([plan], k=2)   # k mismatch
+
+
+def test_pack_bucket_refuses_plan_without_rows():
+    # pack_bucket only copies rows; a plan without them names the builder,
+    # whether it packs alone or beside plans that have rows.
+    plans = [plan_graph(g) for g in _path_graphs([9, 12])]
+    with pytest.raises(ValueError, match="build_packed_rows"):
+        pack_bucket(plans[:1], k=1)
+    plans[0].rows = build_packed_rows(
+        plans[0], sample_keys(jax.random.PRNGKey(0), 1))
+    with pytest.raises(ValueError, match="build_packed_rows"):
+        pack_bucket(plans, k=1)
 
 
 def test_promote_rejects_shrinking():
@@ -183,6 +236,46 @@ def test_packed_rows_lazy_ranks_match_direct_dispatch():
     assert rows.ranks.shape == (2, plan.R + 1)
     assert (rows.ranks[:, :plan.n] == direct).all()
     assert (rows.ranks[:, plan.n:] == np.iinfo(np.int32).max).all()
+
+
+# ---------------------------------------------------------------------------
+# One row builder: the batch API and a serving flush stage the same bytes.
+# ---------------------------------------------------------------------------
+
+
+class _RecordingExecutor(SyncExecutor):
+    """A sync executor that keeps a copy of every staged flush."""
+
+    def __init__(self):
+        super().__init__()
+        self.staged = []
+
+    def submit(self, ell, ranks_p, elig_p, m_edges, k, **kw):
+        self.staged.append(tuple(np.array(a, copy=True)
+                                 for a in (ell, ranks_p, elig_p, m_edges)))
+        return super().submit(ell, ranks_p, elig_p, m_edges, k, **kw)
+
+
+@pytest.mark.parametrize("method", ["pivot", "precluster"])
+def test_batch_api_and_batcher_flush_stage_identical_bytes(method):
+    # Two shape buckets, best of 2: the batch API flushes its buckets in
+    # order of first appearance, as the batcher's end-of-stream drain does.
+    graphs = _graphs(6, 5, 40, seed=21, lam_hi=3)
+    keys = [jax.random.PRNGKey(50 + i) for i in range(len(graphs))]
+    assert len({plan_graph(g, method=method).bucket for g in graphs}) > 1
+    via_batch = _RecordingExecutor()
+    correlation_cluster_batch(graphs, keys=keys, method=method,
+                              num_samples=2, executor=via_batch)
+    via_engine = _RecordingExecutor()
+    engine = ClusterBatcher(max_batch=64, method=method, num_samples=2,
+                            executor=via_engine, result_cache=False)
+    for i, (g, key) in enumerate(zip(graphs, keys)):
+        engine.admit(ClusterRequest(uid=i, graph=g, key=key))
+    assert len(engine.flush()) == len(graphs)
+    assert len(via_batch.staged) == len(via_engine.staged) > 1
+    for a, b in zip(via_batch.staged, via_engine.staged):
+        for x, y in zip(a, b):
+            assert x.shape == y.shape and (x == y).all()
 
 
 # ---------------------------------------------------------------------------
@@ -205,32 +298,16 @@ def test_fingerprint_payload_survives_canonical_sharing():
 
 
 # ---------------------------------------------------------------------------
-# Deprecation shim.
+# Serving engine: bit-exactness of admission-built rows.
 # ---------------------------------------------------------------------------
 
 
-def test_pack_bucket_deprecated_shim():
-    plans = _legacy([plan_graph(build_graph(6, path(6)))])
-    keys = [sample_keys(jax.random.PRNGKey(0), 1)]
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        shimmed = _pack_bucket(plans, keys, k=1)
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    _assert_staging_equal(shimmed,
-                          pack_bucket(_legacy(plans), keys, k=1))
-
-
-# ---------------------------------------------------------------------------
-# Serving engine: prebuild on/off bit-exactness.
-# ---------------------------------------------------------------------------
-
-
-def _serve(graphs, prebuild, executor="sync", use_kernel=False, policy=None,
+def _serve(graphs, executor="sync", use_kernel=False, policy=None,
            max_batch=4, num_samples=1, max_wait=None):
     batcher = ClusterBatcher(max_batch=max_batch, max_wait=max_wait,
                              num_samples=num_samples, executor=executor,
                              use_kernel=use_kernel, policy=policy,
-                             result_cache=False, prebuild_rows=prebuild)
+                             result_cache=False)
     reqs = [ClusterRequest(uid=i, graph=g, key=jax.random.PRNGKey(i))
             for i, g in enumerate(graphs)]
     done = {r.uid: r.result for r in serve_all(batcher, reqs)}
@@ -242,76 +319,67 @@ def _serve(graphs, prebuild, executor="sync", use_kernel=False, policy=None,
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_serving_bitexact_across_executors_and_kernels(executor, use_kernel):
     graphs = _graphs(6, 6, 24, seed=8)
-    pre, _ = _serve(graphs, True, executor=executor, use_kernel=use_kernel,
-                    num_samples=2)
-    leg, stats = _serve(graphs, False, executor=executor,
-                        use_kernel=use_kernel, num_samples=2)
-    assert stats.latency.total_builds == 0
+    done, stats = _serve(graphs, executor=executor, use_kernel=use_kernel,
+                         num_samples=2)
+    assert stats.latency.total_builds == len(graphs)
     for i, g in enumerate(graphs):
         ref = correlation_cluster(g, key=jax.random.PRNGKey(i),
                                   num_samples=2, use_kernel=use_kernel)
-        for arm in (pre, leg):
-            assert (arm[i].labels == ref.labels).all()
-            assert arm[i].cost == ref.cost
-            assert arm[i].info["picked_sample"] == ref.info["picked_sample"]
+        assert (done[i].labels == ref.labels).all()
+        assert done[i].cost == ref.cost
+        assert done[i].info["picked_sample"] == ref.info["picked_sample"]
 
 
 def test_deadline_partial_subbatch_prebuilt_bitexact():
     # max_batch never fills: every flush is a partial deadline sub-batch.
     graphs = _graphs(5, 6, 20, seed=9)
-    for prebuild in (True, False):
-        clock = VirtualClock()
-        batcher = ClusterBatcher(max_batch=64, max_wait=0.01, clock=clock,
-                                 result_cache=False, prebuild_rows=prebuild)
-        done = {}
-        for i, g in enumerate(graphs):
-            clock.advance(0.004)
-            for r in batcher.admit(ClusterRequest(
-                    uid=i, graph=g, key=jax.random.PRNGKey(i))):
-                done[r.uid] = r.result
-            for r in batcher.poll():
-                done[r.uid] = r.result
-        for r in batcher.flush():
+    clock = VirtualClock()
+    batcher = ClusterBatcher(max_batch=64, max_wait=0.01, clock=clock,
+                             result_cache=False)
+    done = {}
+    for i, g in enumerate(graphs):
+        clock.advance(0.004)
+        for r in batcher.admit(ClusterRequest(
+                uid=i, graph=g, key=jax.random.PRNGKey(i))):
             done[r.uid] = r.result
-        assert batcher.stats.deadline_flushes > 0
-        for i, g in enumerate(graphs):
-            ref = correlation_cluster(g, key=jax.random.PRNGKey(i))
-            assert (done[i].labels == ref.labels).all()
-            assert done[i].cost == ref.cost
+        for r in batcher.poll():
+            done[r.uid] = r.result
+    for r in batcher.flush():
+        done[r.uid] = r.result
+    assert batcher.stats.deadline_flushes > 0
+    for i, g in enumerate(graphs):
+        ref = correlation_cluster(g, key=jax.random.PRNGKey(i))
+        assert (done[i].labels == ref.labels).all()
+        assert done[i].cost == ref.cost
 
 
 def test_coalesced_stolen_flush_prebuilt_bitexact():
     # Hot (32, 4) bucket + starved small bucket, aggressive stealing: the
     # stolen requests run at a promoted shape assembled from promoted
-    # PackedRows. Identical steal schedule across arms (virtual clock).
-    stolen_counts = {}
-    for prebuild in (True, False):
-        clock = VirtualClock()
-        batcher = ClusterBatcher(
-            max_batch=8, clock=clock, result_cache=False,
-            prebuild_rows=prebuild,
-            policy=CoalescingPolicy(8, max_wait=0.01, steal_wait=0.001))
-        done = {}
-        graphs = {}
-        rng = np.random.default_rng(11)
-        for i in range(24):
-            n = 6 if i % 8 == 0 else int(rng.integers(17, 30))
-            graphs[i] = build_graph(n, path(n))
-            clock.advance(0.002)
-            for r in batcher.admit(ClusterRequest(
-                    uid=i, graph=graphs[i], key=jax.random.PRNGKey(i))):
-                done[r.uid] = r.result
-            for r in batcher.poll():
-                done[r.uid] = r.result
-        for r in batcher.flush():
+    # PackedRows (virtual clock: a deterministic steal schedule).
+    clock = VirtualClock()
+    batcher = ClusterBatcher(
+        max_batch=8, clock=clock, result_cache=False,
+        policy=CoalescingPolicy(8, max_wait=0.01, steal_wait=0.001))
+    done = {}
+    graphs = {}
+    rng = np.random.default_rng(11)
+    for i in range(24):
+        n = 6 if i % 8 == 0 else int(rng.integers(17, 30))
+        graphs[i] = build_graph(n, path(n))
+        clock.advance(0.002)
+        for r in batcher.admit(ClusterRequest(
+                uid=i, graph=graphs[i], key=jax.random.PRNGKey(i))):
             done[r.uid] = r.result
-        assert batcher.stats.stolen_requests > 0
-        stolen_counts[prebuild] = batcher.stats.stolen_requests
-        for i, g in graphs.items():
-            ref = correlation_cluster(g, key=jax.random.PRNGKey(i))
-            assert (done[i].labels == ref.labels).all()
-            assert done[i].cost == ref.cost
-    assert stolen_counts[True] == stolen_counts[False]
+        for r in batcher.poll():
+            done[r.uid] = r.result
+    for r in batcher.flush():
+        done[r.uid] = r.result
+    assert batcher.stats.stolen_requests > 0
+    for i, g in graphs.items():
+        ref = correlation_cluster(g, key=jax.random.PRNGKey(i))
+        assert (done[i].labels == ref.labels).all()
+        assert done[i].cost == ref.cost
 
 
 # ---------------------------------------------------------------------------

@@ -240,8 +240,7 @@ def test_cluster_stats_accumulate_the_peel_counts():
     graphs = [build_graph(4096, path(4096)),
               build_graph(4096, random_arboric(
                   4096, 4, np.random.default_rng(3))[0])]
-    eng = ClusterBatcher(max_batch=64, prebuild_rows=False,
-                         result_cache=False)
+    eng = ClusterBatcher(max_batch=64, result_cache=False)
     for uid, g in enumerate(graphs):
         eng.admit(ClusterRequest(uid=uid, graph=g,
                                  key=jax.random.PRNGKey(uid)))
