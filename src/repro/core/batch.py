@@ -158,8 +158,8 @@ def correlation_cluster_batch(
     ex = make_executor(executor)
     plans = [plan_graph(g, method=method, eps=eps, lam=lam)
              for g, lam in zip(graphs, lams)]
-    # The same row build admission does: ELL scatter plus an async rank
-    # dispatch per graph, so the packs below only copy rows.
+    # The same row build admission does: compact ELL entries plus an async
+    # rank dispatch per graph, so the packs below only expand rows.
     for plan, key in zip(plans, keys):
         plan.rows = build_packed_rows(plan, sample_keys(key, k))
 

@@ -762,7 +762,7 @@ def pack_and_submit(plans, k: int, executor: "BucketExecutor",
     ``correlation_cluster_batch`` and the serving-layer flush, so group
     padding, donation policy and pad accounting cannot drift between the
     two paths. Every plan carries its :class:`~repro.core.plan.
-    PackedRows`, so the pack is row copies; its measured host time is
+    PackedRows`, so the pack only expands rows; its measured host time is
     stamped on the handle as ``assemble_seconds``. Returns ``(handle,
     stats)`` where ``stats`` is this one flush's :class:`~repro.core.
     plan.PackStats` — the single source every caller's pad accounting

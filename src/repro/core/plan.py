@@ -12,16 +12,19 @@ host:
   :func:`graph_fingerprint` and the row builder both read
   ``GraphPlan.canonical_edges`` instead of re-deriving it.
 * :func:`build_packed_rows` turns one plan into a :class:`PackedRows`
-  artifact — the graph's finished ``(R, W)`` ELL rows, rank rows, and
+  artifact — the graph's ELL entries in compact form (neighbour ids and
+  their flat ``row·W + slot`` positions, no pad), rank rows, and
   eligibility row. It is the one row builder: serving calls it once per
   request at admission and ``correlation_cluster_batch`` once per graph
-  after planning, so the argsort/bincount/scatter work never runs on the
-  flush path.
+  after planning, so the sort/bincount work never runs on the flush path,
+  and no per-request ``(R, W)`` array is ever allocated.
 * :func:`pack_bucket` lays one bucket's rows (× k best-of-k samples)
   into the ``(B, R, W)`` ELL tensor plus ``(B, R+1)`` rank/eligibility
   state the device program consumes, with the group axis padded to a power
   of two (callers may request extra group padding, e.g. to a device-count
-  multiple for the sharded executor). It only copies rows.
+  multiple for the sharded executor). It expands each graph's compact
+  entries into its first staging entry and copies that to the other
+  samples' entries.
 * :class:`PackStats` is the packer's own padding accounting — the single
   source serving stats are derived from, so they cannot drift from what was
   actually padded onto the device. :func:`estimate_pack_stats` is the pure
@@ -154,8 +157,8 @@ def plan_graph(g: Graph, method: str = "pivot", eps: float = 2.0,
         kept = np.zeros((0, 2), dtype=np.int64)
     if len(kept):
         # Canonical order: lexsorted by (u, v). This is the byte order the
-        # fingerprint hashes and the edge order build_packed_rows scatters
-        # from.
+        # fingerprint hashes and the edge order build_packed_rows lays
+        # each row's entries in.
         kept = kept[np.lexsort((kept[:, 1], kept[:, 0]))]
         wreq = int(np.bincount(kept.ravel(), minlength=n).max())
     else:
@@ -205,12 +208,14 @@ class PackedRows:
     """The device rows of one planned graph (see :func:`build_packed_rows`).
 
     Everything the device program reads of this graph, finished once
-    before any flush: the ``(R, W)`` int32 ELL adjacency rows
-    (pad id ``R``), the ``(k, R+1)`` rank rows for the request's best-of-k
-    sample keys (``INT32_MAX`` beyond ``n``), the ``(R+1,)`` eligibility
-    row (slot ``R`` False), and the full edge count ``m`` the cost
-    identity reads. Flush-time assembly then reduces to row copies into
-    the leased staging arrays.
+    before any flush: the ELL adjacency in compact form — ``nbr``, the
+    int32 neighbour ids in ELL slot order, and ``flat``, the int32
+    position ``row·W + slot`` of each in the ``(R, W)`` rows (every other
+    slot is pad id ``R``) — the ``(k, R+1)`` rank rows for the request's
+    best-of-k sample keys (``INT32_MAX`` beyond ``n``), the ``(R+1,)``
+    eligibility row (slot ``R`` False), and the full edge count ``m`` the
+    cost identity reads. The dense, pad-filled rows are written only into
+    the leased staging arrays, at flush time (:meth:`expand_into`).
 
     The rank permutations are dispatched to the device when the artifact
     is built (one fused async call) and materialised into the padded
@@ -218,18 +223,19 @@ class PackedRows:
     finished, so the host work after the build overlaps them.
     """
 
-    __slots__ = ("R", "W", "n", "m", "k", "ell", "elig",
+    __slots__ = ("R", "W", "n", "m", "k", "nbr", "flat", "elig",
                  "_ranks", "_ranks_dev")
 
     def __init__(self, R: int, W: int, n: int, m: int, k: int,
-                 ell: np.ndarray, elig: np.ndarray,
+                 nbr: np.ndarray, flat: np.ndarray, elig: np.ndarray,
                  ranks: Optional[np.ndarray] = None, ranks_dev=None):
         self.R = R
         self.W = W
         self.n = n
         self.m = m
         self.k = k
-        self.ell = ell
+        self.nbr = nbr
+        self.flat = flat
         self.elig = elig
         self._ranks = ranks
         self._ranks_dev = ranks_dev
@@ -237,6 +243,17 @@ class PackedRows:
     @property
     def bucket(self) -> Tuple[int, int]:
         return (self.R, self.W)
+
+    @property
+    def nnz(self) -> int:
+        """Real (non-pad) ELL entries: twice the eligible-induced edges."""
+        return int(self.nbr.size)
+
+    @property
+    def nbytes(self) -> int:
+        """Host bytes the artifact retains, its rank rows materialised."""
+        return (self.nbr.nbytes + self.flat.nbytes + self.elig.nbytes
+                + self.k * (self.R + 1) * np.dtype(np.int32).itemsize)
 
     @property
     def ranks(self) -> np.ndarray:
@@ -252,13 +269,23 @@ class PackedRows:
             self._ranks = out
         return self._ranks
 
+    def expand_into(self, out: np.ndarray) -> None:
+        """Write the dense ``(R, W)`` ELL rows into C-contiguous ``out``.
+
+        Every slot is written: pad id ``R`` first, then the real entries,
+        so a reused (dirty) staging entry holds nothing of its last use.
+        """
+        out.fill(self.R)
+        out.reshape(-1)[self.flat] = self.nbr
+
     def promote(self, R: int, W: int) -> "PackedRows":
-        """Pad-copy relayout into a larger ``(R, W)`` bucket (coalescing).
+        """Relayout into a larger ``(R, W)`` bucket (coalescing).
 
         Bit-exact for the same reason :func:`promote_plan` is: promoted
         rows ``n..R`` carry INF rank and are ineligible, extra width slots
-        hold the new pad id ``R``. Raises ``ValueError`` for a target that
-        cannot hold these rows.
+        hold the new pad id ``R``. The real entries keep their row and
+        slot; only their flat positions move to the new width. Raises
+        ``ValueError`` for a target that cannot hold these rows.
         """
         if (R, W) == (self.R, self.W):
             return self
@@ -266,17 +293,16 @@ class PackedRows:
             raise ValueError(
                 f"cannot promote packed rows {self.bucket} into ({R}, {W}):"
                 " the target must be at least as large in both dimensions")
-        ell = np.full((R, W), R, dtype=np.int32)
-        if self.n:
-            # Real entries only live in rows < n; re-stamp the pad id.
-            sub = self.ell[: self.n]
-            ell[: self.n, : self.W] = np.where(sub == self.R, R, sub)
+        flat = self.flat
+        if W != self.W:
+            row, slot = np.divmod(flat, np.int32(self.W))
+            flat = row * np.int32(W) + slot
         elig = np.zeros(R + 1, dtype=bool)
         elig[: self.n] = self.elig[: self.n]
         ranks = np.full((self.k, R + 1), _INT32_MAX, dtype=np.int32)
         ranks[:, : self.n] = self.ranks[:, : self.n]
         return PackedRows(R=R, W=W, n=self.n, m=self.m, k=self.k,
-                          ell=ell, elig=elig, ranks=ranks)
+                          nbr=self.nbr, flat=flat, elig=elig, ranks=ranks)
 
 
 def build_packed_rows(plan: GraphPlan,
@@ -284,31 +310,35 @@ def build_packed_rows(plan: GraphPlan,
     """Build one graph's :class:`PackedRows` at its native bucket.
 
     ``keys`` are the request's best-of-k sample keys; the rank batch is
-    dispatched here (async) and harvested lazily. The ELL rows scatter
+    dispatched here (async) and harvested lazily. The ELL entries come
     straight from the plan's canonical edge list — the same array the
     fingerprint hashes — so the sort/bincount of packing happens exactly
-    once per request, at admission.
+    once per request, at admission, in O(m) memory.
+
+    Vertex ``x``'s row lists its neighbours where it is the first endpoint
+    of a canonical edge, then where it is the second, each in canonical
+    order: a stable sort of the directed entries by source. A planned
+    graph's vertex ids are below ``MAX_ROWS`` = 2^15, so the sort key fits
+    int16, and numpy's stable sort of an int16 key is a radix sort.
     """
     n = plan.n
     R, W = plan.bucket
-    ell = np.full((R, W), R, dtype=np.int32)
     e = plan_canonical_edges(plan)
-    if len(e):
-        src = np.concatenate([e[:, 0], e[:, 1]])
-        dst = np.concatenate([e[:, 1], e[:, 0]])
-        order = np.argsort(src, kind="stable")
-        src, dst = src[order], dst[order]
-        deg = np.bincount(src, minlength=n)
-        starts = np.zeros(n + 1, dtype=np.int64)
-        np.cumsum(deg, out=starts[1:])
-        slot = np.arange(len(src)) - starts[src]
-        ell[src, slot] = dst
+    src = np.concatenate([e[:, 0], e[:, 1]]).astype(
+        np.int16 if n <= 1 << 15 else np.int32)
+    order = np.argsort(src, kind="stable")
+    nbr = np.concatenate([e[:, 1], e[:, 0]]).astype(np.int32)[order]
+    src = src[order].astype(np.int32)
+    starts = np.zeros(n, dtype=np.int32)
+    np.cumsum(np.bincount(src, minlength=n)[:-1], out=starts[1:])
+    flat = src * np.int32(W) + (np.arange(len(src), dtype=np.int32)
+                                - starts[src])
     elig = np.zeros(R + 1, dtype=bool)
     if n:
         elig[:n] = plan.eligible
     ranks_dev = random_permutation_ranks_batch(n, keys) if n else None
     return PackedRows(R=R, W=W, n=n, m=int(plan.g.m), k=len(keys),
-                      ell=ell, elig=elig, ranks_dev=ranks_dev)
+                      nbr=nbr, flat=flat, elig=elig, ranks_dev=ranks_dev)
 
 
 def promote_plan(plan: GraphPlan, R: int, W: int) -> GraphPlan:
@@ -339,8 +369,9 @@ def promote_plan(plan: GraphPlan, R: int, W: int) -> GraphPlan:
             f"bucket ({MAX_ROWS}, {MAX_WIDTH})")
     if (R, W) == plan.bucket:
         return plan
-    # Prebuilt rows relayout with the plan (cheap pad-copies), so a
-    # coalesced flush at the promoted shape still assembles by row copies.
+    # Prebuilt rows relayout with the plan (their entries' flat positions
+    # move to the new width), so a coalesced flush at the promoted shape
+    # still assembles from compact rows.
     rows = plan.rows.promote(R, W) if plan.rows is not None else None
     return dataclasses.replace(plan, R=R, W=W, rows=rows)
 
@@ -514,10 +545,11 @@ def pack_bucket(plans: Sequence[GraphPlan], k: int,
 
     Every plan carries its :class:`PackedRows` (built by
     :func:`build_packed_rows`, promoted with its plan for coalesced
-    flushes), so assembly is row copies only: each real entry is wholly
-    overwritten by its copy, and only the group-padding tail is (re)stamped
-    with the pad pattern. Raises ``ValueError`` for a plan without rows or
-    with rows at another bucket or sample count.
+    flushes). Its compact entries expand into the first of its ``k``
+    staging entries — pad-stamped whole first, since a leased buffer is
+    dirty — which is then copied to the other ``k − 1``; the group-padding
+    tail is stamped with the pad pattern. Raises ``ValueError`` for a plan
+    without rows or with rows at another bucket or sample count.
     """
     R, W = plans[0].bucket
     if g_pad is None:
@@ -552,7 +584,8 @@ def pack_bucket(plans: Sequence[GraphPlan], k: int,
     for gi, plan in enumerate(plans):
         pr = plan.rows
         base = gi * k
-        ell[base: base + k] = pr.ell
+        pr.expand_into(ell[base])
+        ell[base + 1: base + k] = ell[base]
         ranks[base: base + k] = pr.ranks
         elig[base: base + k] = pr.elig
         m_edges[base: base + k] = pr.m

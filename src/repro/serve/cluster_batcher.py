@@ -57,13 +57,13 @@ Admission backpressure (bounded in-flight work)
 
 Admission-time packing (build/assemble split)
   Every cold admission finishes its per-graph packing work right away:
-  :func:`repro.core.plan.build_packed_rows` scatters the plan's canonical
-  edge list into the graph's :class:`~repro.core.plan.PackedRows` and
-  dispatches its rank permutations, once per request. Flushes then
-  *assemble* buckets by row copies into the leased staging arrays — the
-  argsort/bincount host work stays off the flush critical path
-  (JetStream-style: per-request preprocessing at admission, batch
-  assembly a memcpy).
+  :func:`repro.core.plan.build_packed_rows` sorts the plan's canonical
+  edge list into the graph's compact :class:`~repro.core.plan.PackedRows`
+  (the real ELL entries and their positions, no pad) and dispatches its
+  rank permutations, once per request. Flushes then *assemble* buckets
+  into the leased staging arrays — pad, a scatter of the real entries and
+  row copies — so the sort/bincount host work stays off the flush critical
+  path and no request holds an ``(R, W)`` array of its own.
 
 Telemetry (the policies' stats surface)
   Every harvested flush records its host bucket-assembly time and
@@ -169,6 +169,8 @@ class ClusterStats(EngineStats):
     # and vertices the per-vertex cascade stripped.
     peel_rounds: int = 0
     peel_single: int = 0
+    # Host bytes of the admitted requests' row artifacts (PackedRows.nbytes).
+    rows_bytes: int = 0
     pad_vertex_waste: int = 0    # Σ (R − n) over clustered graphs
     buckets_seen: int = 0        # distinct (method, R, W) queues admitted
     rejected: int = 0            # admissions refused by backpressure
@@ -382,16 +384,18 @@ class ClusterBatcher:
         req.admitted_at = now
         if plan.rows is None:
             # The request's per-graph packing work, done once here — the
-            # ELL scatter from the plan's canonical edges plus the async
-            # rank dispatch — so its flushes only copy rows. Placed after
-            # the cache/single-flight/backpressure gates: only requests
-            # that will actually pack pay the build.
+            # compact ELL entries from the plan's canonical edges plus the
+            # async rank dispatch — so its flushes only expand and copy
+            # rows. Placed after the cache/single-flight/backpressure
+            # gates: only requests that will actually pack pay the build.
             t_build = time.perf_counter()
-            with span("rows", uid=req.uid):
+            with span("rows", uid=req.uid) as sp:
                 plan.rows = build_packed_rows(
                     plan, sample_keys(req.key, self.num_samples))
+                sp.set_metadata(nnz=plan.rows.nnz, bytes=plan.rows.nbytes)
             self.stats.latency.record_build(
                 plan.queue_key, time.perf_counter() - t_build)
+            self.stats.rows_bytes += plan.rows.nbytes
         self.buckets.setdefault(plan.queue_key, []).append(req)
         if req.fingerprint is not None:
             self._single_flight[req.fingerprint.digest] = req

@@ -14,6 +14,9 @@ PackedRows.promote, serve/cluster_batcher.py admission, core/batch.py):
 * ``PackedRows.promote`` relayouts into any larger ``(R, W)`` and the
   promoted rows assemble byte-identically to the reference pack of the
   promoted plans (the coalesced-flush path);
+* the artifact is compact — the real entries and their flat positions,
+  no ``(R, W)`` array — and expanding it into a reused, dirty staging
+  lease re-stamps every slot, whatever the lease held last;
 * ``pack_bucket`` only copies rows: a plan without them is refused;
 * the batch API and a serving flush stage the same bytes for the same
   graphs and keys, since both build rows with ``build_packed_rows``;
@@ -41,7 +44,8 @@ from repro.core import (
     promote_plan,
 )
 from repro.core.api import sample_keys
-from repro.core.graph import path, random_arboric
+from repro.core.dist import _pad_edges_for_mesh
+from repro.core.graph import Graph, path, random_arboric
 from repro.core.mis import (random_permutation_ranks,
                              random_permutation_ranks_batch)
 from repro.core.plan import graph_fingerprint, plan_canonical_edges
@@ -66,6 +70,13 @@ def _graphs(num, lo, hi, seed, lam_hi=2):
 def _path_graphs(ns):
     """Path graphs whose n all land in one (R, W) shape bucket."""
     return [build_graph(n, path(n)) for n in ns]
+
+
+def _circulant(n, d):
+    """The ``d``-regular circulant on ``n`` vertices (``d`` even): every
+    vertex joined to the ``d / 2`` next and previous ones."""
+    return build_graph(n, np.array([(i, (i + j) % n) for i in range(n)
+                                    for j in range(1, d // 2 + 1)]))
 
 
 def _with_rows(plans, keys):
@@ -196,6 +207,122 @@ def test_staging_reuse_resets_stale_tail():
     _assert_staging_equal(reused, fresh)
     _assert_staging_equal(reused,
                           _reference_staging(small, keys[:2], k=1, g_pad=8))
+
+
+def test_reused_lease_restamps_longer_stale_rows():
+    # The same staging entry last held a graph with longer rows at the same
+    # shape (a 4-regular circulant, every row full); a path's rows are
+    # shorter, so its expansion must put every stale real entry back to pad.
+    k = 2
+    full, short = _circulant(16, 4), build_graph(16, path(16))
+    assert plan_graph(full).bucket == plan_graph(short).bucket == (16, 4)
+    keys = [sample_keys(jax.random.PRNGKey(i), k) for i in range(2)]
+    pool = BucketBufferPool()
+    for graph, ks in ((full, keys[0]), (short, keys[1])):
+        plans = _with_rows([plan_graph(graph)], [ks])
+        lease = pool.acquire(k, 16, 4)
+        staged = tuple(np.array(a, copy=True) if isinstance(a, np.ndarray)
+                       else a for a in pack_bucket(
+                           plans, k=k, staging=lease.arrays, g_pad=1))
+        lease.release()
+    assert pool.n_buffers == 1
+    _assert_staging_equal(staged, _reference_staging(plans, [keys[1]], k,
+                                                     g_pad=1))
+
+
+@pytest.mark.parametrize("n, d", [(16, 4), (32, 8)])
+def test_every_row_full_matches_reference_and_counts_its_bytes(n, d):
+    # The compact form's worst case: a d-regular graph on R = n vertices at
+    # W = d, so every slot is real. The artifact then holds 8 bytes a slot
+    # (id and position) against the dense rows' 4.
+    k = 2
+    graph = _circulant(n, d)
+    keys = [sample_keys(jax.random.PRNGKey(3), k)]
+    plans = _with_rows([plan_graph(graph)], keys)
+    rows = plans[0].rows
+    assert rows.bucket == (n, d)
+    assert rows.nnz == n * d
+    assert rows.nbytes == 8 * n * d + (n + 1) + 4 * k * (n + 1)
+    _assert_staging_equal(pack_bucket(plans, k=k),
+                          _reference_staging([plan_graph(graph)], keys, k))
+
+
+def _shuffled(g, seed):
+    """``g`` with its directed COO slots in a random order."""
+    perm = np.random.default_rng(seed).permutation(g.num_directed)
+    return Graph(n=g.n, m=g.m, src=g.src[perm], dst=g.dst[perm],
+                 row_offsets=g.row_offsets, deg=g.deg, eid=g.eid[perm])
+
+
+@pytest.mark.parametrize("reorder", [
+    lambda g: _pad_edges_for_mesh(g, 7),
+    lambda g: _shuffled(g, 0),
+    lambda g: _shuffled(_pad_edges_for_mesh(g, 5), 1),
+], ids=["mesh_padded", "shuffled", "padded_shuffled"])
+def test_rows_do_not_depend_on_the_graphs_slot_order(reorder):
+    # Graphs built by the distributed engine carry their COO arrays in
+    # other orders; the rows come from the canonical edge list alone.
+    k = 2
+    graphs = _graphs(3, 20, 30, seed=17, lam_hi=3)
+    keys = [sample_keys(jax.random.PRNGKey(60 + i), k)
+            for i in range(len(graphs))]
+    for graph, ks in zip(graphs, keys):
+        other = reorder(graph)
+        assert other.num_directed != graph.num_directed \
+            or (np.asarray(other.src) != np.asarray(graph.src)).any()
+        plans = _with_rows([plan_graph(other)], [ks])
+        _assert_staging_equal(
+            pack_bucket(plans, k=k),
+            _reference_staging([plan_graph(graph)], [ks], k))
+
+
+@pytest.mark.parametrize("grow", [(1, 2), (2, 1), (2, 4)],
+                         ids=["wider", "taller", "both"])
+def test_promoted_best_of_k_flush_into_a_dirty_lease(grow):
+    # A coalesced best-of-3 flush: mixed native buckets promoted to one
+    # shape (only W, only R, or both grown, so flat positions move or
+    # stay), assembled into a pooled lease a fuller flush dirtied first.
+    k = 3
+    graphs = _graphs(4, 5, 14, seed=23, lam_hi=3)
+    keys = [sample_keys(jax.random.PRNGKey(80 + i), k)
+            for i in range(len(graphs))]
+    built = _with_rows([plan_graph(g) for g in graphs], keys)
+    R = grow[0] * max(p.R for p in built)
+    W = grow[1] * max(p.W for p in built)
+    promoted = [promote_plan(p, R, W) for p in built]
+    filler = _with_rows([plan_graph(_circulant(R, W))] * 4,
+                        [sample_keys(jax.random.PRNGKey(9), k)] * 4)
+    assert filler[0].bucket == (R, W)
+    pool = BucketBufferPool()
+    lease = pool.acquire(4 * k, R, W)
+    pack_bucket(filler, k=k, staging=lease.arrays)
+    lease.release()
+    lease = pool.acquire(4 * k, R, W)
+    staged = pack_bucket(promoted, k=k, staging=lease.arrays)
+    lease.release()
+    assert pool.n_buffers == 1
+    ref = [promote_plan(plan_graph(g), R, W) for g in graphs]
+    _assert_staging_equal(staged, _reference_staging(ref, keys, k))
+
+
+def test_build_keeps_no_dense_rows():
+    # The artifact holds each real entry's id and flat position, the
+    # eligibility row and the k rank rows: 8 bytes an entry plus O(k·R),
+    # never an (R, W) array.
+    k = 4
+    rng = np.random.default_rng(31)
+    edges, _ = random_arboric(3000, 3, rng)
+    plan = plan_graph(build_graph(3000, edges))
+    R, W = plan.bucket
+    rows = build_packed_rows(plan, sample_keys(jax.random.PRNGKey(0), k))
+    m2 = 2 * len(plan_canonical_edges(plan))
+    assert rows.nnz == m2
+    assert rows.nbytes == 8 * m2 + (R + 1) + 4 * k * (R + 1)
+    assert rows.nbytes < 4 * R * W      # the dense int32 rows alone
+    arrays = [getattr(rows, name) for name in PackedRows.__slots__
+              if isinstance(getattr(rows, name), np.ndarray)]
+    assert arrays and max(a.size for a in arrays) < R * W
+    assert rows.ranks.shape == (k, R + 1)
 
 
 def test_pack_bucket_rejects_mismatched_prebuilt_shape():

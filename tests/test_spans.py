@@ -245,3 +245,17 @@ def test_degeneracy_span_carries_the_peel_counts(served):
     peels = [degeneracy_peel(graph) for _, graph, _ in _requests()]
     assert eng.stats.peel_rounds == sum(p.rounds for p in peels)
     assert eng.stats.peel_single == sum(p.single for p in peels) > 0
+
+
+def test_rows_span_carries_the_artifacts_size(served):
+    # nnz: the real ELL entries (two per eligible-induced edge); bytes: the
+    # compact artifact with its K rank rows, summed into stats.rows_bytes.
+    spans, eng = served[0], served[1]
+    rows = {s[4]["uid"]: s[4] for s in _named(spans, "rows")}
+    for uid, graph, _ in _requests()[:3]:
+        plan = plan_graph(graph)
+        nnz = 2 * len(plan.canonical_edges)
+        assert rows[uid]["nnz"] == nnz
+        assert rows[uid]["bytes"] == 8 * nnz + (plan.R + 1) \
+            + 4 * K * (plan.R + 1)
+    assert eng.stats.rows_bytes == sum(a["bytes"] for a in rows.values())
